@@ -37,10 +37,7 @@ def flops_of_compiled(compiled) -> Optional[float]:
     the SAME executable for the timed loop, so the analysis describes
     exactly what ran."""
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
-        flops = cost.get("flops")
+        flops = compiled.cost_analysis().get("flops")
         return float(flops) if flops else None
     except Exception:
         return None

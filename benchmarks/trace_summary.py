@@ -88,9 +88,8 @@ def summarize(xplane_path: str):
         if op_lines:
             lines = op_lines
         else:
-            # host-CPU fallback. Two generations of layout: older jax put
-            # op events on one anonymous line; current jax scatters them
-            # over the runtime's thread-pool lines ("tf_XLAEigen/...",
+            # host-CPU plane: jax scatters op events over the runtime's
+            # thread-pool lines ("tf_XLAEigen/...",
             # "tf_XLATfrtCpuClient/...") interleaved with python frames
             # and C++ wrapper spans, and names events by HLO instruction
             # ("dot.4") instead of framework op — so filtering happens

@@ -48,8 +48,9 @@ def initPaddle(*args: str) -> None:
     from paddle_tpu.utils.flags import FLAGS
 
     FLAGS.parse(list(args))
-    if not FLAGS.use_tpu:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from paddle_tpu.utils.device import select_platform
+
+    select_platform(FLAGS.use_tpu)
 
 
 class DataProviderConverter:

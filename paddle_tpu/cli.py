@@ -38,6 +38,23 @@ def main(argv=None) -> int:
 
         print(f"paddle_tpu {__version__} (jax {jax.__version__})")
         print(f"devices: {jax.devices()}")
+        # the device as jax reports it, with the peaks the roofline and
+        # MFU accounting will use for it (null = not in the table, and
+        # every utilization number would be omitted)
+        import json
+
+        from paddle_tpu.ops.kernel_flops import (peak_gbps, peak_hbm_gb,
+                                                 peak_tflops)
+        from paddle_tpu.utils.device import device_stamp
+
+        d = device_stamp()
+        kind = d["device_kind"]
+        print("device: " + json.dumps({
+            "platform": d["platform"], "kind": kind,
+            "count": d["device_count"],
+            "peak_tflops": peak_tflops(kind), "peak_gbps": peak_gbps(kind),
+            "peak_hbm_gb": peak_hbm_gb(kind),
+        }))
         return 0
     if cmd in ("train", "test", "checkgrad", "gen"):
         return _run_trainer_job(cmd, rest)
@@ -142,7 +159,10 @@ def _faults() -> int:
     return 0
 
 
-def _setup(rest):
+def _setup(rest, device_job=True):
+    """Parse flags + config. ``device_job`` = the command will run on a
+    device (train/test/checkgrad/gen); dump_config and merge_model stay
+    jax-free."""
     from paddle_tpu.utils.flags import FLAGS
 
     leftover = FLAGS.parse(rest)
@@ -153,14 +173,16 @@ def _setup(rest):
         from paddle_tpu.resilience import faultinject
 
         faultinject.configure(FLAGS.fault_spec, FLAGS.fault_seed)
-    if not FLAGS.use_tpu:
-        # before ANYTHING imports jax — jax reads JAX_PLATFORMS once at
-        # import, so the compile-cache block below must come after
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if FLAGS.compile_cache_dir:
-        # before any jax compile (Trainer re-applies the same dir, which
-        # is a no-op): warm restarts skip the XLA backend compile and
-        # the compile telemetry records the hits
+    # before ANYTHING imports jax (it reads JAX_PLATFORMS once at import):
+    # --use_tpu is a requirement, not a hint — utils/device.py
+    from paddle_tpu.utils.device import select_platform
+
+    select_platform(FLAGS.use_tpu)
+    if device_job:
+        # before any jax compile: `paddle train` has a persistent
+        # compilation cache by default, at the one place
+        # compile_log.resolve_cache_dir names — warm restarts skip the XLA
+        # backend compile and the compile telemetry records the hits
         from paddle_tpu.observability.compile_log import enable_compile_cache
 
         enable_compile_cache(FLAGS.compile_cache_dir)
@@ -299,7 +321,7 @@ def _test_saved_passes(trainer, flags) -> None:
 
 
 def _dump_config(rest) -> int:
-    flags, config = _setup(rest)
+    flags, config = _setup(rest, device_job=False)
     print(config.to_json(indent=2))
     return 0
 
@@ -386,7 +408,7 @@ def _check_checkpoint(rest) -> int:
 
 
 def _merge_model(rest) -> int:
-    flags, config = _setup(rest)
+    flags, config = _setup(rest, device_job=False)
     from paddle_tpu.trainer import checkpoint
     from paddle_tpu.trainer.checkpoint import latest_pass
 

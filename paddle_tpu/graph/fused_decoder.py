@@ -21,7 +21,6 @@ default before a measured A/B win).
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, Optional
 
 import jax
@@ -49,10 +48,15 @@ def _single_proj(cfg, want_type: str):
 
 def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[str, Any]]:
     """Returns the extraction plan, or None when the group is not the
-    attention-GRU decoder template (every bail is silent — the scan path
-    is always a correct fallback)."""
+    attention-GRU decoder template (the scan path is always a correct
+    fallback; every bail says why once, at debug)."""
+    from paddle_tpu.utils import device
+
+    def no(why):
+        device.log_selection("pallas_decoder", sub.name, f"scan path ({why})")
+
     if not ctx.is_training or sub.reversed:
-        return None
+        return no("not a forward training group")
     if ctx.mesh is not None:
         from paddle_tpu.parallel.mesh import data_only_extent
 
@@ -60,13 +64,11 @@ def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[s
         # purely data-parallel mesh the decoder runs per-shard via
         # shard_map (run_fused_decoder) — anything else takes the scan
         if data_only_extent(ctx.mesh) is None:
-            return None
-    on_tpu = jax.default_backend() == "tpu"
-    force_interpret = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1"
-    if not (on_tpu or force_interpret):
-        return None
+            return no("mesh has a non-data axis")
+    if device.pallas_mode() is None:
+        return no(device.why_no_pallas())
     if len(sub.memories) != 1 or sub.memories[0].is_sequence:
-        return None
+        return no("not exactly one non-sequence memory")
     mem = sub.memories[0]
     lm = network.layer_map
     step_layers = [
@@ -76,29 +78,29 @@ def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[s
     ]
     by_name = {l.name: l for l in step_layers}
     if len(step_layers) != 8 or not all(_clean(l) for l in step_layers):
-        return None
+        return no("step graph is not 8 clean layers")
 
     # anchor: the gru_step owning the memory
     gru = next((l for l in step_layers if l.type == "gru_step"), None)
     if gru is None or gru.name != mem.layer_name or len(gru.inputs) != 2:
-        return None
+        return no("no gru_step owning the memory")
     if gru.inputs[1].input_layer_name != mem.link_name:
-        return None
+        return no("gru_step does not read the memory")
     D = gru.size
 
     din = by_name.get(gru.inputs[0].input_layer_name)
     if din is None or din.type != "mixed" or din.size != 3 * D:
-        return None
+        return no("gru input is not a linear 3D-wide mixed layer")
     if din.active_type not in ("", "linear"):
-        return None
+        return no("gru input has an activation")
     # every din input except the context projection must be hoisted
     hoisted = set(pro_plan.get(din.name, ()))
     ctx_idx = [i for i in range(len(din.inputs)) if i not in hoisted]
     if len(ctx_idx) != 1:
-        return None
+        return no("not exactly one unhoisted (context) input")
     ctx_ic = din.inputs[ctx_idx[0]]
     if ctx_ic.proj_conf is None or ctx_ic.proj_conf.type != "fc":
-        return None
+        return no("context input is not an fc projection")
 
     pooling = by_name.get(ctx_ic.input_layer_name)
     if (
@@ -109,17 +111,17 @@ def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[s
         or pooling.active_type not in ("", "linear")
         or len(pooling.inputs) != 1
     ):
-        return None
+        return no("context is not a sum pooling")
 
     scaling = by_name.get(pooling.inputs[0].input_layer_name)
     if scaling is None or scaling.type != "scaling" or len(scaling.inputs) != 2:
-        return None
+        return no("no scaling layer under the pooling")
     sm_name, ev_link = (
         scaling.inputs[0].input_layer_name,
         scaling.inputs[1].input_layer_name,
     )
     if ev_link not in statics:
-        return None
+        return no("encoder values are not a static link")
 
     sm = by_name.get(sm_name)
     if (
@@ -130,7 +132,7 @@ def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[s
         or sm.bias_parameter_name
         or len(sm.inputs) != 1
     ):
-        return None
+        return no("attention weights are not a bias-free sequence_softmax fc")
 
     combine = by_name.get(sm.inputs[0].input_layer_name)
     if (
@@ -140,11 +142,11 @@ def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[s
         or combine.size != D
         or len(combine.inputs) != 2
     ):
-        return None
+        return no("no tanh combine of two inputs")
     comb_srcs = []
     for ic in combine.inputs:
         if ic.proj_conf is None or ic.proj_conf.type != "identity":
-            return None
+            return no("combine input is not an identity projection")
         comb_srcs.append(ic.input_layer_name)
 
     expand = next(
@@ -153,9 +155,9 @@ def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[s
     )
     ep_link = next((n for n in comb_srcs if n in statics), None)
     if expand is None or ep_link is None or ep_link == ev_link:
-        return None
+        return no("no expand + static encoder projection pair")
     if not expand.inputs or expand.inputs[0].input_layer_name not in by_name:
-        return None
+        return no("expand does not read a step layer")
 
     transform = by_name.get(expand.inputs[0].input_layer_name)
     if (
@@ -164,16 +166,16 @@ def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[s
         or transform.active_type not in ("", "linear")
         or transform.size != D
     ):
-        return None
+        return no("no linear D-wide attention transform")
     tr_ic = _single_proj(transform, "fc")
     if tr_ic is None or tr_ic.input_layer_name != mem.link_name:
-        return None
+        return no("attention transform does not read the memory")
 
     # the whole template accounted for?
     template = {gru.name, din.name, pooling.name, scaling.name, sm.name,
                 combine.name, expand.name, transform.name}
     if template != set(by_name):
-        return None
+        return no("layers outside the template")
     # in-links may only feed the hoisted din inputs
     in_link_names = {l.link_name for l in sub.in_links}
     for l in step_layers:
@@ -181,11 +183,11 @@ def match_decoder(network, sub, ctx, statics, skip, pro_plan) -> Optional[Dict[s
             if ic.input_layer_name in in_link_names and not (
                 l.name == din.name and i in hoisted
             ):
-                return None
+                return no("an in-link feeds an unhoisted input")
 
     gru_acts = (gru.active_type or "tanh", gru.active_gate_type or "sigmoid")
     if gru_acts != ("tanh", "sigmoid"):
-        return None
+        return no("gru activations are not tanh/sigmoid")
     return dict(
         gru=gru, din=din, transform=transform, combine=combine, softmax=sm,
         ctx_ic=ctx_ic, tr_ic=tr_ic, ep_link=ep_link, ev_link=ev_link, D=D,
@@ -198,36 +200,45 @@ def run_fused_decoder(network, sub, ctx, statics, plan, pro_feeds,
     the RAW per-step GRU output stream [T, B, D], or None when shapes
     fail the kernel gate (caller falls back to the scan)."""
     from paddle_tpu.ops import pallas_attention_gru as pag
+    from paddle_tpu.utils import device
+
+    def no(why):
+        device.log_selection("pallas_decoder", sub.name, f"scan path ({why})")
 
     D = plan["D"]
     gru, din = plan["gru"], plan["din"]
     ep_arg = statics[plan["ep_link"]]
     ev_arg = statics[plan["ev_link"]]
     if ep_arg.value is None or ev_arg.value is None or not ep_arg.is_seq:
-        return None
+        return no("encoder statics are not dense sequences")
     B, Te = ep_arg.value.shape[0], ep_arg.value.shape[1]
     E = ev_arg.value.shape[2]
     xw = pro_feeds.get(din.name)
     if xw is None or ep_arg.value.shape[2] != D:
-        return None
+        return no("no hoisted word-side input, or encoder projection not D wide")
     Td = xw.shape[0]
     dtype = xw.dtype
     if dtype not in (jnp.float32, jnp.bfloat16):
-        return None
-    interpret = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1"
+        return no(f"dtype {dtype}")
+    mode = device.pallas_mode()
+    interpret = mode == "interpret"
     data_extent = None
     if ctx.mesh is not None:
         from paddle_tpu.parallel.mesh import data_only_extent
 
         data_extent = data_only_extent(ctx.mesh)
         if data_extent is None or B % data_extent:
-            return None
+            return no(f"batch {B} does not split over the mesh's data axis")
     B_local = B // (data_extent or 1)
     # the lane-alignment/VMEM gate is a Mosaic-compile constraint; the
     # interpreter (CPU parity tests) takes any shape
     if not interpret and not pag.supported(B_local, Te, D, E,
                                            jnp.dtype(dtype).itemsize):
-        return None
+        return no(f"kernel gate refuses B={B_local} Te={Te} D={D} E={E} {dtype}")
+    device.log_selection(
+        "pallas_decoder", sub.name,
+        f"Pallas kernel, {mode}"
+        + (f", shard_map over data={data_extent}" if data_extent else ""))
 
     wa = ctx.param(plan["tr_ic"].input_parameter_name).reshape(D, D)
     v = ctx.param(plan["softmax"].inputs[0].input_parameter_name).reshape(D, 1)
@@ -258,10 +269,10 @@ def run_fused_decoder(network, sub, ctx, statics, plan, pro_feeds,
                                        interpret)
     # purely data-parallel mesh: per-shard execution (each shard's batch
     # rows are independent decodes); weights replicated, batch dims
-    # sharded (the version-compat lives in parallel/mesh.py).
+    # sharded.
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh import replicated_specs, shard_map_compat
+    from paddle_tpu.parallel.mesh import replicated_specs, shard_map_unchecked
 
     def shard_fn(ep_l, ev_l, em_l, xw_l, dm_l, h0_l, *ws):
         return pag.fused_attention_gru(ep_l, ev_l, em_l, xw_l, dm_l, h0_l,
@@ -269,6 +280,6 @@ def run_fused_decoder(network, sub, ctx, statics, plan, pro_feeds,
 
     seq_spec = P(None, "data")
     in_specs = (seq_spec,) * 5 + (P("data"),) + replicated_specs(*operands[6:])
-    return shard_map_compat(
+    return shard_map_unchecked(
         shard_fn, ctx.mesh, in_specs=in_specs, out_specs=seq_spec
     )(*operands)

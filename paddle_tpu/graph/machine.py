@@ -332,12 +332,8 @@ class GradientMachine:
         """
         saved = self.compute_dtype
         self.compute_dtype = None  # bf16 forward would swamp the FD signal
-        # jax >= 0.4.37 removed the jax.enable_x64 alias; the context
-        # manager lives (and always lived) in jax.experimental
-        from jax.experimental import enable_x64
-
         try:
-            with enable_x64():
+            with jax.enable_x64(True):
                 return self._check_gradient_x64(params, in_args, epsilon, max_entries, rng, rtol)
         finally:
             self.compute_dtype = saved

@@ -107,16 +107,23 @@ def lstm_cell_step(
 
 def _pallas_rnn_path(ctx, cfg, a, x, mask, w, bias, usable_fn, fwd_fn):
     """The fused Pallas kernel path shared by lstmemory/gated_recurrent,
-    or None to take the scan. Gating: TPU backend (non-TPU would run the
-    Python interpreter — tests force it via PADDLE_TPU_PALLAS_INTERPRET=1,
-    production falls back to the scan); shapes/activations/VMEM checked
-    by the kernel's usable(). Meshes: single-device, or a purely
+    or None to take the scan. Gating: a TPU backend (off it the kernel
+    body would run in the Python interpreter — only the parity tests ask
+    for that, see utils/device.pallas_mode); shapes/activations/VMEM
+    checked by the kernel's usable(). Meshes: single-device, or a purely
     data-parallel mesh — there the kernel runs per-shard under shard_map
     (each shard's batch rows are independent sequences); any non-trivial
     model/seq axis falls back to the scan, whose ops GSPMD can partition.
-    Callers guard on ctx.pallas_rnn BEFORE importing the kernel module,
-    keeping the ops import lazy on the default path."""
+    Either way the choice is logged once per layer at debug. Callers
+    guard on ctx.pallas_rnn BEFORE importing the kernel module, keeping
+    the ops import lazy on the default path."""
     import os
+
+    from paddle_tpu.utils import device
+
+    def scan(why):
+        device.log_selection("pallas_rnn", cfg.name, f"scan path ({why})")
+        return None
 
     data_extent = None
     T, B = mask.shape
@@ -124,19 +131,23 @@ def _pallas_rnn_path(ctx, cfg, a, x, mask, w, bias, usable_fn, fwd_fn):
         from paddle_tpu.parallel.mesh import data_only_extent
 
         data_extent = data_only_extent(ctx.mesh)
-        if data_extent is None or B % data_extent:
-            return None
-    on_tpu = jax.default_backend() == "tpu"
-    force_interpret = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1"
-    if not (on_tpu or force_interpret):
-        return None
-    if data_extent is not None:
-        # gate on the PER-SHARD batch the kernel will actually see
-        local = jax.ShapeDtypeStruct((T, B // data_extent, x.shape[2]), x.dtype)
-        if not usable_fn(cfg, local):
-            return None
-    elif not usable_fn(cfg, x):
-        return None
+        if data_extent is None:
+            return scan("mesh has a non-data axis")
+        if B % data_extent:
+            return scan(f"batch {B} not divisible by data={data_extent}")
+    mode = device.pallas_mode()
+    if mode is None:
+        return scan(device.why_no_pallas())
+    interpret = mode == "interpret"
+    # gate on the PER-SHARD batch the kernel will actually see
+    local = jax.ShapeDtypeStruct(
+        (T, B // (data_extent or 1), x.shape[2]), x.dtype)
+    if not usable_fn(cfg, local):
+        return scan(f"kernel gate refuses {local.shape} {local.dtype}")
+    device.log_selection(
+        "pallas_rnn", cfg.name,
+        f"Pallas kernel, {mode}"
+        + (f", shard_map over data={data_extent}" if data_extent else ""))
     # transpose-free interface — the kernel reads the projection
     # output's batch-major value through a free [B, T*width] reshape
     # instead of a materialized time-major swap (A/B knob; flip the
@@ -145,26 +156,23 @@ def _pallas_rnn_path(ctx, cfg, a, x, mask, w, bias, usable_fn, fwd_fn):
     # still forces it for configs that can't be edited.
     flat = ctx.pallas_flat or os.environ.get("PADDLE_TPU_PALLAS_FLAT") == "1"
     x_bt = a.value if flat else None
-    # the env flag wins even on TPU so a compiled-kernel discrepancy can
-    # be A/B'd in interpret mode on the device where it manifests (off
-    # TPU the guard above already required the flag)
     if data_extent is None:
-        ys = fwd_fn(cfg, x, mask, w, bias, interpret=force_interpret, x_bt=x_bt)
+        ys = fwd_fn(cfg, x, mask, w, bias, interpret=interpret, x_bt=x_bt)
     else:
         from jax.sharding import PartitionSpec as P
 
-        from paddle_tpu.parallel.mesh import replicated_specs, shard_map_compat
+        from paddle_tpu.parallel.mesh import replicated_specs, shard_map_unchecked
 
         def shard_fn(xin, mask_l, *wb):
             w_l = wb[0]
             bias_l = wb[1] if len(wb) > 1 else None
             return fwd_fn(cfg, xin, mask_l, w_l, bias_l,
-                          interpret=force_interpret,
+                          interpret=interpret,
                           x_bt=xin if flat else None)
 
         x_spec = P("data") if flat else P(None, "data")
         wb_args = (w,) if bias is None else (w, bias)
-        ys = shard_map_compat(
+        ys = shard_map_unchecked(
             shard_fn, ctx.mesh,
             in_specs=(x_spec, P(None, "data")) + replicated_specs(*wb_args),
             out_specs=x_spec,  # ys shards on batch exactly like x

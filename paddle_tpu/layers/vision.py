@@ -179,18 +179,20 @@ def _conv1x1_stats_forward(cfg: LayerConfig, inputs: List[Argument],
     (no GSPMD partitioning rule for the custom call), TPU backend or
     forced interpret mode, and kernel shape/VMEM support.
     """
-    import os
+    from paddle_tpu.utils import device
+
+    def xla(why):
+        device.log_selection("conv_stats_pallas", cfg.name, f"XLA conv ({why})")
 
     if ctx.mesh is not None:
-        return None
+        return xla("under a mesh")
     in_cfg = _fused_stats_gates(cfg, ctx)
     if in_cfg is None:
-        return None
+        return xla("not a 1x1 conv feeding a batch_norm in training")
     cc = in_cfg.conv_conf
-    on_tpu = jax.default_backend() == "tpu"
-    force_interpret = os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1"
-    if not (on_tpu or force_interpret):
-        return None
+    mode = device.pallas_mode()
+    if mode is None:
+        return xla(device.why_no_pallas())
     from paddle_tpu.ops import pallas_conv1x1_bn as pcb
 
     h = w = cc.img_size
@@ -198,13 +200,15 @@ def _conv1x1_stats_forward(cfg: LayerConfig, inputs: List[Argument],
     B = x.shape[0]
     M, K, N = B * h * w, cc.channels, cfg.num_filters
     if not pcb.supported(M, K, N, x.dtype.itemsize):
-        return None
+        return xla(f"kernel gate refuses M={M} K={K} N={N} {x.dtype}")
+    device.log_selection("conv_stats_pallas", cfg.name, f"Pallas kernel, {mode}")
     wf = ctx.param(in_cfg.input_parameter_name).reshape(N, K)
     if cfg.bias_parameter_name:
         b = ctx.param(cfg.bias_parameter_name).reshape(N).astype(x.dtype)
     else:
         b = jnp.zeros((N,), x.dtype)
-    y2, s, q = pcb.conv1x1_stats(x.reshape(M, K), wf.T, b, force_interpret)
+    y2, s, q = pcb.conv1x1_stats(x.reshape(M, K), wf.T, b,
+                                 mode == "interpret")
     ctx.conv_stats[cfg.name] = (s, q, M)
     return _publish_nhwc(ctx, cfg, y2.reshape(B, h, w, N))
 

@@ -18,7 +18,9 @@ record and makes the cache persistent:
   (``mode="inline"``) — the telemetry never loses a compile, it just
   reports it coarser.
 - :func:`enable_compile_cache` wires jax's persistent compilation cache
-  to ``--compile_cache_dir``: warm restarts skip the XLA backend
+  to the ONE directory :func:`resolve_cache_dir` names
+  (``JAX_COMPILATION_CACHE_DIR``, else ``--compile_cache_dir``, else a
+  fixed path in the checkout): warm restarts skip the XLA backend
   compile, and the compile records prove it (``cache_hit=true``, lower
   ``time_to_first_step_s`` in the PR-6 ``restart`` record).
 - The registry also accumulates per-group execution time
@@ -35,42 +37,68 @@ stay per-host honest ("this host's compile did not add an entry").
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import os
+import re
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
+import jax
+
 from paddle_tpu.observability import metrics as obs
+from paddle_tpu.utils.device import device_stamp
 from paddle_tpu.utils.logging import logger
 
 # the enabled persistent-cache dir ("" = off) — module state, one per
 # process, matching jax's own process-global cache config
 _cache_dir: str = ""
 
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# the cache's path is part of its key, so the default never moves: one
+# directory inside the checkout (git-ignored), the same for every entry
+# point and every process — never a temp dir, a pid or a timestamp
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-def enable_compile_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``
-    (created if missing). Also drops the min-compile-time/entry-size
-    gates so even fast CPU-backend compiles populate the cache — without
-    that, smoke-scale steps would never cache and a warm restart would
-    measure nothing. Returns True when the cache is active; never
-    raises (telemetry must not take down the run it observes)."""
+
+def resolve_cache_dir(flag_dir: str = "") -> str:
+    """THE place the persistent compilation cache lives, for every entry
+    point (`paddle train|serve`, bench.py, chip_smoke.py, the tests):
+    where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory and no
+    other — the machine's owner placed the cache, and no flag overrides
+    it; otherwise the caller's ``--compile_cache_dir``; otherwise the
+    fixed in-checkout :data:`DEFAULT_CACHE_DIR`."""
+    return os.environ.get(CACHE_DIR_ENV) or flag_dir or DEFAULT_CACHE_DIR
+
+
+def enable_compile_cache(flag_dir: str = "") -> bool:
+    """Point jax's persistent compilation cache at
+    :func:`resolve_cache_dir` (created if missing) — call before the
+    first compile. On by default everywhere but on an explicit
+    ``JAX_PLATFORMS=cpu`` run, which gets it only by naming a directory
+    (variable or flag). Also drops the min-compile-time/entry-size gates so
+    even fast CPU-backend compiles populate the cache — without that,
+    smoke-scale steps would never cache and a warm restart would measure
+    nothing. Returns True when the cache is active; never raises
+    (telemetry must not take down the run it observes)."""
     global _cache_dir
-    if not cache_dir:
+    named = os.environ.get(CACHE_DIR_ENV) or flag_dir
+    if not named and os.environ.get("JAX_PLATFORMS") == "cpu":
+        # an explicit CPU run (the tests, debugging) gets no cache it did
+        # not ask for by name: XLA:CPU in jax 0.9.0 logs ~8 KB of
+        # target-feature complaints for EVERY cached executable it loads
+        # — a flood on stderr that stalls a child whose parent does not
+        # drain the pipe — and test-sized CPU compiles are fast anyway
         return False
+    cache_dir = named or DEFAULT_CACHE_DIR
     try:
-        import jax
-
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        for name, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ):
-            try:
-                jax.config.update(name, val)
-            except Exception:
-                pass  # older jax: its defaults apply
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         _cache_dir = cache_dir
         logger.info("persistent compilation cache: %s", cache_dir)
         return True
@@ -109,6 +137,45 @@ def cache_probe() -> Callable[[], Optional[bool]]:
         return after == before
 
     return hit
+
+
+_MOSAIC_RE = re.compile(
+    r"= (.*?) custom-call\(.*custom_call_target=\"tpu_custom_call\"")
+_COLLECTIVE_RE = re.compile(
+    r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(?:-start)?\(")
+
+
+def hlo_census(compiled) -> Dict[str, Any]:
+    """What the optimized (and, on a mesh, partitioned) program of one
+    AOT executable contains — the only proof a kernel or a collective is
+    really in the step, since every kernel selection upstream is silent:
+
+    - ``mosaic_calls``: Pallas/Mosaic kernels (``tpu_custom_call``), and
+      ``mosaic_shapes`` their distinct result shapes — PER-DEVICE shapes,
+      so a kernel under ``shard_map`` shows its shard's batch;
+    - ``collectives``: count per collective op (absent when none);
+    - ``devices``: distinct devices the executable's inputs live on, and
+      ``sharded_inputs``: how many inputs are split (not replicated)
+      over them.
+
+    Empty when the backend offers no HLO text (never raises)."""
+    try:
+        text = compiled.as_text()
+        shardings = jax.tree_util.tree_leaves(compiled.input_shardings)
+    except Exception:
+        return {}
+    shapes = [re.sub(r"\{[^}]*\}", "", m) for m in _MOSAIC_RE.findall(text)]
+    out: Dict[str, Any] = {"mosaic_calls": len(shapes)}
+    if shapes:
+        out["mosaic_shapes"] = sorted(set(shapes))
+    ops = collections.Counter(_COLLECTIVE_RE.findall(text))
+    if ops:
+        out["collectives"] = dict(sorted(ops.items()))
+    out["devices"] = len({d.id for sh in shardings for d in sh.device_set}) or 1
+    out["sharded_inputs"] = sum(
+        1 for sh in shardings if not sh.is_fully_replicated)
+    return out
 
 
 def sig_hash(key: Any) -> str:
@@ -216,6 +283,9 @@ class CompileRegistry:
             # recompiled (new batch signature / rollback invalidation —
             # lifetime count, so invalidate() cannot reset it to 0)
             "recompiles": self._group_compiles.get(group, 0),
+            # the device this compile was FOR, as jax reports it — a
+            # compile record read off another machine says where it ran
+            **device_stamp(),
         }
         self._group_compiles[group] = self._group_compiles.get(group, 0) + 1
         hit_probe = cache_probe()
@@ -243,6 +313,7 @@ class CompileRegistry:
                 # the first step runs — the raw material of
                 # `paddle memory` and the OOM pre-mortem
                 mem = memory_analysis_of(compiled)
+                rec.update(hlo_census(compiled))
                 callable_ = compiled
             except Exception as e:
                 logger.debug(

@@ -48,15 +48,13 @@ def cost_analysis_of(compiled) -> Optional[Dict[str, float]]:
     """FLOPs / bytes accessed of one compiled executable, or None.
 
     Graceful by contract: backends without cost analysis (or raising
-    from it), list-shaped returns (older jax), and missing keys all
+    from it), non-dict returns and missing keys all
     collapse to None / absent keys — accounting must never be able to
     break training (same covenant as ``_count_model_flops``)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     out: Dict[str, float] = {}

@@ -23,13 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # unavailable when jax has no TPU platform registered (CPU test env)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # noqa: BLE001
-    pltpu = None
-
-from paddle_tpu.ops.pallas_compat import compiler_params as _compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -156,12 +150,8 @@ def _dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
-def _len_spec(B):
-    # full lengths vector visible to every program — scalar memory on TPU,
-    # a plain whole-array block under the interpreter
-    if pltpu is not None:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec((B,), lambda b, h, i: (0,))
+# full lengths vector visible to every program — scalar memory
+_LEN_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _run_fwd(q, k, v, lengths, causal, bq, bk, interpret):
@@ -173,14 +163,14 @@ def _run_fwd(q, k, v, lengths, causal, bq, bk, interpret):
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, block_k=bk, scale=scale),
         grid=(B, H, T // bq),
-        in_specs=[_len_spec(B), qspec, kvspec, kvspec],
+        in_specs=[_LEN_SPEC, qspec, kvspec, kvspec],
         out_specs=[qspec, lse_spec],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, T), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(lengths, q, k, v)
@@ -202,11 +192,11 @@ def _run_bwd(q, k, v, do, out, lse, lengths, causal, bq, bk, interpret):
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, block_k=bk, scale=scale),
         grid=(B, H, T // bq),
-        in_specs=[_len_spec(B), qspec, kv_full, kv_full, qspec, stat_q, stat_q],
+        in_specs=[_LEN_SPEC, qspec, kv_full, kv_full, qspec, stat_q, stat_q],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         interpret=interpret,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(lengths, q, k, v, do, lse, delta)
@@ -215,14 +205,14 @@ def _run_bwd(q, k, v, do, out, lse, lengths, causal, bq, bk, interpret):
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, block_q=bq, scale=scale),
         grid=(B, H, T // bk),
-        in_specs=[_len_spec(B), kv_full, k_blk, k_blk, kv_full, stat_full, stat_full],
+        in_specs=[_LEN_SPEC, kv_full, k_blk, k_blk, kv_full, stat_full, stat_full],
         out_specs=[k_blk, k_blk],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, T, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, T, D), v.dtype),
         ],
         interpret=interpret,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(lengths, q, k, v, do, lse, delta)

@@ -117,8 +117,6 @@ def _vmem_bwd(bb: int, Te: int, D: int, E: int, itemsize: int) -> int:
 
 
 def supported(B: int, Te: int, D: int, E: int, itemsize: int = 2) -> bool:
-    if pltpu is None:
-        return False
     if D % 128 != 0 or E % 128 != 0:
         return False
     bwd = lambda bb: _vmem_bwd(bb, Te, D, E, itemsize)
@@ -136,11 +134,12 @@ def _attention(ep, em, v, m, Te):
     f32 = jnp.float32
     combined = jnp.tanh(ep.astype(f32) + m.astype(f32)[None, :, :])
     s = jnp.sum(combined * v.astype(f32)[None, :, :], axis=-1)      # [Te,bB]
-    s = jnp.where(em[:, :, 0] > 0, s, -1e30)
+    valid = em.astype(f32)[:, :, 0] > 0
+    s = jnp.where(valid, s, -1e30)
     smax = jnp.max(s, axis=0, keepdims=True)
     e = jnp.exp(s - smax)
     alpha = e / jnp.sum(e, axis=0, keepdims=True)
-    alpha = jnp.where(em[:, :, 0] > 0, alpha, 0.0)
+    alpha = jnp.where(valid, alpha, 0.0)
     return combined, alpha
 
 
@@ -273,9 +272,7 @@ def _run_fwd(ep, ev, em, xw, dmask, h0, wa, ba, v, wctx, wg,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bb, D), jnp.float32)]
-        if pltpu is not None
-        else [],
+        scratch_shapes=[pltpu.VMEM((bb, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_params(2),
     )(ep, ev, em, xw, dmask, h0, wa, ba, v, wctx, wg)
@@ -439,9 +436,7 @@ def _run_bwd(dy, ep, ev, em, dmask, hprev, acts3, alphas,
             jax.ShapeDtypeStruct(ba.shape, f32),
             jax.ShapeDtypeStruct(v.shape, f32),
         ],
-        scratch_shapes=[pltpu.VMEM((bb, D), jnp.float32)]
-        if pltpu is not None
-        else [],
+        scratch_shapes=[pltpu.VMEM((bb, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_params(2),
     )(dy, ep, ev, em, dmask, hprev, acts3, alphas, wa, ba, v, wctx, wg)

@@ -41,11 +41,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # unavailable when jax has no TPU platform registered (CPU test env)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # noqa: BLE001
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -98,8 +94,6 @@ def _vmem_bytes(bm: int, bn: int, bk: int, N: int, itemsize: int) -> int:
 
 def blocks_for(M: int, K: int, N: int, itemsize: int):
     """(bm, bn, bk) if the kernel supports this shape, else None."""
-    if pltpu is None:
-        return None
     bm, bn, bk = _pick_bm(M), _pick_bn(N), _pick_bk(K)
     if bm is None or bn is None or bk is None:
         return None
@@ -146,16 +140,13 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, s_ref, q_ref, acc_scr, *, bn: int, nk: i
 def _run(x: Array, w: Array, b: Array, interpret: bool):
     M, K = x.shape
     _, N = w.shape
-    # blocks_for returned non-None (callers gate on supported()), which
-    # implies pltpu imported — no pltpu-less branch exists below
     blocks = blocks_for(M, K, N, x.dtype.itemsize)
     assert blocks is not None, (M, K, N)
     bm, bn, bk = blocks
     nm, nn, nk = M // bm, N // bn, K // bk
     kernel = functools.partial(_kernel, bn=bn, nk=nk)
-    from paddle_tpu.ops.pallas_compat import compiler_params as _cp
-
-    compiler_params = _cp(dimension_semantics=("arbitrary",) * 3)
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",) * 3)
     y, s, q = pl.pallas_call(
         kernel,
         grid=(nm, nn, nk),

@@ -181,7 +181,7 @@ def _run_fwd(x3, mask_tb1, w, acts, interpret, residuals=True, flat=False):
         in_specs=[x_spec, mask_spec, wspec],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)] if pltpu is not None else [],
+        scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)],
         interpret=interpret,
         compiler_params=_params(1),
     )(x3, mask_tb1, w)
@@ -212,7 +212,7 @@ def _run_bwd(dy, acts_seq, hprev, mask_tb1, w, acts, interpret, flat=False):
             dx_shape,
             jax.ShapeDtypeStruct(w.shape, jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)] if pltpu is not None else [],
+        scratch_shapes=[pltpu.VMEM((B, H), jnp.float32)],
         interpret=interpret,
         compiler_params=_params(1),
     )(dy, acts_seq, hprev, mask_tb1, w)

@@ -34,11 +34,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # unavailable when jax has no TPU platform registered (CPU test env)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # noqa: BLE001
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -94,11 +90,10 @@ def _bwd_vmem_bytes(B: int, H: int, gates: int, itemsize: int,
 
 def shape_ok(acts, B: int, H: int, gates: int, itemsize: int,
              f32_state: bool) -> bool:
-    """Shared kernel gate: TPU pallas available, whitelisted activations,
-    MXU-friendly tiling, and the backward's VMEM residency fits."""
+    """Shared kernel gate: whitelisted activations, MXU-friendly tiling,
+    and the backward's VMEM residency fits."""
     return (
-        pltpu is not None  # kernels need TPU scratch shapes even interpreted
-        and all(a in _ACTS for a in acts)
+        all(a in _ACTS for a in acts)
         and H % 128 == 0 and B % 8 == 0
         and _bwd_vmem_bytes(B, H, gates, itemsize, f32_state) < _VMEM_BUDGET_BYTES
     )
@@ -255,9 +250,7 @@ def _bwd_kernel(dy_ref, acts_ref, hprev_ref, cprev_ref, m_ref, w_ref, peep_ref,
 
 
 def _params(n):
-    from paddle_tpu.ops.pallas_compat import compiler_params
-
-    return compiler_params(dimension_semantics=("arbitrary",) * n)
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",) * n)
 
 
 def _run_fwd(x4, mask_tb1, w, peep, acts, interpret, residuals=True,
@@ -313,7 +306,7 @@ def _run_fwd(x4, mask_tb1, w, peep, acts, interpret, residuals=True,
         scratch_shapes=[
             pltpu.VMEM((B, H), jnp.float32),
             pltpu.VMEM((B, H), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
         interpret=interpret,
         compiler_params=_params(1),
     )(x4, mask_tb1, w, peep)
@@ -351,7 +344,7 @@ def _run_bwd(dy, saved, mask_tb1, w, peep, acts, interpret, flat=False):
         scratch_shapes=[
             pltpu.VMEM((B, H), jnp.float32),
             pltpu.VMEM((B, H), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
         interpret=interpret,
         compiler_params=_params(1),
     )(dy, acts_seq, hprev, cprev, mask_tb1, w, peep)

@@ -145,23 +145,11 @@ def data_only_extent(mesh: Mesh):
     return d if d > 1 else None
 
 
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions, with replication checking off:
-    pallas_call out_shapes carry no varying-mesh-axes annotation, which
-    the new type system (check_vma) would reject; older jax spells the
-    knob check_rep (and lives in jax.experimental.shard_map). The kwarg
-    probe happens HERE, eagerly, so a TypeError from tracing user code
-    can never be misread as a version mismatch."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+def shard_map_unchecked(fn, mesh: Mesh, in_specs, out_specs):
+    """shard_map with the varying-mesh-axes check off: pallas_call
+    out_shapes carry no such annotation, which check_vma would reject."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def replicated_specs(*arrays):

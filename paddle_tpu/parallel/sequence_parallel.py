@@ -63,18 +63,25 @@ def full_attention(
     Outputs at padded query rows (positions >= lengths) are unspecified
     and differ between the flash and XLA paths — callers must mask them
     (the mha layer does)."""
-    if (
-        q_offset == 0
-        and kv_offset == 0
-        and q.shape == k.shape
-        and jax.default_backend() == "tpu"
-    ):
+    from paddle_tpu.utils import device
+
+    # one debug line per shape: which attention ran and why
+    site = f"T={q.shape[1]} D={q.shape[3]}"
+    if q_offset or kv_offset or q.shape != k.shape:
+        why = "not plain self-attention"
+    elif jax.default_backend() != "tpu":
+        why = device.why_no_pallas()
+    else:
         from paddle_tpu.ops import pallas_attention
 
         if pallas_attention.supported(q.shape[1], q.shape[3]):
+            device.log_selection("flash_attention", site,
+                                 "Pallas kernel, compiled")
             return pallas_attention.tpu_flash_attention(
                 q, k, v, lengths=lengths, causal=causal
             )
+        why = "kernel gate refuses the shape"
+    device.log_selection("flash_attention", site, f"XLA path ({why})")
     D = q.shape[-1]
     # scores and softmax in f32 even for bf16 q/k/v: the QK matmul takes
     # bf16 operands with an f32 result; p stays f32 through the PV matmul
@@ -209,12 +216,7 @@ def _sharded_attention(q, k, v, lengths, mesh: Mesh, *, causal: bool, axis: str,
     len_spec = P(b_spec)
     shard_fn = functools.partial(local_fn, causal=causal, axis_name=axis)
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
-    mapped = shard_map(
+    mapped = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec, len_spec),

@@ -318,7 +318,7 @@ def shard_train_step(step, mesh: Mesh, gm, donate: bool = True,
     bs = batch_sharding(mesh)
     cache: Dict[Any, Any] = {}
 
-    def call(params, opt_state, batch, rng, batch_size):
+    def jitted(params, opt_state, batch):
         treedef = jax.tree_util.tree_structure((opt_state, batch))
         fn = cache.get(treedef)
         if fn is None:
@@ -335,8 +335,18 @@ def shard_train_step(step, mesh: Mesh, gm, donate: bool = True,
                 donate_argnums=(0, 1) if donate else (),
             )
             cache[treedef] = fn
-        return fn(params, opt_state, batch, rng, batch_size)
+        return fn
 
+    def call(params, opt_state, batch, rng, batch_size):
+        return jitted(params, opt_state, batch)(
+            params, opt_state, batch, rng, batch_size)
+
+    # the AOT seam CompileRegistry compiles through: the mesh step gets
+    # the same split trace/compile timing, cost and memory analysis and
+    # HLO census (kernels, collectives, devices) as the one-device step
+    call.lower = lambda params, opt_state, batch, rng, batch_size: jitted(
+        params, opt_state, batch).lower(
+            params, opt_state, batch, rng, batch_size)
     return call
 
 

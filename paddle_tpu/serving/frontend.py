@@ -25,6 +25,7 @@ import signal
 import sys
 from typing import Any, Dict, List, Optional, Tuple
 
+from paddle_tpu.observability.memory import sample_and_emit
 from paddle_tpu.utils import concurrency as cc
 
 
@@ -160,6 +161,9 @@ def _serve_listen(engine, journal, status, reloader) -> int:
         journal.close()
     if obsm.enabled():
         engine.window_roll()
+        # the closing kind=memory record: the allocator's peak HBM over
+        # the whole serve (absent on stat-less backends), host RSS always
+        sample_and_emit()
         obsm.emit("run_end", status="completed")
         obsm.flush()
     print("# paddle serve: drained", file=sys.stderr)
@@ -172,18 +176,19 @@ def main(rest: List[str]) -> int:
     leftover = FLAGS.parse(list(rest))
     if leftover:
         print(f"warning: unrecognized flags {leftover}", file=sys.stderr)
-    if not FLAGS.use_tpu:
-        # before ANYTHING imports jax (jax reads JAX_PLATFORMS once at
-        # import), and therefore before the compile-cache block below
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if FLAGS.compile_cache_dir:
-        # warm serve restarts skip the XLA backend compile of
-        # serve_prefill/serve_decode — the compile records land with
-        # cache_hit=true and Engine.start()'s warmup (time-to-first-
-        # token-ready) drops to trace time (ROADMAP item 5 for serving)
-        from paddle_tpu.observability.compile_log import enable_compile_cache
+    # before ANYTHING imports jax (it reads JAX_PLATFORMS once at import):
+    # --use_tpu is a requirement, not a hint — utils/device.py
+    from paddle_tpu.utils.device import describe_devices, select_platform
 
-        enable_compile_cache(FLAGS.compile_cache_dir)
+    select_platform(FLAGS.use_tpu)
+    # the persistent compilation cache, at the one place
+    # compile_log.resolve_cache_dir names: warm serve restarts skip the
+    # XLA backend compile of serve_prefill/serve_decode — the compile
+    # records land with cache_hit=true and Engine.start()'s warmup
+    # (time-to-first-token-ready) drops to trace time
+    from paddle_tpu.observability.compile_log import enable_compile_cache
+
+    enable_compile_cache(FLAGS.compile_cache_dir)
     if not FLAGS.config:
         print("error: --config is required", file=sys.stderr)
         return 2
@@ -198,8 +203,6 @@ def main(rest: List[str]) -> int:
 
         faultinject.configure(FLAGS.fault_spec, FLAGS.fault_seed)
 
-    import jax
-
     from paddle_tpu import api
     from paddle_tpu.observability.compile_log import CompileRegistry
     from paddle_tpu.resilience import EXIT_OOM
@@ -212,13 +215,14 @@ def main(rest: List[str]) -> int:
         WeightReloader,
     )
 
+    device = describe_devices("paddle serve")
     am = api.GradientMachine(config.model_config, seed=FLAGS.seed)
     if FLAGS.init_model_path:
         am.loadParameters(FLAGS.init_model_path)
     else:
         print("# serving randomly initialized parameters "
               "(no --init_model_path)", file=sys.stderr)
-    registry = CompileRegistry(device_kind=jax.devices()[0].device_kind)
+    registry = CompileRegistry(device_kind=device["device_kind"])
     # forensics land next to the telemetry (or the cwd, telemetry-less):
     # serve_hang_report.json / oom_report.json — where `paddle
     # supervise` looks for them
@@ -477,6 +481,9 @@ def main(rest: List[str]) -> int:
         journal.close()
     if obsm.enabled():
         engine.window_roll()
+        # the closing kind=memory record: the allocator's peak HBM over
+        # the whole serve (absent on stat-less backends), host RSS always
+        sample_and_emit()
         obsm.emit("run_end", status="completed")
         obsm.flush()
     print("# paddle serve: drained", file=sys.stderr)

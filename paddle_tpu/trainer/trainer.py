@@ -112,6 +112,17 @@ class Trainer:
         self._t_construct = time.perf_counter()
         self.config = config
         self.flags = flags
+        from paddle_tpu.utils.device import describe_devices
+
+        # fails here, naming the reason, when the platform the run asked
+        # for (--use_tpu) is not there — never carries on elsewhere
+        device = describe_devices("trainer")
+        from paddle_tpu.native import get_lib
+
+        # builds datapath.cc on first use; a missing toolchain degrades to
+        # NumPy packing — say which this run got, not only when it failed
+        logger.info("native datapath: %s",
+                    "loaded" if get_lib() is not None else "NumPy fallback")
         dtype = jnp.float32
         if flags.use_double:
             # the reference's WITH_DOUBLE build; mostly for gradient checks
@@ -151,6 +162,11 @@ class Trainer:
 
             self._mesh = make_mesh(mesh_shape)
             self.gm.mesh = self._mesh  # layers with explicit collectives
+        elif device["device_count"] > 1:
+            logger.info(
+                "no --mesh_shape: training on device 0 of %d (pass "
+                "--mesh_shape=data=%d for data-parallel SGD over all of them)",
+                device["device_count"], device["device_count"])
         # sync-SGD over a data-parallel mesh needs every device to get an
         # identical batch slice: batches whose size is not divisible by
         # the data axis (the end-of-pass remainder) are skipped, matching
@@ -384,14 +400,16 @@ class Trainer:
         # compile & cost attribution (doc/observability.md "Compile
         # telemetry"): every launch-group compilation becomes a
         # kind=compile record (trace/compile seconds, cache hit/miss,
-        # XLA cost analysis), and --compile_cache_dir persists compiled
+        # XLA cost analysis), and the persistent cache keeps compiled
         # executables across processes so elastic relaunches stop
-        # re-paying the full trace+compile (ROADMAP item 5)
+        # re-paying the full trace+compile. `paddle train` (cli._setup)
+        # enabled it already; a program that builds a Trainer itself
+        # asks through flags.compile_cache_dir — either way the place is
+        # compile_log.resolve_cache_dir's
         if getattr(flags, "compile_cache_dir", ""):
             compile_log.enable_compile_cache(flags.compile_cache_dir)
         self._compiles = compile_log.CompileRegistry(
-            device_kind=jax.devices()[0].device_kind
-        )
+            device_kind=device["device_kind"])
         # hang defense (doc/resilience.md "Hang detection"): the step
         # loop pings the watchdog at every launch boundary; a stall
         # beyond --step_hang_timeout dumps forensics (hang_report.json

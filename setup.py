@@ -85,7 +85,7 @@ try:
         """The bundled library is ctypes-loaded — no CPython ABI — so the
         wheel must stay py3-none-<plat>, not cp3X-cp3X-<plat>: an
         interpreter-specific tag would lock out other supported Python
-        versions (requires-python >= 3.10) for no reason."""
+        versions (requires-python >= 3.11) for no reason."""
 
         def get_tag(self):
             python, abi, plat = super().get_tag()

@@ -1,0 +1,151 @@
+"""The Pallas kernels the model DSL can select, compiled by the TPU's own
+compiler for a DESCRIBED v5e (nothing attached, nothing run) at the widths
+of the model that uses each one — section 2 of the on-chip-measurement
+guide. Interpret mode cannot see what Mosaic refuses (unaligned slices,
+lane broadcasts, VMEM); this can, at no chip time.
+
+Every case also pins the gate: where ``supported()`` says yes the kernel
+compiles, and a shape the compiler refuses is one the gate refuses too.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+# libtpu lets one process at a time in (a lock file under /tmp), because a
+# chip takes one owner. Nothing here touches a chip, and test runners work
+# in several processes at once: let each load its own copy
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device. The persistent compile cache is off
+    around these compiles: an executable compiled for a described device
+    is written to it but cannot be read back without a chip (the guide's
+    section 2), so the next run would only warn and compile again."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, shapes, chip, grad):
+    """Compile ``fn`` (and its backward when ``grad``) for the described
+    chip; returns the optimized HLO text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    if grad:
+        diff = tuple(i for i, (_, d) in enumerate(shapes)
+                     if jnp.issubdtype(d, jnp.floating))
+        target = jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(F32)), argnums=diff)
+    else:
+        target = fn
+    return jax.jit(target).lower(*args).compile().as_text()
+
+
+# ------------------------------------------------------------------ cases
+
+
+def _lstm(B, H, dtype, flat, T=8):
+    from paddle_tpu.ops import pallas_lstm as pk
+
+    acts = ("tanh", "sigmoid", "tanh")
+    x = ((B, T * 4 * H) if flat else (T, B, 4 * H), dtype)
+    shapes = [x, ((T, B), dtype), ((H, 4 * H), dtype), ((3, H), dtype)]
+    fn = lambda x4, m, w, p: pk.fused_lstm(x4, m, w, p, acts, False, flat)
+    return fn, shapes, pk.supported(*acts, B, H, jnp.dtype(dtype).itemsize)
+
+
+def _gru(B, H, dtype, flat, T=8):
+    from paddle_tpu.ops import pallas_gru as pg
+
+    acts = ("tanh", "sigmoid")
+    x = ((B, T * 3 * H) if flat else (T, B, 3 * H), dtype)
+    shapes = [x, ((T, B), dtype), ((H, 3 * H), dtype)]
+    fn = lambda x3, m, w: pg.fused_gru(x3, m, w, acts, False, flat)
+    return fn, shapes, pg.supported(*acts, B, H, jnp.dtype(dtype).itemsize)
+
+
+def _attention_gru(B, dtype, Te=32, Td=32, D=512, E=1024):
+    from paddle_tpu.ops import pallas_attention_gru as pag
+
+    shapes = [((Te, B, D), dtype), ((Te, B, E), dtype), ((Te, B, 1), dtype),
+              ((Td, B, 3 * D), dtype), ((Td, B, 1), dtype), ((B, D), dtype),
+              ((D, D), dtype), ((1, D), dtype), ((1, D), dtype),
+              ((E, 3 * D), dtype), ((D, 3 * D), dtype)]
+    fn = lambda *a: pag.fused_attention_gru(*a, ("tanh", "sigmoid"), False)
+    return fn, shapes, pag.supported(B, Te, D, E, jnp.dtype(dtype).itemsize)
+
+
+def _flash(T, D, dtype, B=2, H=4):
+    """What layers/attention.py runs on a TPU backend: the library flash
+    kernel behind ``tpu_flash_attention`` (demo/long_context: dim 64 over
+    4 heads), gated by ``pallas_attention.supported``."""
+    from paddle_tpu.ops import pallas_attention as pa
+
+    shapes = [((B, T, H, D), dtype)] * 3 + [((B,), jnp.int32)]
+    fn = lambda q, k, v, n: pa.tpu_flash_attention(q, k, v, lengths=n,
+                                                   causal=True)
+    return fn, shapes, pa.supported(T, D)
+
+
+def _conv1x1(M, K, N, dtype):
+    from paddle_tpu.ops import pallas_conv1x1_bn as pcb
+
+    shapes = [((M, K), dtype), ((K, N), dtype), ((N,), dtype)]
+    fn = lambda x, w, b: pcb.conv1x1_stats(x, w, b, False)[0]
+    return fn, shapes, pcb.supported(M, K, N, jnp.dtype(dtype).itemsize)
+
+
+CASES = {
+    # flagship LSTM classifier (bench_lstm_classifier: B=256, H=512)
+    "lstm-bf16": lambda: _lstm(256, 512, BF16, False),
+    "lstm-bf16-flat": lambda: _lstm(256, 512, BF16, True),
+    "lstm-f32": lambda: _lstm(256, 512, F32, False),
+    # seqToseq NMT encoder (H=512; 448 tops the bench ladder)
+    "gru-bf16-b256": lambda: _gru(256, 512, BF16, False),
+    "gru-bf16-b448": lambda: _gru(448, 512, BF16, False),
+    "gru-bf16-b448-flat": lambda: _gru(448, 512, BF16, True),
+    "gru-f32-b256": lambda: _gru(256, 512, F32, False),
+    # seqToseq NMT decoder (D=512, E=1024)
+    "attention-gru-bf16-b64": lambda: _attention_gru(64, BF16),
+    "attention-gru-bf16-b256": lambda: _attention_gru(256, BF16),
+    "attention-gru-bf16-b448": lambda: _attention_gru(448, BF16),
+    "attention-gru-f32-b256": lambda: _attention_gru(256, F32),
+    # demo/long_context (seq_len=2048; dim 64 over 4 heads, and a 64-wide head)
+    "flash-t2048-d16": lambda: _flash(2048, 16, F32),
+    "flash-t2048-d64": lambda: _flash(2048, 64, BF16),
+    # a ResNet-50 1x1 at B=256: stage-1 expand, 56x56 pixels, 64 -> 256
+    "conv1x1-bf16": lambda: _conv1x1(256 * 56 * 56, 64, 256, BF16),
+}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_where_its_gate_says_yes(case, grad, chip):
+    fn, shapes, gate = CASES[case]()
+    try:
+        hlo = _compile(fn, shapes, chip, grad)
+    except Exception as e:  # noqa: BLE001 — whatever the compiler raises
+        assert not gate, (
+            f"{case}: supported() admits a shape the v5e compiler refuses: "
+            f"{type(e).__name__}: {str(e)[:400]}")
+        return
+    assert "tpu_custom_call" in hlo, f"{case}: no Mosaic kernel in the HLO"

@@ -243,9 +243,9 @@ def test_cost_analysis_of_graceful_on_unavailable_backends():
         def cost_analysis(self):
             raise NotImplementedError("backend says no")
 
-    class Listy:
+    class Dicty:
         def cost_analysis(self):
-            return [{"flops": 8.0, "bytes accessed": 4.0}]
+            return {"flops": 8.0, "bytes accessed": 4.0}
 
     class Empty:
         def cost_analysis(self):
@@ -258,7 +258,7 @@ def test_cost_analysis_of_graceful_on_unavailable_backends():
     assert cost_analysis_of(Raises()) is None
     assert cost_analysis_of(Empty()) is None
     assert cost_analysis_of(Scalarless()) is None
-    assert cost_analysis_of(Listy()) == {"flops": 8.0, "bytes_accessed": 4.0}
+    assert cost_analysis_of(Dicty()) == {"flops": 8.0, "bytes_accessed": 4.0}
 
 
 def test_registry_inline_fallback_without_lower(tmp_path):

@@ -10,19 +10,12 @@ invariants that catch drift without paying a build per suite run.
 import ast
 import os
 
-import pytest
-
-try:  # stdlib from 3.11; the package supports 3.10 (CI matrix)
-    import tomllib
-except ModuleNotFoundError:  # pragma: no cover - 3.10 only
-    tomllib = None
+import tomllib
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _pyproject():
-    if tomllib is None:
-        pytest.skip("tomllib unavailable (python < 3.11)")
     with open(os.path.join(ROOT, "pyproject.toml"), "rb") as f:
         return tomllib.load(f)
 
