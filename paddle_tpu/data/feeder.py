@@ -29,13 +29,13 @@ from paddle_tpu.graph.argument import Argument
 from paddle_tpu.data.provider import DataType, SequenceType
 from paddle_tpu.native import ptr
 from paddle_tpu.observability import metrics as obs
-from paddle_tpu.observability import spans as obs_spans
 from paddle_tpu.proto import DataConfig
 from paddle_tpu.resilience import BadSampleError, DataStallError
 from paddle_tpu.resilience.faultinject import fault_point
 from paddle_tpu.utils import concurrency as cc
 from paddle_tpu.utils.logging import logger
 from paddle_tpu.utils.retry import RetryPolicy
+from paddle_tpu.utils.stats import stat_timer
 
 
 def bucket_length(n: int, multiple: int = 8) -> int:
@@ -628,7 +628,8 @@ class DataProvider:
                 n_busy = busy[0]
             try:
                 busy_hist.observe(float(n_busy))
-                out = self.assembler.assemble(batch)
+                with stat_timer("data/pack"):
+                    out = self.assembler.assemble(batch)
                 beat[0] = cc.monotonic()  # a finished pack IS progress
                 return out
             finally:
@@ -640,8 +641,16 @@ class DataProvider:
         )
 
         def dispatcher():
+            # `data/provider_next` (this thread) and `data/pack` (the
+            # pool's) say which half of the feeder is slow when the step
+            # loop's `trainer/data_wait` is not 0
+            batches = iter(batch_lists)
             try:
-                for batch in batch_lists:
+                while True:
+                    with stat_timer("data/provider_next"):
+                        batch = next(batches, sentinel)
+                    if batch is sentinel:
+                        break
                     fault_point("provider.stall")
                     beat[0] = cc.monotonic()
                     # the bounded put is the backpressure: at most
@@ -665,23 +674,21 @@ class DataProvider:
         try:
             while True:
                 wait_t0 = time.perf_counter()
-                fut = self._watched_get(fetch_future, beat, t, q, age_gauge)
-                if fut is not sentinel:
-                    # the future is already executing (pool order =
-                    # submission order), so this wait is short — but a
-                    # packer wedged inside a bad native call must still
-                    # trip the watchdog, not hang the step loop
-                    item = self._watched_get(
-                        lambda to: fut.result(timeout=to), beat, t, q,
-                        age_gauge,
-                    )
-                else:
-                    item = sentinel
-                waited = time.perf_counter() - wait_t0
-                wait_counter.inc(waited)
+                with stat_timer("data/prefetch_wait"):
+                    fut = self._watched_get(fetch_future, beat, t, q, age_gauge)
+                    if fut is not sentinel:
+                        # the future is already executing (pool order =
+                        # submission order), so this wait is short — but a
+                        # packer wedged inside a bad native call must still
+                        # trip the watchdog, not hang the step loop
+                        item = self._watched_get(
+                            lambda to: fut.result(timeout=to), beat, t, q,
+                            age_gauge,
+                        )
+                    else:
+                        item = sentinel
+                wait_counter.inc(time.perf_counter() - wait_t0)
                 age_gauge.set(0.0)
-                if waited > 1e-3:
-                    obs_spans.record_perf("data/prefetch_wait", wait_t0, waited)
                 if item is sentinel:
                     break
                 yield item

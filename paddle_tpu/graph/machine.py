@@ -182,8 +182,9 @@ class GradientMachine:
             if cfg is None or cfg.type not in self.COST_TYPES:
                 continue
             arg = outputs[name]
-            c = jnp.mean(arg.value[:, 0])
-            total = c if total is None else total + c
+            with jax.named_scope("cost"):
+                c = jnp.mean(arg.value[:, 0])
+                total = c if total is None else total + c
         if total is None:
             raise ValueError("no cost outputs among output layers")
         return total

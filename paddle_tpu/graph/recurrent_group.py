@@ -64,10 +64,13 @@ def _agent_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) ->
 def forward_recurrent_group(network, cfg: LayerConfig, ctx: LayerContext) -> None:
     sub = network.submodel_map.get(cfg.name)
     assert sub is not None, f"no sub-model named {cfg.name!r}"
-    if sub.generator is not None:
-        _generate(network, cfg, sub, ctx)
-    else:
-        _forward_scan(network, cfg, sub, ctx)
+    # the group's scan, prologue and epilogue, and every layer of its step,
+    # nest under this scope in the program's HLO metadata
+    with jax.named_scope(f"{cfg.type}:{cfg.name}"):
+        if sub.generator is not None:
+            _generate(network, cfg, sub, ctx)
+        else:
+            _forward_scan(network, cfg, sub, ctx)
 
 
 # ------------------------------------------------------------- training

@@ -238,7 +238,11 @@ _clip_error.defvjp(_clip_error_fwd, _clip_error_bwd)
 
 def forward_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) -> Argument:
     fn = layer_registry.get(cfg.type)
-    with layer_scope(f"{cfg.name}({cfg.type})"):
+    # the named scope is HLO metadata only (`op_name`): device time in a
+    # profile splits by `<type>:<name>`, for a layer of the root network, of
+    # a recurrent group's step and of its hoisted epilogue alike
+    with layer_scope(f"{cfg.name}({cfg.type})"), \
+            jax.named_scope(f"{cfg.type}:{cfg.name}"):
         out = fn(cfg, inputs, ctx)
     if cfg.error_clipping_threshold > 0 and out.value is not None:
         out = out.replace(

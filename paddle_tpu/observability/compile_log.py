@@ -146,10 +146,11 @@ _COLLECTIVE_RE = re.compile(
     r"(?:-start)?\(")
 
 
-def hlo_census(compiled) -> Dict[str, Any]:
+def hlo_census(compiled, text: str) -> Dict[str, Any]:
     """What the optimized (and, on a mesh, partitioned) program of one
-    AOT executable contains — the only proof a kernel or a collective is
-    really in the step, since every kernel selection upstream is silent:
+    AOT executable contains (``text`` is its ``as_text()``) — the only
+    proof a kernel or a collective is really in the step, since every
+    kernel selection upstream is silent:
 
     - ``mosaic_calls``: Pallas/Mosaic kernels (``tpu_custom_call``), and
       ``mosaic_shapes`` their distinct result shapes — PER-DEVICE shapes,
@@ -160,8 +161,9 @@ def hlo_census(compiled) -> Dict[str, Any]:
       over them.
 
     Empty when the backend offers no HLO text (never raises)."""
+    if not text:
+        return {}
     try:
-        text = compiled.as_text()
         shardings = jax.tree_util.tree_leaves(compiled.input_shardings)
     except Exception:
         return {}
@@ -176,6 +178,31 @@ def hlo_census(compiled) -> Dict[str, Any]:
     out["sharded_inputs"] = sum(
         1 for sh in shardings if not sh.is_fully_replicated)
     return out
+
+
+def _hlo_text(compiled) -> str:
+    """The executable's optimized HLO as text; "" where the backend
+    offers none (never raises)."""
+    try:
+        return compiled.as_text() or ""
+    except Exception:
+        return ""
+
+
+def _keep_hlo(hlo_dir: str, rec: Dict[str, Any], text: str) -> None:
+    """Write one compile's HLO text to ``<hlo_dir>/<group>-<sig>.hlo.txt``
+    (once per compile, in set-up) and name it in the compile record
+    (``hlo_path``). A failed write is logged and leaves the record
+    without it — telemetry must not take down the run it observes."""
+    path = os.path.join(hlo_dir, f"{rec['group']}-{rec['sig']}.hlo.txt")
+    try:
+        os.makedirs(hlo_dir, exist_ok=True)
+        with open(path, "w") as f:
+            f.write(text)
+    except OSError as e:
+        logger.warning("keeping the HLO text at %s failed: %s", path, e)
+        return
+    rec["hlo_path"] = path
 
 
 def sig_hash(key: Any) -> str:
@@ -222,7 +249,12 @@ class CompileRegistry:
     the accumulated totals into ``kind=roofline`` records.
     """
 
-    def __init__(self, device_kind: Optional[str] = None):
+    def __init__(self, device_kind: Optional[str] = None, hlo_dir: str = ""):
+        # where each compile's optimized HLO text is kept ("" = nowhere):
+        # a profile's device events carry an instruction's name and no
+        # scope, and this text is the map from the one to the other
+        # (`metadata={op_name="..."}`)
+        self._hlo_dir = hlo_dir
         self._entries: Dict[Tuple[str, Any], _Entry] = {}
         self._warned_flops: set = set()
         self._warned_degraded: set = set()
@@ -313,7 +345,10 @@ class CompileRegistry:
                 # the first step runs — the raw material of
                 # `paddle memory` and the OOM pre-mortem
                 mem = memory_analysis_of(compiled)
-                rec.update(hlo_census(compiled))
+                text = _hlo_text(compiled)
+                rec.update(hlo_census(compiled, text))
+                if text and self._hlo_dir:
+                    _keep_hlo(self._hlo_dir, rec, text)
                 callable_ = compiled
             except Exception as e:
                 logger.debug(

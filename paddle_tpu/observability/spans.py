@@ -6,8 +6,10 @@ third consumer: when a collector is configured (``--trace_events_path``),
 every scope additionally records a complete ("ph": "X") trace event, and
 the collector exports ``{"traceEvents": [...]}`` that chrome://tracing /
 Perfetto load directly. Nesting falls out of the format: events on the
-same pid/tid nest by time containment, so ``train_step`` spans appear
-inside their ``trainer/pass`` span and next to ``data/prefetch_wait``.
+same pid/tid nest by time containment, so a ``trainer/launch`` span
+appears inside its ``trainer/step``, and that inside its ``trainer/pass``.
+``stat_timer`` is the only caller (``record_perf``): there is one span
+primitive, and this is one of its three sinks.
 
 This intentionally does NOT replace the jax profiler (``--profile_dir``
 captures device-side xplanes; stat_timer's TraceAnnotation names these
@@ -22,13 +24,11 @@ dropped (counted), so a long run cannot OOM its own telemetry.
 from __future__ import annotations
 
 import atexit
-import contextlib
 import json
 import os
-import threading
 from paddle_tpu.utils import concurrency as cc
 import time
-from typing import Iterator, List, Optional
+from typing import List, Optional
 
 from paddle_tpu.utils.logging import logger
 
@@ -43,12 +43,9 @@ class SpanCollector:
         self._lock = cc.Lock()
         self._t0 = time.perf_counter()
 
-    def now(self) -> float:
-        """Span clock (seconds since collector start)."""
-        return time.perf_counter() - self._t0
-
     def record(self, name: str, start_s: float, dur_s: float) -> None:
-        """One complete span; ``start_s`` is a ``now()`` reading."""
+        """One complete span; ``start_s`` counts from the collector's
+        start."""
         ev = {
             "name": name,
             "ph": "X",
@@ -57,24 +54,6 @@ class SpanCollector:
             "pid": self.host,
             "tid": cc.get_ident() % 2**31,
         }
-        with self._lock:
-            if len(self._events) >= self.max_events:
-                self.dropped += 1
-                return
-            self._events.append(ev)
-
-    def instant(self, name: str, **args) -> None:
-        """Instant event ("ph": "i") — nonfinite hits, fault firings."""
-        ev = {
-            "name": name,
-            "ph": "i",
-            "s": "t",
-            "ts": round(self.now() * 1e6, 3),
-            "pid": self.host,
-            "tid": cc.get_ident() % 2**31,
-        }
-        if args:
-            ev["args"] = args
         with self._lock:
             if len(self._events) >= self.max_events:
                 self.dropped += 1
@@ -152,15 +131,6 @@ def _atexit_export() -> None:
         _collector.export()
 
 
-def enabled() -> bool:
-    return _collector is not None
-
-
-def record(name: str, start_s: float, dur_s: float) -> None:
-    if _collector is not None:
-        _collector.record(name, start_s, dur_s)
-
-
 def record_perf(name: str, t0_perf: float, dur_s: float) -> None:
     """Record a span whose start was taken with ``time.perf_counter()``
     (stat_timer's clock) — converted onto the collector clock here, so
@@ -170,25 +140,5 @@ def record_perf(name: str, t0_perf: float, dur_s: float) -> None:
         c.record(name, t0_perf - c._t0, dur_s)
 
 
-def instant(name: str, **args) -> None:
-    if _collector is not None:
-        _collector.instant(name, **args)
-
-
 def export() -> Optional[str]:
     return _collector.export() if _collector is not None else None
-
-
-@contextlib.contextmanager
-def span(name: str) -> Iterator[None]:
-    """Span-only scope for sites where a StatSet entry would be noise
-    (or jax may not be imported); stat_timer uses record() directly."""
-    c = _collector
-    if c is None:
-        yield
-        return
-    t0 = c.now()
-    try:
-        yield
-    finally:
-        c.record(name, t0, c.now() - t0)

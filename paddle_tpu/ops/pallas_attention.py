@@ -162,6 +162,7 @@ def _run_fwd(q, k, v, lengths, causal, bq, bk, interpret):
     lse_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i: (b, h, i))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, block_k=bk, scale=scale),
+        name="attention_fwd",
         grid=(B, H, T // bq),
         in_specs=[_LEN_SPEC, qspec, kvspec, kvspec],
         out_specs=[qspec, lse_spec],
@@ -191,6 +192,7 @@ def _run_bwd(q, k, v, do, out, lse, lengths, causal, bq, bk, interpret):
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, block_k=bk, scale=scale),
+        name="attention_dq",
         grid=(B, H, T // bq),
         in_specs=[_LEN_SPEC, qspec, kv_full, kv_full, qspec, stat_q, stat_q],
         out_specs=qspec,
@@ -204,6 +206,7 @@ def _run_bwd(q, k, v, do, out, lse, lengths, causal, bq, bk, interpret):
     k_blk = pl.BlockSpec((1, 1, bk, D), lambda b, h, i: (b, h, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, block_q=bq, scale=scale),
+        name="attention_dkv",
         grid=(B, H, T // bk),
         in_specs=[_LEN_SPEC, kv_full, k_blk, k_blk, kv_full, stat_full, stat_full],
         out_specs=[k_blk, k_blk],

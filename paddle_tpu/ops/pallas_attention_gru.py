@@ -264,6 +264,7 @@ def _run_fwd(ep, ev, em, xw, dmask, h0, wa, ba, v, wctx, wg,
         )
     outs = pl.pallas_call(
         kern,
+        name="attention_gru_fwd",
         grid=(B // bb, Td),
         in_specs=[
             enc3(D), enc3(E), enc3(1), step(3 * D), step(1), bspec,
@@ -411,6 +412,7 @@ def _run_bwd(dy, ep, ev, em, dmask, hprev, acts3, alphas,
     f32 = jnp.float32
     dxw, dctxs, dh0, dep, dwa, dba, dv = pl.pallas_call(
         kern,
+        name="attention_gru_bwd",
         grid=(B // bb, Td),
         in_specs=[
             rev(D),                       # dy

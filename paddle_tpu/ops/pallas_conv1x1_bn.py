@@ -149,6 +149,7 @@ def _run(x: Array, w: Array, b: Array, interpret: bool):
         dimension_semantics=("arbitrary",) * 3)
     y, s, q = pl.pallas_call(
         kernel,
+        name="conv1x1_bn_fwd",
         grid=(nm, nn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),

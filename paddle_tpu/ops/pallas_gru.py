@@ -177,6 +177,7 @@ def _run_fwd(x3, mask_tb1, w, acts, interpret, residuals=True, flat=False):
         ]
     return pl.pallas_call(
         kern,
+        name="gru_fwd",
         grid=(T,),
         in_specs=[x_spec, mask_spec, wspec],
         out_specs=out_specs,
@@ -205,6 +206,7 @@ def _run_bwd(dy, acts_seq, hprev, mask_tb1, w, acts, interpret, flat=False):
                              flat=flat)
     dx3, dw = pl.pallas_call(
         kern,
+        name="gru_bwd",
         grid=(T,),
         in_specs=[dy_spec, rev3, rev1, mask_spec, wspec],
         out_specs=[dx_spec, wspec],

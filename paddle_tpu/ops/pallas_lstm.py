@@ -299,6 +299,7 @@ def _run_fwd(x4, mask_tb1, w, peep, acts, interpret, residuals=True,
         ]
     return pl.pallas_call(
         kern,
+        name="lstm_fwd",
         grid=(T,),
         in_specs=[x_spec, mask_spec, const2(w.shape), const2(peep.shape)],
         out_specs=out_specs,
@@ -333,6 +334,7 @@ def _run_bwd(dy, saved, mask_tb1, w, peep, acts, interpret, flat=False):
     )
     dx4, dw, dpeep = pl.pallas_call(
         kern,
+        name="lstm_bwd",
         grid=(T,),
         in_specs=[dy_spec, rev4, rev, rev, mask_spec, const2(w.shape), const2(peep.shape)],
         out_specs=[dx_spec, const2(w.shape), const2(peep.shape)],

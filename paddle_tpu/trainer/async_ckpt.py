@@ -92,6 +92,7 @@ from paddle_tpu.resilience import CheckpointError
 from paddle_tpu.sparse import runtime as sparse_rt
 from paddle_tpu.utils import concurrency as cc
 from paddle_tpu.utils.logging import logger
+from paddle_tpu.utils.stats import stat_timer
 
 __all__ = [
     "AsyncCheckpointer", "ShardedAsyncCheckpointer", "snapshot_to_host",
@@ -652,11 +653,12 @@ class ShardedAsyncCheckpointer(AsyncCheckpointer):
 
     def _default_finalize(self, pass_id: int, job: _Job, rotate: bool) -> str:
         t0 = cc.perf_counter()
-        final = ckpt.finalize_sharded_pass(
-            self.save_dir, pass_id, job.snapshot.keys(), job.meta,
-            keep=job.keep, protect_pass=job.protect_pass,
-            expected_pids=range(self.count), rotate=rotate,
-        )
+        with stat_timer("checkpoint/save"):
+            final = ckpt.finalize_sharded_pass(
+                self.save_dir, pass_id, job.snapshot.keys(), job.meta,
+                keep=job.keep, protect_pass=job.protect_pass,
+                expected_pids=range(self.count), rotate=rotate,
+            )
         logger.info("saved checkpoint %s", final)
         ckpt._ckpt_record(
             "save", final, t0, pass_id=pass_id, measure_bytes=True,

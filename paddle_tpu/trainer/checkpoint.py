@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.observability import metrics as obs
-from paddle_tpu.observability import spans as obs_spans
 from paddle_tpu.optimizer.updater import UpdaterState
 from paddle_tpu.resilience import CheckpointCorruptError
 from paddle_tpu.resilience import manifest as ckpt_manifest
@@ -59,6 +58,7 @@ from paddle_tpu.sparse import runtime as sparse_rt
 from paddle_tpu.utils.flags import FLAGS
 from paddle_tpu.utils.logging import logger
 from paddle_tpu.utils.retry import RetryPolicy
+from paddle_tpu.utils.stats import stat_timer
 
 PASS_FMT = "pass-%05d"
 TMP_SUFFIX = ".tmp"
@@ -100,17 +100,17 @@ def _dir_bytes(path: str) -> int:
 
 def _ckpt_record(op: str, path: str, t0: float, pass_id: Optional[int] = None,
                  measure_bytes: bool = False, **fields) -> None:
-    """One structured ``checkpoint`` record + matching span (save/load/
-    verify durations and bytes — doc/observability.md). The dir walk for
+    """One structured ``checkpoint`` record (save/load/verify durations
+    and bytes — doc/observability.md) for the operation the caller ran
+    under its ``checkpoint/<op>`` span. The dir walk for
     ``measure_bytes`` only runs when telemetry is actually on — a
     telemetry-less tool (merge_model, tests) must not pay thousands of
     stat() calls for a field a no-op emit would discard. Multi-host
     saves/loads are collective: only process 0 records (and walks), so a
     pod save costs ONE shared-FS directory walk, not N, and `paddle
-    metrics` shows one checkpoint row per operation. Spans stay per-host
-    (host-side timing is cheap and genuinely per process)."""
+    metrics` shows one checkpoint row per operation. The spans are
+    per-host (host-side timing is cheap and genuinely per process)."""
     dur = time.perf_counter() - t0
-    obs_spans.record_perf(f"checkpoint/{op}", t0, dur)
     if not obs.enabled():
         return
     if jax.process_count() > 1 and jax.process_index() != 0:
@@ -513,52 +513,53 @@ def save_checkpoint(
     final = os.path.join(save_dir, PASS_FMT % pass_id)
     tmp = final + TMP_SUFFIX
     t0 = time.perf_counter()
-    multihost = jax.process_count() > 1
-    if jax.process_index() == 0:
-        os.makedirs(save_dir, exist_ok=True)
-        # a stale .tmp here is a crashed previous attempt at this pass —
-        # garbage by definition (it never renamed); the FINAL dir stays
-        # untouched until the fresh write is durable
-        shutil.rmtree(tmp, ignore_errors=True)
-        os.makedirs(tmp)
-    trees, meta = build_save_trees(pass_id, params, opt_state, extra_meta, multihost)
-    if multihost:
-        from paddle_tpu.utils.barrier import host_barrier
-
-        # everyone waits for mkdir, writes its shards + its slice of the
-        # manifest, then process 0 merges partial indexes and manifests,
-        # finalizes meta, and commits the rename. The barriers are HOST
-        # barriers (distributed-runtime rendezvous): this is a pure
-        # filesystem protocol and must not depend on the backend being
-        # able to run cross-process device computations.
-        host_barrier("ckpt_dir:" + os.path.basename(tmp))
-        own_files = [_save_tree_sharded(tmp, base, flat) for base, flat in trees.items()]
-        pid = jax.process_index()
-        _durable_manifest(
-            ckpt_manifest.write_partial_manifest, tmp, pid, own_files,
-            label=f"MANIFEST.partial.{pid:05d}.json",
-        )
-        host_barrier("ckpt_shards:" + os.path.basename(tmp))
+    with stat_timer("checkpoint/save"):
+        multihost = jax.process_count() > 1
         if jax.process_index() == 0:
-            finalize_sharded_pass(
-                save_dir, pass_id, trees, meta, keep=keep,
-                protect_pass=protect_pass,
+            os.makedirs(save_dir, exist_ok=True)
+            # a stale .tmp here is a crashed previous attempt at this pass —
+            # garbage by definition (it never renamed); the FINAL dir stays
+            # untouched until the fresh write is durable
+            shutil.rmtree(tmp, ignore_errors=True)
+            os.makedirs(tmp)
+        trees, meta = build_save_trees(pass_id, params, opt_state, extra_meta, multihost)
+        if multihost:
+            from paddle_tpu.utils.barrier import host_barrier
+
+            # everyone waits for mkdir, writes its shards + its slice of the
+            # manifest, then process 0 merges partial indexes and manifests,
+            # finalizes meta, and commits the rename. The barriers are HOST
+            # barriers (distributed-runtime rendezvous): this is a pure
+            # filesystem protocol and must not depend on the backend being
+            # able to run cross-process device computations.
+            host_barrier("ckpt_dir:" + os.path.basename(tmp))
+            own_files = [_save_tree_sharded(tmp, base, flat) for base, flat in trees.items()]
+            pid = jax.process_index()
+            _durable_manifest(
+                ckpt_manifest.write_partial_manifest, tmp, pid, own_files,
+                label=f"MANIFEST.partial.{pid:05d}.json",
             )
-        host_barrier("ckpt_done:" + os.path.basename(final))
-    else:
-        for base, flat in trees.items():
+            host_barrier("ckpt_shards:" + os.path.basename(tmp))
+            if jax.process_index() == 0:
+                finalize_sharded_pass(
+                    save_dir, pass_id, trees, meta, keep=keep,
+                    protect_pass=protect_pass,
+                )
+            host_barrier("ckpt_done:" + os.path.basename(final))
+        else:
+            for base, flat in trees.items():
+                _write_file(
+                    os.path.join(tmp, f"{base}.npz"),
+                    lambda f, _flat=flat: np.savez(f, **_flat),
+                )
             _write_file(
-                os.path.join(tmp, f"{base}.npz"),
-                lambda f, _flat=flat: np.savez(f, **_flat),
+                os.path.join(tmp, "meta.json"),
+                lambda f: json.dump(meta, f, indent=2),
+                mode="w",
             )
-        _write_file(
-            os.path.join(tmp, "meta.json"),
-            lambda f: json.dump(meta, f, indent=2),
-            mode="w",
-        )
-        _durable_manifest(ckpt_manifest.write_manifest, tmp, label="MANIFEST.json")
-        _commit(tmp, final)
-        _rotate(save_dir, keep, protect=protect_pass)
+            _durable_manifest(ckpt_manifest.write_manifest, tmp, label="MANIFEST.json")
+            _commit(tmp, final)
+            _rotate(save_dir, keep, protect=protect_pass)
     logger.info("saved checkpoint %s", final)
     _ckpt_record("save", final, t0, pass_id=pass_id, measure_bytes=True,
                  # mid-pass periodic saves (--saving_period_by_batches)
@@ -653,15 +654,16 @@ def verify_checkpoint(path: str) -> List[str]:
     if not os.path.isdir(path):
         return [f"{path}: not a directory"]
     t0 = time.perf_counter()
-    problems: List[str] = []
-    if not has_params_tree(path):
-        problems.append("no params tree (params.npz / params.index.json)")
-    # the CRC pass reads every manifested byte — transient shared-FS read
-    # errors retry through the shared policy rather than condemning a
-    # good checkpoint
-    problems.extend(
-        _io_policy().call(ckpt_manifest.verify_dir, path, name=f"verify {path}")
-    )
+    with stat_timer("checkpoint/verify"):
+        problems: List[str] = []
+        if not has_params_tree(path):
+            problems.append("no params tree (params.npz / params.index.json)")
+        # the CRC pass reads every manifested byte — transient shared-FS read
+        # errors retry through the shared policy rather than condemning a
+        # good checkpoint
+        problems.extend(
+            _io_policy().call(ckpt_manifest.verify_dir, path, name=f"verify {path}")
+        )
     _ckpt_record("verify", path, t0, ok=not problems)
     return problems
 
@@ -1042,77 +1044,78 @@ def load_checkpoint(
         raise FileNotFoundError(f"checkpoint {cur} does not exist")
     t0 = time.perf_counter()
     first = True
-    while True:
-        # verify=False / trust_own_writes cover only the FIRST candidate
-        # (the caller just CRC'd it, e.g. find_restorable_checkpoint, or
-        # this process wrote it); anything the fallback chain reaches is
-        # unvetted and must be verified here
-        trusted = trust_own_writes and written_this_process(cur)
-        if first and trusted and verify:
-            logger.info(
-                "load_checkpoint: %s was committed by this process — "
-                "skipping re-verification", cur,
-            )
-        skip_crc = first and (not verify or trusted)
-        problems = [] if skip_crc else verify_checkpoint(cur)
-        # the corruption-vs-config disambiguation below may assume
-        # clean bytes only when a CRC actually ran — here, or by the
-        # caller (the verify=False contract). A trusted self-written
-        # skip verified NOTHING: its deserialization failures must
-        # enter the fallback chain, not re-raise as config errors.
-        bytes_vetted = not (skip_crc and trusted)
-        first = False
-        if not problems:
-            try:
-                result = _load_checkpoint_once(
-                    cur, opt_template, missing, expected_params, sharding_for,
-                    io_stats,
+    with stat_timer("checkpoint/load"):
+        while True:
+            # verify=False / trust_own_writes cover only the FIRST candidate
+            # (the caller just CRC'd it, e.g. find_restorable_checkpoint, or
+            # this process wrote it); anything the fallback chain reaches is
+            # unvetted and must be verified here
+            trusted = trust_own_writes and written_this_process(cur)
+            if first and trusted and verify:
+                logger.info(
+                    "load_checkpoint: %s was committed by this process — "
+                    "skipping re-verification", cur,
                 )
-                _ckpt_record(
-                    "load", cur, t0,
-                    pass_id=result[2].get("pass_id")
-                    if isinstance(result[2].get("pass_id"), int) else None,
-                    measure_bytes=True,
-                    fallbacks=len(tried),
+            skip_crc = first and (not verify or trusted)
+            problems = [] if skip_crc else verify_checkpoint(cur)
+            # the corruption-vs-config disambiguation below may assume
+            # clean bytes only when a CRC actually ran — here, or by the
+            # caller (the verify=False contract). A trusted self-written
+            # skip verified NOTHING: its deserialization failures must
+            # enter the fallback chain, not re-raise as config errors.
+            bytes_vetted = not (skip_crc and trusted)
+            first = False
+            if not problems:
+                try:
+                    result = _load_checkpoint_once(
+                        cur, opt_template, missing, expected_params, sharding_for,
+                        io_stats,
+                    )
+                    _ckpt_record(
+                        "load", cur, t0,
+                        pass_id=result[2].get("pass_id")
+                        if isinstance(result[2].get("pass_id"), int) else None,
+                        measure_bytes=True,
+                        fallbacks=len(tried),
+                    )
+                    return result
+                except (
+                    FileNotFoundError,
+                    EOFError,
+                    ValueError,
+                    zipfile.BadZipFile,
+                    zlib.error,
+                ) as e:
+                    # corruption-shaped deserialization failures: no params
+                    # tree, a file vanished between verify and read, or a
+                    # torn/truncated archive in a PRE-MANIFEST checkpoint
+                    # (np.load raises BadZipFile on truncation, zlib.error on
+                    # corrupt members, ValueError/EOFError on garbage). But a
+                    # checkpoint whose manifest just CRC-verified clean cannot
+                    # be torn on disk — a ValueError there is a model/config
+                    # mismatch (wrong shapes for this net), and quarantining
+                    # good checkpoints over it would walk the whole chain into
+                    # *.corrupt. Config errors propagate; only manifest-less
+                    # dirs (and vanished files) enter the fallback chain here.
+                    if (
+                        bytes_vetted
+                        and not isinstance(e, FileNotFoundError)
+                        and ckpt_manifest.read_manifest(cur) is not None
+                    ):
+                        raise
+                    problems = [f"load failed: {e}"]
+            detail = f"{cur}: {'; '.join(problems)}"
+            tried.append(detail)
+            logger.error("checkpoint failed verification: %s", detail)
+            nxt = _fallback_candidate(cur) if fallback else None
+            if fallback:
+                _quarantine(cur)
+            if nxt is None:
+                raise CheckpointCorruptError(
+                    "no restorable checkpoint: " + " | ".join(tried), problems=tried
                 )
-                return result
-            except (
-                FileNotFoundError,
-                EOFError,
-                ValueError,
-                zipfile.BadZipFile,
-                zlib.error,
-            ) as e:
-                # corruption-shaped deserialization failures: no params
-                # tree, a file vanished between verify and read, or a
-                # torn/truncated archive in a PRE-MANIFEST checkpoint
-                # (np.load raises BadZipFile on truncation, zlib.error on
-                # corrupt members, ValueError/EOFError on garbage). But a
-                # checkpoint whose manifest just CRC-verified clean cannot
-                # be torn on disk — a ValueError there is a model/config
-                # mismatch (wrong shapes for this net), and quarantining
-                # good checkpoints over it would walk the whole chain into
-                # *.corrupt. Config errors propagate; only manifest-less
-                # dirs (and vanished files) enter the fallback chain here.
-                if (
-                    bytes_vetted
-                    and not isinstance(e, FileNotFoundError)
-                    and ckpt_manifest.read_manifest(cur) is not None
-                ):
-                    raise
-                problems = [f"load failed: {e}"]
-        detail = f"{cur}: {'; '.join(problems)}"
-        tried.append(detail)
-        logger.error("checkpoint failed verification: %s", detail)
-        nxt = _fallback_candidate(cur) if fallback else None
-        if fallback:
-            _quarantine(cur)
-        if nxt is None:
-            raise CheckpointCorruptError(
-                "no restorable checkpoint: " + " | ".join(tried), problems=tried
-            )
-        logger.warning("falling back to earlier checkpoint %s", nxt)
-        cur = nxt
+            logger.warning("falling back to earlier checkpoint %s", nxt)
+            cur = nxt
 
 
 def _load_checkpoint_once(

@@ -18,6 +18,7 @@ import numpy as np
 from paddle_tpu.graph.argument import Argument
 from paddle_tpu.proto import EvaluatorConfig, ModelConfig
 from paddle_tpu.utils.registry import Registry
+from paddle_tpu.utils.stats import stat_timer
 
 evaluator_registry: Registry[type] = Registry("evaluator")
 
@@ -64,12 +65,20 @@ class Evaluator:
     @staticmethod
     def _rows(arg: Argument) -> np.ndarray:
         """Flatten an output to valid rows [N, D] (masking padding)."""
-        v = np.asarray(arg.value) if arg.value is not None else None
-        if v is None:
-            ids = np.asarray(arg.ids)
-            v = ids.reshape(ids.shape + (1,)).astype(np.float32)
+        # `eval/readback`: np.asarray of a device array IS the
+        # device-to-host copy (a whole [B, T, V] output for a softmax
+        # layer); what follows it in the evaluator is host arithmetic
+        lens = (arg.sub_seq_lengths if arg.sub_seq_lengths is not None
+                else arg.seq_lengths)
+        with stat_timer("eval/readback"):
+            if arg.value is not None:
+                v = np.asarray(arg.value)
+            else:
+                ids = np.asarray(arg.ids)
+                v = ids.reshape(ids.shape + (1,)).astype(np.float32)
+            if lens is not None:
+                lens = np.asarray(lens)
         if arg.sub_seq_lengths is not None:
-            lens = np.asarray(arg.sub_seq_lengths)
             rows = [
                 v[b, s, :t]
                 for b in range(v.shape[0])
@@ -78,7 +87,6 @@ class Evaluator:
             ]
             return np.concatenate(rows, axis=0) if rows else v.reshape(0, v.shape[-1])
         if arg.seq_lengths is not None:
-            lens = np.asarray(arg.seq_lengths)
             rows = [v[b, : lens[b]] for b in range(v.shape[0])]
             return np.concatenate(rows, axis=0) if rows else v.reshape(0, v.shape[-1])
         return v
@@ -86,9 +94,12 @@ class Evaluator:
     @staticmethod
     def _label_rows(arg: Argument) -> np.ndarray:
         if arg.ids is not None:
-            ids = np.asarray(arg.ids)
-            if arg.seq_lengths is not None and ids.ndim >= 2:
-                lens = np.asarray(arg.seq_lengths)
+            with stat_timer("eval/readback"):
+                ids = np.asarray(arg.ids)
+                by_seq = arg.seq_lengths is not None and ids.ndim >= 2
+                if by_seq:
+                    lens = np.asarray(arg.seq_lengths)
+            if by_seq:
                 return np.concatenate([ids[b, : lens[b]].reshape(-1) for b in range(ids.shape[0])])
             return ids.reshape(-1)
         return np.argmax(Evaluator._rows(arg), axis=-1)
@@ -632,7 +643,8 @@ class EvaluatorChain:
         for e in (self.evaluators if only is None else only):
             args = [outputs[n] for n in e.cfg.input_layers if n in outputs]
             if len(args) == len(e.cfg.input_layers):
-                e.eval_batch(args)
+                with stat_timer(f"eval/{e.cfg.type}"):
+                    e.eval_batch(args)
 
     def summary(self) -> str:
         parts = []

@@ -408,8 +408,13 @@ class Trainer:
         # compile_log.resolve_cache_dir's
         if getattr(flags, "compile_cache_dir", ""):
             compile_log.enable_compile_cache(flags.compile_cache_dir)
+        # with --metrics_path, each compile's optimized HLO text is kept
+        # beside its record (`hlo_path`): what maps a profile's device
+        # events to the layers' named scopes
+        metrics_path = getattr(flags, "metrics_path", "")
         self._compiles = compile_log.CompileRegistry(
-            device_kind=device["device_kind"])
+            device_kind=device["device_kind"],
+            hlo_dir=os.path.join(metrics_path, "hlo") if metrics_path else "")
         # hang defense (doc/resilience.md "Hang detection"): the step
         # loop pings the watchdog at every launch boundary; a stall
         # beyond --step_hang_timeout dumps forensics (hang_report.json
@@ -648,8 +653,12 @@ class Trainer:
         nm_groups = self._numerics_groups
 
         def step(params, opt_state, in_args, rng, batch_size):
+            # the scopes are HLO metadata: a profile's device time splits
+            # into the layers' own scopes (layers/base.py forward_layer),
+            # `cost`, `optimizer` and `numerics`
             loss, grads, outputs, state_updates = grad_fn(params, in_args, rng)
-            new_params, new_opt = updater(params, grads, opt_state, batch_size)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = updater(params, grads, opt_state, batch_size)
             for k, v in state_updates.items():
                 new_params[k] = v
             keep = {k: v for k, v in outputs.items() if k in out_layers}
@@ -658,7 +667,8 @@ class Trainer:
             # numerics aux: fused into THIS launch (grads and both
             # parameter trees are already live on device) — one extra
             # [4]-vector per layer in the outputs, zero extra launches
-            health = obs_num.step_health(params, new_params, grads, nm_groups)
+            with jax.named_scope("numerics"):
+                health = obs_num.step_health(params, new_params, grads, nm_groups)
             return new_params, new_opt, loss, keep, health
 
         return step
@@ -960,11 +970,12 @@ class Trainer:
                     while pass_id < num_passes:
                         pass_rng = jax.random.fold_in(rng, pass_id)
                         try:
-                            self.train_one_pass(pass_id, train_provider, pass_rng)
+                            with stat_timer("trainer/pass"):
+                                self.train_one_pass(pass_id, train_provider, pass_rng)
                         except _RollbackRequest as rb:
                             pass_id = self._apply_rollback(rb)
                             continue
-                        with stat_timer("test"):
+                        with stat_timer("trainer/test"):
                             pass_results = self.test(pass_id=pass_id)
                         if pass_results:
                             self.test_history.append((pass_id, pass_results))
@@ -1104,7 +1115,7 @@ class Trainer:
         last_pass = self.start_pass - 1
         for pass_id in range(self.start_pass, num_passes):
             last_pass = pass_id
-            with stat_timer("onePass"):
+            with stat_timer("trainer/pass"):
                 if cached is not None:
                     cost, grads, n = cached
                     cached = None
@@ -1141,7 +1152,7 @@ class Trainer:
                 f_new,
                 "" if accepted else ", line search rejected",
             )
-            with stat_timer("test"):
+            with stat_timer("trainer/test"):
                 self.test(pass_id=pass_id)
             if (
                 self.flags.show_parameter_stats_period
@@ -1248,7 +1259,8 @@ class Trainer:
         self._pass_flops_incomplete = False
         self._lsgd_discarded = 0
         t0 = time.monotonic()  # rate clock: immune to NTP steps mid-pass
-        pass_t0 = time.perf_counter()  # span + pass_time_s clock
+        pass_t0 = time.perf_counter()  # pass_time_s clock
+        spans_before = global_stats.snapshot()
         batch_id = 0
         step_times: list = []
         launch_counts = {"single": 0, "fused": 0}
@@ -1267,338 +1279,362 @@ class Trainer:
                 )
             if pass_id >= tgt_pass:
                 self._ff_target = None
-        for kind, group in self._launch_groups(
+        groups = self._launch_groups(
             self._device_prefetch(self._global_batches(provider))
-        ):
-            # launch boundary: the hangwatch ping that proves the step
-            # loop is alive — everything below (stall site included)
-            # counts against --step_hang_timeout. BEFORE the
-            # fast-forward skip: replaying the data pipeline past a
-            # rollback's poison region IS progress (same rationale as
-            # the feeder watchdog's fast-forward heartbeat), and a long
-            # replay must not be misdiagnosed as a hang mid-recovery.
-            if self._hangwatch is not None:
-                self._hangwatch.ping(pass_id, batch_id)
-            self._last_launch = (pass_id, batch_id)
-            if ff_until and batch_id < ff_until:
-                batch_id += len(group) if kind == "fused" else 1
-                continue
-            # chaos sites (one hit per trained launch):
-            # `trainer.crash=exit@N` is a deterministic mid-run process
-            # death — what `paddle supervise` drills recover from;
-            # `trainer.stall=sleep:S@N` wedges the step loop — what the
-            # hangwatch (--step_hang_timeout) drills detect
-            faultinject.fault_point(
-                "trainer.crash", info=f"pass={pass_id} batch={batch_id}"
-            )
-            faultinject.fault_point(
-                "trainer.stall", info=f"pass={pass_id} batch={batch_id}"
-            )
-            # `trainer.oom=raise@N` is a deterministic device OOM at the
-            # launch boundary — what the oom_report.json pre-mortem +
-            # exit-20 drills recover from (the synthetic error carries
-            # the canonical RESOURCE_EXHAUSTED marker, so the catch in
-            # train() classifies it exactly like the real thing)
-            try:
+        )
+        while True:
+            # one launch, end to end, is one `trainer/step` span, opened
+            # BEFORE the pull of its input so that every phase of the step
+            # (the wait for data included) is its child and shares its
+            # step number in the trace
+            with stat_timer("trainer/step", step_num=batch_id) as step_span:
+                with stat_timer("trainer/data_wait") as wait_span:
+                    launch = next(groups, None)
+                    if launch is None:
+                        # the pull that finds the pass's end is no step
+                        wait_span.drop()
+                        step_span.drop()
+                if launch is None:
+                    break
+                kind, group = launch
+                # launch boundary: the hangwatch ping that proves the step
+                # loop is alive — everything below (stall site included)
+                # counts against --step_hang_timeout. BEFORE the
+                # fast-forward skip: replaying the data pipeline past a
+                # rollback's poison region IS progress (same rationale as
+                # the feeder watchdog's fast-forward heartbeat), and a long
+                # replay must not be misdiagnosed as a hang mid-recovery.
+                if self._hangwatch is not None:
+                    self._hangwatch.ping(pass_id, batch_id)
+                self._last_launch = (pass_id, batch_id)
+                if ff_until and batch_id < ff_until:
+                    batch_id += len(group) if kind == "fused" else 1
+                    continue
+                # chaos sites (one hit per trained launch):
+                # `trainer.crash=exit@N` is a deterministic mid-run process
+                # death — what `paddle supervise` drills recover from;
+                # `trainer.stall=sleep:S@N` wedges the step loop — what the
+                # hangwatch (--step_hang_timeout) drills detect
                 faultinject.fault_point(
-                    "trainer.oom", info=f"pass={pass_id} batch={batch_id}"
+                    "trainer.crash", info=f"pass={pass_id} batch={batch_id}"
                 )
-            except faultinject.FaultInjected as e:
-                raise obs_mem.SyntheticOomError(
-                    f"pass={pass_id} batch={batch_id}"
-                ) from e
-            # `trainer.nonfinite_layer=raise:LAYER@N` poisons the named
-            # layer's parameters with NaN — the effect a nonfinite
-            # gradient applied by the optimizer has — so the next loss
-            # goes NaN and the per-layer blame re-run must name LAYER
-            try:
                 faultinject.fault_point(
-                    "trainer.nonfinite_layer",
-                    info=f"pass={pass_id} batch={batch_id}",
+                    "trainer.stall", info=f"pass={pass_id} batch={batch_id}"
                 )
-            except faultinject.FaultInjected as e:
-                self._poison_layer(e.arg, pass_id, batch_id)
-            # sparse tables: `sparse.gather_fault=raise@N` aborts the
-            # launch whose touched-row prefetch is about to run (loud
-            # failure, never training on stale rows), and the host
-            # batch ids feed the kind=sparse per-pass accounting —
-            # BEFORE the fused path drops its per-batch host args
-            if self._sparse_stats is not None:
-                faultinject.fault_point(
-                    "sparse.gather_fault",
-                    info=f"pass={pass_id} batch={batch_id}",
-                )
-                for hb in ([it[1] for it in group] if kind == "fused"
-                           else [group[1]]):
-                    self._sparse_stats.note_batch(self._sparse_plan, hb)
-            launch_counts[kind] += 1
-            if (
-                self.flags.profile_dir
-                and pass_id == self.start_pass
-                and not profiling
-                and not profiled
-                and batch_id >= self.flags.profile_start_batch
-            ):
-                # fused launches advance batch_id by k: trigger at launch
-                # granularity (the window covers whole launches)
-                jax.profiler.start_trace(self.flags.profile_dir)
-                profiling = True
-                logger.info("profiler trace started → %s", self.flags.profile_dir)
-            if kind == "fused":
-                t_prep = time.perf_counter()
-                items = group
-                kf = len(items)
-                ns = [it[0] for it in items]
-                stacked = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *[it[2] for it in items]
-                )
-                # the stacked copy is what the launch consumes — drop the
-                # per-batch device arrays now instead of holding ~2x the
-                # launch's input data in HBM across the step. Cleared IN
-                # PLACE: _launch_groups' suspended frame still aliases
-                # this list (its buf rebind only runs on the next resume)
-                group.clear()
-                items = group = None
-                # consume one split of the pass chain PER BATCH, exactly
-                # as the unfused loop does, so batches_per_launch=k
-                # reproduces k=1 numerics for rng-using models (dropout)
-                step_keys = []
-                for _ in range(kf):
-                    rng, sr = jax.random.split(rng)
-                    step_keys.append(sr)
-                rngs = jnp.stack(step_keys)
-                ns_arr = jnp.asarray([float(x) for x in ns])
-                prep_s = time.perf_counter() - t_prep
-                # launch FLOPs counted exactly: the walker multiplies the
-                # fused scan body by its length k. Counted OUTSIDE the
-                # step window (a cache-miss jaxpr trace must not inflate
-                # step timing), while host-side stacking/rng prep stays
-                # INSIDE it, preserving the window's original semantics
-                launch_key = ("fused", kf, self._shape_sig(stacked))
-                self._pass_flops += self._count_model_flops(
-                    launch_key,
-                    self.fused_step, self.params, self.opt_state, stacked,
-                    rngs, ns_arr,
-                )
-                t_step = time.perf_counter() - prep_s
-                snap = self._nf_snapshot()
-                with stat_timer("train_step"):
-                    fused_out = self._compiles.call(
-                        "fused_step", launch_key, self.fused_step,
-                        self.params, self.opt_state, stacked, rngs, ns_arr,
-                        analytic_flops=self._flops_cache.get(launch_key),
-                        pass_id=pass_id, step=batch_id,
+                # `trainer.oom=raise@N` is a deterministic device OOM at the
+                # launch boundary — what the oom_report.json pre-mortem +
+                # exit-20 drills recover from (the synthetic error carries
+                # the canonical RESOURCE_EXHAUSTED marker, so the catch in
+                # train() classifies it exactly like the real thing)
+                try:
+                    faultinject.fault_point(
+                        "trainer.oom", info=f"pass={pass_id} batch={batch_id}"
                     )
-                self.params, self.opt_state, losses, keeps = fused_out[:4]
-                if self._numerics_groups is not None:
-                    # stays on device: read back only at the log period
-                    self._numerics_last = fused_out[4]
-                # ONE device→host transfer per launch (losses + kept
-                # outputs together); numpy slicing below adds no further
-                # device dispatches
-                losses_host, keeps_host = jax.device_get((losses, keeps))  # lint: disable=PTL002 -- the one designed sync: amortized over the k-batch launch, feeds the nonfinite gate
-                losses_host = np.asarray(losses_host)
-                if faultinject.is_active():
-                    losses_host = np.asarray([
-                        self._poisoned_loss(float(l), pass_id, batch_id + i)
-                        for i, l in enumerate(losses_host)
-                    ])
-                if not np.isfinite(losses_host).all():
-                    # gate BEFORE any per-batch housekeeping: params already
-                    # contain all k updates, so a periodic save fired for an
-                    # earlier batch of this launch would checkpoint
-                    # NaN-poisoned weights as if they were pre-NaN
-                    bad = int(np.flatnonzero(~np.isfinite(losses_host))[0])
-                    if self._handle_nonfinite(
-                        pass_id, batch_id + bad, float(losses_host[bad]),
-                        snap, f"(launch of {kf}) ",
-                        # the poisoned batch, sliced out of the stacked
-                        # launch for the per-layer blame re-run (cold
-                        # path: this only ever runs on a NaN loss)
-                        batch=jax.tree_util.tree_map(
-                            lambda x, i=bad: x[i], stacked
-                        ),
-                        rng=rngs[bad],
-                    ):
-                        # poisoned launch discarded whole (skip policy):
-                        # pre-launch params/opt_state are back in place.
-                        # If this was the group's FIRST launch, nobody
-                        # consumed its compile-cost deduction — drop it,
-                        # or the next clean launch's exec time would be
-                        # zeroed by a compile it never paid
-                        self._compiles.drop_pending("fused_step", launch_key)
-                        batch_id += kf
-                        continue
-                launch_s = time.perf_counter() - t_step
-                self._pass_train_s += launch_s
-                self._compiles.note_exec(
-                    "fused_step", launch_key, launch_s, batches=kf
-                )
-                step_dt = launch_s / kf
-                results = [
-                    (
-                        float(losses_host[i]),
-                        jax.tree_util.tree_map(lambda x, i=i: x[i], keeps_host),
-                        ns[i],
+                except faultinject.FaultInjected as e:
+                    raise obs_mem.SyntheticOomError(
+                        f"pass={pass_id} batch={batch_id}"
+                    ) from e
+                # `trainer.nonfinite_layer=raise:LAYER@N` poisons the named
+                # layer's parameters with NaN — the effect a nonfinite
+                # gradient applied by the optimizer has — so the next loss
+                # goes NaN and the per-layer blame re-run must name LAYER
+                try:
+                    faultinject.fault_point(
+                        "trainer.nonfinite_layer",
+                        info=f"pass={pass_id} batch={batch_id}",
                     )
-                    for i in range(kf)
-                ]
-            else:
-                rng, step_rng = jax.random.split(rng)
-                n, _host_batch, batch = group
-                launch_key = None
-                if self._accum_n <= 1 and not self._async:
-                    launch_key = ("single", self._shape_sig(batch))
-                    self._pass_flops += self._count_model_flops(
-                        launch_key,
-                        self.train_step, self.params, self.opt_state, batch,
-                        step_rng, jnp.asarray(float(n)),
+                except faultinject.FaultInjected as e:
+                    self._poison_layer(e.arg, pass_id, batch_id)
+                # sparse tables: `sparse.gather_fault=raise@N` aborts the
+                # launch whose touched-row prefetch is about to run (loud
+                # failure, never training on stale rows), and the host
+                # batch ids feed the kind=sparse per-pass accounting —
+                # BEFORE the fused path drops its per-batch host args
+                if self._sparse_stats is not None:
+                    faultinject.fault_point(
+                        "sparse.gather_fault",
+                        info=f"pass={pass_id} batch={batch_id}",
                     )
-                t_step = time.perf_counter()
-                snap = self._nf_snapshot()
-                with stat_timer("train_step"):
-                    if self._accum_n > 1:
-                        loss, outputs = self._accum_step(batch, step_rng, n)
-                    elif self._async:
-                        loss, outputs = self._async_step(batch, step_rng, n)
-                    else:
-                        step_out = self._compiles.call(
-                            "train_step", launch_key, self.train_step,
-                            self.params, self.opt_state, batch, step_rng,
-                            jnp.asarray(float(n)),
+                    for hb in ([it[1] for it in group] if kind == "fused"
+                               else [group[1]]):
+                        self._sparse_stats.note_batch(self._sparse_plan, hb)
+                launch_counts[kind] += 1
+                if (
+                    self.flags.profile_dir
+                    and pass_id == self.start_pass
+                    and not profiling
+                    and not profiled
+                    and batch_id >= self.flags.profile_start_batch
+                ):
+                    # fused launches advance batch_id by k: trigger at launch
+                    # granularity (the window covers whole launches)
+                    jax.profiler.start_trace(self.flags.profile_dir)
+                    profiling = True
+                    logger.info("profiler trace started → %s", self.flags.profile_dir)
+                if kind == "fused":
+                    t_prep = time.perf_counter()
+                    items = group
+                    kf = len(items)
+                    ns = [it[0] for it in items]
+                    stacked = jax.tree_util.tree_map(
+                        lambda *xs: jnp.stack(xs), *[it[2] for it in items]
+                    )
+                    # the stacked copy is what the launch consumes — drop the
+                    # per-batch device arrays now instead of holding ~2x the
+                    # launch's input data in HBM across the step. Cleared IN
+                    # PLACE: _launch_groups' suspended frame still aliases
+                    # this list (its buf rebind only runs on the next resume)
+                    group.clear()
+                    items = group = None
+                    # consume one split of the pass chain PER BATCH, exactly
+                    # as the unfused loop does, so batches_per_launch=k
+                    # reproduces k=1 numerics for rng-using models (dropout)
+                    step_keys = []
+                    for _ in range(kf):
+                        rng, sr = jax.random.split(rng)
+                        step_keys.append(sr)
+                    rngs = jnp.stack(step_keys)
+                    ns_arr = jnp.asarray([float(x) for x in ns])
+                    prep_s = time.perf_counter() - t_prep
+                    # launch FLOPs counted exactly: the walker multiplies the
+                    # fused scan body by its length k. Counted OUTSIDE the
+                    # step window (a cache-miss jaxpr trace must not inflate
+                    # step timing), while host-side stacking/rng prep stays
+                    # INSIDE it, preserving the window's original semantics
+                    launch_key = ("fused", kf, self._shape_sig(stacked))
+                    with stat_timer("trainer/flops_count"):
+                        self._pass_flops += self._count_model_flops(
+                            launch_key,
+                            self.fused_step, self.params, self.opt_state,
+                            stacked, rngs, ns_arr,
+                        )
+                    t_step = time.perf_counter() - prep_s
+                    snap = self._nf_snapshot()
+                    with stat_timer("trainer/launch"):
+                        fused_out = self._compiles.call(
+                            "fused_step", launch_key, self.fused_step,
+                            self.params, self.opt_state, stacked, rngs, ns_arr,
                             analytic_flops=self._flops_cache.get(launch_key),
                             pass_id=pass_id, step=batch_id,
                         )
-                        self.params, self.opt_state, loss, outputs = step_out[:4]
-                        if self._numerics_groups is not None:
-                            self._numerics_last = step_out[4]
-                loss_f = self._poisoned_loss(float(loss), pass_id, batch_id)  # lint: disable=PTL002 -- single-step path: the per-launch loss read IS the nonfinite gate
-                step_dt = time.perf_counter() - t_step
-                self._pass_train_s += step_dt
-                if launch_key is not None:
-                    self._compiles.note_exec("train_step", launch_key, step_dt)
-                results = [(loss_f, outputs, n)]
-            if self._restart_pending:
-                # the run's first completed launch: restart latency is
-                # now fully paid (restore + trace + compile + step 1) —
-                # the structured number heartbeat-grace and crash-loop
-                # windows are tuned from (`paddle metrics` "restore s" /
-                # "ttfs s" columns)
-                self._restart_pending = False
-                obs.emit(
-                    "restart", pass_id=pass_id, step=batch_id,
-                    restore_s=round(self._restore_s, 6),
-                    time_to_first_step_s=round(
-                        time.perf_counter() - self._t_construct, 6
-                    ),
-                    resumed=self._restored_pass is not None,
-                )
-            batch_id_start = batch_id
-            for loss_f, outputs, n in results:
-                step_times.append(step_dt)
-                if not np.isfinite(loss_f):
-                    # FP trap role (ref: feenableexcept(FE_INVALID|FE_DIVBYZERO|
-                    # FE_OVERFLOW), TrainerMain.cpp:96), now policy-driven:
-                    # abort raises, skip discards the update, rollback
-                    # restores a checkpoint. Fused launches were gated
-                    # above; reaching here is the single-batch path. loss
-                    # is already read back each batch, so the check is free.
-                    # (`batch` is only bound on the non-fused path —
-                    # fused launches were gated above and never get here)
-                    if self._handle_nonfinite(
-                        pass_id, batch_id, loss_f, snap,
-                        batch=batch if kind == "single" else None,
-                        rng=step_rng if kind == "single" else None,
-                    ):
-                        batch_id += 1
-                        continue
-                stats.add(loss_f * n, n)
-                self._eval_outputs(evaluators, outputs)
-                batch_id += 1
-                if self.flags.dot_period and batch_id % self.flags.dot_period == 0:
-                    print(".", end="", flush=True, file=sys.stderr)
-                    self._dots_pending = True
+                    self.params, self.opt_state, losses, keeps = fused_out[:4]
+                    if self._numerics_groups is not None:
+                        # stays on device: read back only at the log period
+                        self._numerics_last = fused_out[4]
+                    # ONE device→host transfer per launch (losses + kept
+                    # outputs together); numpy slicing below adds no further
+                    # device dispatches
+                    with stat_timer("trainer/loss_sync"):
+                        losses_host, keeps_host = jax.device_get((losses, keeps))  # lint: disable=PTL002 -- the one designed sync: amortized over the k-batch launch, feeds the nonfinite gate
+                    losses_host = np.asarray(losses_host)
+                    if faultinject.is_active():
+                        losses_host = np.asarray([
+                            self._poisoned_loss(float(l), pass_id, batch_id + i)
+                            for i, l in enumerate(losses_host)
+                        ])
+                    if not np.isfinite(losses_host).all():
+                        # gate BEFORE any per-batch housekeeping: params already
+                        # contain all k updates, so a periodic save fired for an
+                        # earlier batch of this launch would checkpoint
+                        # NaN-poisoned weights as if they were pre-NaN
+                        bad = int(np.flatnonzero(~np.isfinite(losses_host))[0])
+                        if self._handle_nonfinite(
+                            pass_id, batch_id + bad, float(losses_host[bad]),
+                            snap, f"(launch of {kf}) ",
+                            # the poisoned batch, sliced out of the stacked
+                            # launch for the per-layer blame re-run (cold
+                            # path: this only ever runs on a NaN loss)
+                            batch=jax.tree_util.tree_map(
+                                lambda x, i=bad: x[i], stacked
+                            ),
+                            rng=rngs[bad],
+                        ):
+                            # poisoned launch discarded whole (skip policy):
+                            # pre-launch params/opt_state are back in place.
+                            # If this was the group's FIRST launch, nobody
+                            # consumed its compile-cost deduction — drop it,
+                            # or the next clean launch's exec time would be
+                            # zeroed by a compile it never paid
+                            self._compiles.drop_pending("fused_step", launch_key)
+                            batch_id += kf
+                            continue
+                    launch_s = time.perf_counter() - t_step
+                    self._pass_train_s += launch_s
+                    self._compiles.note_exec(
+                        "fused_step", launch_key, launch_s, batches=kf
+                    )
+                    step_dt = launch_s / kf
+                    results = [
+                        (
+                            float(losses_host[i]),
+                            jax.tree_util.tree_map(lambda x, i=i: x[i], keeps_host),
+                            ns[i],
+                        )
+                        for i in range(kf)
+                    ]
+                else:
+                    rng, step_rng = jax.random.split(rng)
+                    n, _host_batch, batch = group
+                    launch_key = None
+                    if self._accum_n <= 1 and not self._async:
+                        launch_key = ("single", self._shape_sig(batch))
+                        with stat_timer("trainer/flops_count"):
+                            self._pass_flops += self._count_model_flops(
+                                launch_key,
+                                self.train_step, self.params, self.opt_state,
+                                batch, step_rng, jnp.asarray(float(n)),
+                            )
+                    t_step = time.perf_counter()
+                    snap = self._nf_snapshot()
+                    with stat_timer("trainer/launch"):
+                        if self._accum_n > 1:
+                            loss, outputs = self._accum_step(batch, step_rng, n)
+                        elif self._async:
+                            loss, outputs = self._async_step(batch, step_rng, n)
+                        else:
+                            step_out = self._compiles.call(
+                                "train_step", launch_key, self.train_step,
+                                self.params, self.opt_state, batch, step_rng,
+                                jnp.asarray(float(n)),
+                                analytic_flops=self._flops_cache.get(launch_key),
+                                pass_id=pass_id, step=batch_id,
+                            )
+                            self.params, self.opt_state, loss, outputs = step_out[:4]
+                            if self._numerics_groups is not None:
+                                self._numerics_last = step_out[4]
+                    # the host blocked on the device: the only phase in
+                    # which an idle device is not the host's doing
+                    with stat_timer("trainer/loss_sync"):
+                        loss_f = float(loss)  # lint: disable=PTL002 -- single-step path: the per-launch loss read IS the nonfinite gate
+                    loss_f = self._poisoned_loss(loss_f, pass_id, batch_id)
+                    step_dt = time.perf_counter() - t_step
+                    self._pass_train_s += step_dt
+                    if launch_key is not None:
+                        self._compiles.note_exec("train_step", launch_key, step_dt)
+                    results = [(loss_f, outputs, n)]
+                if self._restart_pending:
+                    # the run's first completed launch: restart latency is
+                    # now fully paid (restore + trace + compile + step 1) —
+                    # the structured number heartbeat-grace and crash-loop
+                    # windows are tuned from (`paddle metrics` "restore s" /
+                    # "ttfs s" columns)
+                    self._restart_pending = False
+                    obs.emit(
+                        "restart", pass_id=pass_id, step=batch_id,
+                        restore_s=round(self._restore_s, 6),
+                        time_to_first_step_s=round(
+                            time.perf_counter() - self._t_construct, 6
+                        ),
+                        resumed=self._restored_pass is not None,
+                    )
+                batch_id_start = batch_id
+                for loss_f, outputs, n in results:
+                    step_times.append(step_dt)
+                    if not np.isfinite(loss_f):
+                        # FP trap role (ref: feenableexcept(FE_INVALID|FE_DIVBYZERO|
+                        # FE_OVERFLOW), TrainerMain.cpp:96), now policy-driven:
+                        # abort raises, skip discards the update, rollback
+                        # restores a checkpoint. Fused launches were gated
+                        # above; reaching here is the single-batch path. loss
+                        # is already read back each batch, so the check is free.
+                        # (`batch` is only bound on the non-fused path —
+                        # fused launches were gated above and never get here)
+                        if self._handle_nonfinite(
+                            pass_id, batch_id, loss_f, snap,
+                            batch=batch if kind == "single" else None,
+                            rng=step_rng if kind == "single" else None,
+                        ):
+                            batch_id += 1
+                            continue
+                    stats.add(loss_f * n, n)
+                    with stat_timer("trainer/eval_outputs"):
+                        self._eval_outputs(evaluators, outputs)
+                    batch_id += 1
+                    if self.flags.dot_period and batch_id % self.flags.dot_period == 0:
+                        print(".", end="", flush=True, file=sys.stderr)
+                        self._dots_pending = True
 
-            # periodic housekeeping fires at LAUNCH boundaries: params hold
-            # every update of the launch, so a save labeled with a
-            # mid-launch batch_id would contain later batches' updates and
-            # a resume from it would double-apply them. ``crossed`` is the
-            # plain modulo check when a launch is one batch.
-            def crossed(period):
-                return period and batch_id // period > batch_id_start // period
+                # periodic housekeeping fires at LAUNCH boundaries: params hold
+                # every update of the launch, so a save labeled with a
+                # mid-launch batch_id would contain later batches' updates and
+                # a resume from it would double-apply them. ``crossed`` is the
+                # plain modulo check when a launch is one batch.
+                def crossed(period):
+                    return period and batch_id // period > batch_id_start // period
 
-            if crossed(self.flags.test_period):
-                self._end_dot_line()
-                with stat_timer("test"):
-                    self.test(pass_id=pass_id)
-            if crossed(self.flags.show_parameter_stats_period):
-                self._end_dot_line()
-                self.show_parameter_stats()
-            if crossed(log_period):
-                self._end_dot_line()
-                logger.info(
-                    "Pass %d batch %d  %s  %s",
-                    pass_id,
-                    batch_id,
-                    stats.summary(),
-                    evaluators.summary(),
-                )
-                # the window record carries the SAME key=value pairs the
-                # log line just printed (one shared dict, satellite of
-                # doc/observability.md)
-                obs.emit("train_window", pass_id=pass_id, step=batch_id,
-                         **stats.summary_dict())
-                stats.reset_window()
-            if crossed(self._numerics_period) and self._numerics_last is not None:
-                # the ONLY host readback of the health aux: a tiny
-                # [n_layers, 4] transfer at the numerics log period,
-                # inside a helper so the per-step loop stays sync-free
-                self._emit_numerics(pass_id, batch_id)
-            # preemption (SIGTERM flag) saves through the SAME block as the
-            # periodic save — one flush, one save, even when both fire on
-            # this boundary (TPU pods preempt with a SIGTERM notice; the
-            # reference is restart-from-last-pass only — SURVEY §5 names
-            # this the recovery gap). Snapshot the flag ONCE: a signal
-            # landing between two reads must not make the raise claim a
-            # save that never ran.
-            preempted = self._preempt_requested
-            want_save = crossed(self.flags.saving_period_by_batches) or preempted
-            if want_save and self.save_dir:
-                if self._accum_n > 1:
-                    # apply pending gradients first or the checkpoint
-                    # would silently drop up to N-1 batches' worth
-                    self._accum_flush()
-                self.save(pass_id, batch_id=batch_id)
-            if preempted:
-                self._end_dot_line()
-                logger.info("SIGTERM received — checkpointed at the launch "
-                            "boundary" if self.save_dir else
-                            "SIGTERM received — no save_dir, nothing saved")
-                if profiling:
-                    # the open trace would otherwise be abandoned mid-write
-                    jax.block_until_ready(self.params)  # lint: disable=PTL002 -- preemption exit: runs AT MOST ONCE per process (SIGTERM teardown), and the profiler trace must see the last launch land before stop_trace abandons it
+                with stat_timer("trainer/housekeeping"):
+                    if crossed(self.flags.test_period):
+                        self._end_dot_line()
+                        with stat_timer("trainer/test"):
+                            self.test(pass_id=pass_id)
+                    if crossed(self.flags.show_parameter_stats_period):
+                        self._end_dot_line()
+                        self.show_parameter_stats()
+                    if crossed(log_period):
+                        self._end_dot_line()
+                        logger.info(
+                            "Pass %d batch %d  %s  %s",
+                            pass_id,
+                            batch_id,
+                            stats.summary(),
+                            evaluators.summary(),
+                        )
+                        # the window record carries the SAME key=value pairs the
+                        # log line just printed (one shared dict, satellite of
+                        # doc/observability.md)
+                        obs.emit("train_window", pass_id=pass_id, step=batch_id,
+                                 **stats.summary_dict())
+                        stats.reset_window()
+                    if crossed(self._numerics_period) and self._numerics_last is not None:
+                        # the ONLY host readback of the health aux: a tiny
+                        # [n_layers, 4] transfer at the numerics log period,
+                        # inside a helper so the per-step loop stays sync-free
+                        self._emit_numerics(pass_id, batch_id)
+                    # preemption (SIGTERM flag) saves through the SAME block as the
+                    # periodic save — one flush, one save, even when both fire on
+                    # this boundary (TPU pods preempt with a SIGTERM notice; the
+                    # reference is restart-from-last-pass only — SURVEY §5 names
+                    # this the recovery gap). Snapshot the flag ONCE: a signal
+                    # landing between two reads must not make the raise claim a
+                    # save that never ran.
+                    preempted = self._preempt_requested
+                    want_save = crossed(self.flags.saving_period_by_batches) or preempted
+                    if want_save and self.save_dir:
+                        if self._accum_n > 1:
+                            # apply pending gradients first or the checkpoint
+                            # would silently drop up to N-1 batches' worth
+                            self._accum_flush()
+                        self.save(pass_id, batch_id=batch_id)
+                if preempted:
+                    self._end_dot_line()
+                    logger.info("SIGTERM received — checkpointed at the launch "
+                                "boundary" if self.save_dir else
+                                "SIGTERM received — no save_dir, nothing saved")
+                    if profiling:
+                        # the open trace would otherwise be abandoned mid-write
+                        jax.block_until_ready(self.params)  # lint: disable=PTL002 -- preemption exit: runs AT MOST ONCE per process (SIGTERM teardown), and the profiler trace must see the last launch land before stop_trace abandons it
+                        jax.profiler.stop_trace()
+                        logger.info("profiler trace written to %s",
+                                    self.flags.profile_dir)
+                    saved_path = (
+                        os.path.join(self.save_dir, ckpt.PASS_FMT % pass_id)
+                        if self.save_dir else ""
+                    )
+                    # SIGTERM-driven flush: the preemption window must not
+                    # cost the buffered telemetry of this partial pass
+                    obs.emit("preempt", pass_id=pass_id, step=batch_id,
+                             saved_path=saved_path)
+                    obs.flush()
+                    obs_spans.export()
+                    raise PreemptionExit(pass_id, saved_path)
+                if profiling and batch_id >= (
+                    self.flags.profile_start_batch + self.flags.profile_num_batches
+                ):
+                    jax.block_until_ready(self.params)  # lint: disable=PTL002 -- profiler window close: runs ONCE per run (profiling flips false right below), and the trace must include the final profiled launch before stop_trace
                     jax.profiler.stop_trace()
-                    logger.info("profiler trace written to %s",
-                                self.flags.profile_dir)
-                saved_path = (
-                    os.path.join(self.save_dir, ckpt.PASS_FMT % pass_id)
-                    if self.save_dir else ""
-                )
-                # SIGTERM-driven flush: the preemption window must not
-                # cost the buffered telemetry of this partial pass
-                obs.emit("preempt", pass_id=pass_id, step=batch_id,
-                         saved_path=saved_path)
-                obs.flush()
-                obs_spans.export()
-                raise PreemptionExit(pass_id, saved_path)
-            if profiling and batch_id >= (
-                self.flags.profile_start_batch + self.flags.profile_num_batches
-            ):
-                jax.block_until_ready(self.params)  # lint: disable=PTL002 -- profiler window close: runs ONCE per run (profiling flips false right below), and the trace must include the final profiled launch before stop_trace
-                jax.profiler.stop_trace()
-                profiling = False
-                profiled = True
-                logger.info("profiler trace written to %s", self.flags.profile_dir)
+                    profiling = False
+                    profiled = True
+                    logger.info("profiler trace written to %s", self.flags.profile_dir)
         if self._accum_n > 1:
             # end-of-pass remainder: apply whatever is accumulated so no
             # sample's gradient is dropped (reference flushes on finishPass)
@@ -1660,6 +1696,9 @@ class Trainer:
             record["progress_age_max_s"] = round(
                 self._hangwatch.take_max_age(), 3
             )
+        # the step's phases without a profiler: {span: [count, total_s]}
+        # over this pass, the same scopes the trace shows
+        record["spans"] = global_stats.growth_since(spans_before)
         if obs.enabled():
             record["counters"] = obs.registry().snapshot()
         obs.emit("pass_end", pass_id=pass_id, step=batch_id, **record)
@@ -1676,9 +1715,6 @@ class Trainer:
         # `paddle roofline` keeps latest-wins per group, so re-run
         # passes never double-count)
         self._compiles.emit_roofline(pass_id=pass_id)
-        obs_spans.record_perf(
-            "trainer/pass", pass_t0, time.perf_counter() - pass_t0
-        )
         from paddle_tpu.utils.barrier import step_time_skew_summary
 
         step_time_skew_summary(step_times, pass_id=pass_id)
@@ -2092,9 +2128,14 @@ class Trainer:
             from paddle_tpu.parallel.spmd import batch_sharding
 
             sharding = batch_sharding(self._mesh)
-            put = lambda b: jax.device_put(b, sharding)
+            to_device = lambda b: jax.device_put(b, sharding)
         else:
-            put = jax.device_put
+            to_device = jax.device_put
+
+        def put(b):
+            with stat_timer("data/h2d"):
+                return to_device(b)
+
         it = iter(gen)
         try:
             n, host, dev = next(it)

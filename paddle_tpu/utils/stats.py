@@ -3,20 +3,20 @@
 TPU-native analog of the reference's ``REGISTER_TIMER`` / ``StatSet``
 (/root/reference/paddle/utils/Stat.h:70,127,244): named scopes accumulate
 wall-time and call counts, dumped periodically by the trainer. On TPU the
-device work is async, so timers around jitted calls measure dispatch unless
-you pass ``block=True`` (which block_until_ready's the result); the trainer
-uses blocking timers only at log boundaries. Scopes also emit
-``jax.profiler.TraceAnnotation`` so they show up in xplane traces.
+device work is async, so a timer around a jitted call measures its dispatch;
+the wait for the device is a scope of its own where the host reads a result
+back (``trainer/loss_sync``). Scopes also emit
+``jax.profiler.TraceAnnotation`` so they show up in xplane traces, on the
+device trace's clock. Names follow one rule, ``<layer>/<what>``
+(doc/observability.md lists them).
 """
 
 from __future__ import annotations
 
-import contextlib
-import threading
 from paddle_tpu.utils import concurrency as cc
 import time
 from dataclasses import dataclass, field
-from typing import Dict, Iterator
+from typing import Dict, Optional, Tuple
 
 
 @dataclass
@@ -46,11 +46,28 @@ class StatSet:
         self._lock = cc.Lock()
 
     def get(self, name: str) -> Stat:
+        st = self._stats.get(name)  # a hit needs no lock: dict reads are atomic
+        if st is None:
+            with self._lock:
+                st = self._stats.setdefault(name, Stat(name))
+        return st
+
+    def snapshot(self) -> Dict[str, Tuple[int, float]]:
+        """name -> (count, total_s) now; two snapshots bracket a pass and
+        their difference is what the pass spent in each scope."""
         with self._lock:
-            st = self._stats.get(name)
-            if st is None:
-                st = self._stats[name] = Stat(name)
-            return st
+            stats = list(self._stats.values())
+        return {s.name: (s.count, s.total_s) for s in stats}
+
+    def growth_since(self, before: Dict[str, Tuple[int, float]]) -> Dict[str, list]:
+        """name -> [count, total_s] added since the ``before`` snapshot
+        (scopes that did not run are left out)."""
+        out = {}
+        for name, (count, total_s) in self.snapshot().items():
+            c0, t0 = before.get(name, (0, 0.0))
+            if count > c0:
+                out[name] = [count - c0, round(total_s - t0, 6)]
+        return out
 
     def reset(self) -> None:
         with self._lock:
@@ -72,29 +89,69 @@ class StatSet:
 
 global_stats = StatSet()
 
+# (TraceAnnotation, StepTraceAnnotation, record_perf), looked up on the
+# first scope and kept: importing this module must not pull in jax — the
+# supervisor CLI (`paddle supervise`) imports the utils package and has to
+# stay usable when the accelerator runtime is exactly what keeps crashing —
+# and a scope on the step's path must not pay two import statements each
+# time it opens
+_hooks: Optional[tuple] = None
 
-@contextlib.contextmanager
-def stat_timer(name: str, block_on=None) -> Iterator[None]:
-    """Time a scope into ``global_stats``, the jax profiler trace, and —
-    when ``--trace_events_path`` configured a collector — the span layer
-    (observability/spans.py), where the same named scopes export as
-    nested Chrome trace events.
 
-    ``block_on``: optional pytree whose leaves are block_until_ready'd before
-    stopping the clock, so device time is included.
-    """
-    # lazy: importing this module must not pull in jax — the supervisor
-    # CLI (`paddle supervise`) imports the utils package and has to stay
-    # usable when the accelerator runtime is exactly what keeps crashing
+def _lookup_hooks() -> tuple:
+    global _hooks
     import jax
 
     from paddle_tpu.observability import spans
 
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
-        yield
-    if block_on is not None:
-        jax.block_until_ready(block_on)
-    dt = time.perf_counter() - t0
-    global_stats.get(name).add(dt)
-    spans.record_perf(name, t0, dt)
+    _hooks = (jax.profiler.TraceAnnotation, jax.profiler.StepTraceAnnotation,
+              spans.record_perf)
+    return _hooks
+
+
+class stat_timer:
+    """THE span primitive: ``with stat_timer("<layer>/<what>"):`` times a
+    scope into ``global_stats`` (total and count: what the ``pass_end``
+    record's ``spans`` and the pass-end log dump read), the jax profiler
+    trace (a ``TraceAnnotation``, so the span lies on the device trace's
+    clock) and — when ``--trace_events_path`` configured a collector —
+    the span layer (observability/spans.py), where the same named scopes
+    export as nested Chrome trace events. With no trace running and no
+    collector a scope costs two clock reads, one TraceMe enter/exit and
+    one locked add.
+
+    ``step_num``: the scope is the root of one step — a
+    ``StepTraceAnnotation`` carrying the number, so that the spans of a
+    step share an identifier in the trace.
+
+    A scope left by an exception, or ``drop()``ped, reaches the profiler
+    trace only: it is no completed unit of the work it names.
+    """
+
+    __slots__ = ("name", "step_num", "_t0", "_ann", "_dropped")
+
+    def __init__(self, name: str, step_num: Optional[int] = None):
+        self.name = name
+        self.step_num = step_num
+        self._dropped = False
+
+    def __enter__(self) -> "stat_timer":
+        hooks = _hooks or _lookup_hooks()
+        self._ann = (hooks[0](self.name) if self.step_num is None
+                     else hooks[1](self.name, step_num=self.step_num))
+        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def drop(self) -> None:
+        """Leave this scope out of the StatSet and the collector (a pull
+        that found the pass's end is no step)."""
+        self._dropped = True
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is None and not self._dropped:
+            dt = time.perf_counter() - self._t0
+            global_stats.get(self.name).add(dt)
+            _hooks[2](self.name, self._t0, dt)
+        return False

@@ -234,11 +234,13 @@ def test_trace_events_export_loads_and_nests(tmp_path):
         by_name.setdefault(ev["name"], []).append(ev)
     # trainer / data / checkpoint spans all present
     assert "trainer/pass" in by_name
-    assert "train_step" in by_name
+    assert "trainer/launch" in by_name
     assert "checkpoint/save" in by_name
-    # nesting: every train_step lies inside some trainer/pass span
+    # one name a phase: the old flat names are gone
+    assert not {"train_step", "onePass", "test"} & set(by_name)
+    # nesting: every launch lies inside some trainer/pass span
     passes = [(e["ts"], e["ts"] + e["dur"]) for e in by_name["trainer/pass"]]
-    for step in by_name["train_step"]:
+    for step in by_name["trainer/launch"]:
         s0, s1 = step["ts"], step["ts"] + step["dur"]
         assert any(p0 <= s0 and s1 <= p1 + 1 for p0, p1 in passes), (
             (s0, s1), passes

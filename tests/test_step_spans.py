@@ -1,0 +1,235 @@
+"""The train step split in place (doc/observability.md "Spans"): every
+phase of a launch is a `stat_timer` span under one `trainer/step` root, on
+the profiler trace's clock and in the `pass_end` record's `spans`; every
+layer, the cost and the optimizer run under a `jax.named_scope`; and each
+compile's optimized HLO text, which maps a profile's device events to those
+scopes, is kept beside its record. No assertion here is on a duration."""
+
+import glob
+import os
+import re
+import sys
+import textwrap
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.config import parse_config
+from paddle_tpu.observability import metrics as obs
+from paddle_tpu.observability import spans as obs_spans
+from paddle_tpu.trainer import Trainer
+from paddle_tpu.utils.flags import FLAGS
+from paddle_tpu.utils.stats import global_stats, stat_timer
+
+pytestmark = pytest.mark.obs
+
+PROVIDER_DIR = os.path.join(os.path.dirname(__file__), "providers")
+STEPS = 3
+# the spans of one launch, all on the trainer's thread and inside its step
+PHASES = ("trainer/data_wait", "trainer/flops_count", "trainer/launch",
+          "trainer/loss_sync", "trainer/eval_outputs", "trainer/housekeeping")
+# the rest of the table of doc/observability.md that this run must show
+OTHERS = ("trainer/step", "trainer/pass", "trainer/test", "data/prefetch_wait",
+          "data/h2d", "data/provider_next", "data/pack",
+          "eval/classification_error", "eval/readback", "checkpoint/save")
+
+
+def _config(tmp):
+    (tmp / "train.list").write_text("1\n")
+    (tmp / "test.list").write_text("99\n")
+    # synthetic_bow yields 400 samples a file: 160 + 160 + 80 = three steps
+    (tmp / "conf.py").write_text(textwrap.dedent(f"""
+    from paddle_tpu.trainer_config_helpers import *
+
+    define_py_data_sources2(train_list={str(tmp / 'train.list')!r},
+                            test_list={str(tmp / 'test.list')!r},
+                            module="synthetic_bow", obj="process")
+    settings(batch_size=160, learning_rate=0.02, learning_method=AdamOptimizer())
+    data = data_layer(name="word", size=100)
+    output = fc_layer(input=data, size=2, act=SoftmaxActivation(), name="output")
+    label = data_layer(name="label", size=2)
+    outputs(classification_cost(input=output, label=label))
+    """))
+    return str(tmp / "conf.py")
+
+
+def _train(tmp, metrics_path):
+    """One pass of three steps; returns the run's records."""
+    flags = dict(save_dir=str(tmp / "out"), metrics_path=metrics_path,
+                 num_passes=1, start_pass=0, log_period=0, init_model_path="",
+                 trace_events_path="", seed=7)
+    before = {k: getattr(FLAGS, k) for k in flags}
+    sys.path.insert(0, PROVIDER_DIR)
+    try:
+        cfg = parse_config(_config(tmp))
+        for k, v in flags.items():
+            setattr(FLAGS, k, v)
+        obs.registry().reset()
+        Trainer(cfg).train(num_passes=1)
+        path = os.path.join(metrics_path or FLAGS.save_dir, "metrics.jsonl")
+        return list(obs.read_records(path))
+    finally:
+        sys.path.remove(PROVIDER_DIR)
+        obs.configure("")
+        obs_spans.configure("")
+        for k, v in before.items():
+            setattr(FLAGS, k, v)
+
+
+def _program_spans(trace_dir):
+    """{thread line: [(name, start, end, step number)]} of the
+    `<layer>/<what>` events of a profiler trace."""
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                      dict(e.stats).get("step_num"))
+                     for e in line.events
+                     if re.fullmatch(r"[a-z_]+/[a-z0-9_]+", e.name)]
+            if spans:
+                lines[(plane.name, line.name, i)] = spans
+    return lines
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("step_spans")
+    trace_dir = str(tmp / "trace")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        records = _train(tmp, str(tmp / "metrics"))
+    finally:
+        jax.profiler.stop_trace()
+    return _program_spans(trace_dir), records, tmp
+
+
+def _trainer_line(lines):
+    return next(sp for sp in lines.values()
+                if any(s[0] == "trainer/step" for s in sp))
+
+
+def test_every_span_of_the_table_is_in_the_trace(traced):
+    lines, _, _ = traced
+    seen = {s[0] for sp in lines.values() for s in sp}
+    assert seen >= set(PHASES) | set(OTHERS), sorted(set(PHASES + OTHERS) - seen)
+    # the feeder's two halves run on threads of their own, so on other
+    # lines of the trace than the trainer's
+    trainer = {s[0] for s in _trainer_line(lines)}
+    assert "data/pack" not in trainer and "data/provider_next" not in trainer
+    # one name, one rule
+    assert not seen & {"train_step", "onePass", "test"}
+
+
+def test_each_phase_lies_inside_a_step_and_steps_tile_the_pass(traced):
+    line = _trainer_line(traced[0])
+    steps = [s for s in line if s[0] == "trainer/step"]
+    for name, start, end, _ in line:
+        if name in PHASES:
+            assert any(a <= start and end <= b for _, a, b, _ in steps), name
+    # the steps that hold a launch carry the batch numbers 0, 1, 2; the one
+    # more is the pull that found the pass's end
+    launches = [s for s in line if s[0] == "trainer/launch"]
+    full = [s for s in steps
+            if any(s[1] <= l[1] and l[2] <= s[2] for l in launches)]
+    assert [s[3] for s in full] == list(range(STEPS))
+    assert len(steps) == STEPS + 1
+    (_, p0, p1, _), = [s for s in line if s[0] == "trainer/pass"]
+    assert all(p0 <= a and b <= p1 for _, a, b, _ in steps)
+    assert sum(b - a for _, a, b, _ in steps) >= 0.95 * (p1 - p0)
+    # the evaluator's read-back is inside its evaluator, inside eval_outputs
+    evs = [s for s in line if s[0] == "trainer/eval_outputs"]
+    for name, start, end, _ in line:
+        if name == "eval/readback" and any(a <= start < b for _, a, b, _ in full):
+            assert any(a <= start and end <= b for _, a, b, _ in evs)
+
+
+def test_pass_end_record_counts_the_same_spans(traced):
+    _, records, _ = traced
+    (end,) = [r for r in records if r["kind"] == "pass_end"]
+    spans = end["spans"]
+    for name in PHASES + ("trainer/step", "eval/classification_error", "data/h2d"):
+        assert spans[name][0] == STEPS, (name, spans[name])
+        assert spans[name][1] >= 0
+    assert spans["eval/readback"][0] == 2 * STEPS      # outputs, then labels
+    assert spans["data/prefetch_wait"][0] >= STEPS
+    # the pass's own span closes after its record is written
+    assert "trainer/pass" not in spans
+
+
+def test_compile_record_names_the_kept_hlo_text(traced):
+    _, records, tmp = traced
+    compiles = [r for r in records if r["kind"] == "compile"]
+    assert {r["group"] for r in compiles} >= {"train_step", "test_fwd"}
+    for r in compiles:
+        assert os.path.dirname(r["hlo_path"]) == str(tmp / "metrics" / "hlo")
+        with open(r["hlo_path"]) as f:
+            text = f.read()
+        assert text.startswith("HloModule ")
+    step = next(r for r in compiles if r["group"] == "train_step")
+    with open(step["hlo_path"]) as f:
+        assert 'op_name="jit(step)/optimizer/' in f.read()
+
+
+def test_no_hlo_is_kept_without_metrics_path(tmp_path):
+    records = _train(tmp_path, "")          # records go to --save_dir
+    compiles = [r for r in records if r["kind"] == "compile"]
+    assert compiles and not any("hlo_path" in r for r in compiles)
+    assert not glob.glob(str(tmp_path / "**" / "hlo"), recursive=True)
+
+
+def test_step_hlo_holds_a_scope_for_every_layer_cost_and_optimizer():
+    from paddle_tpu.flagship import nmt_batch, nmt_config
+
+    tc = nmt_config(vocab=300, dim=32, batch_size=4)
+    trainer = Trainer(tc)
+    text = trainer.train_step.lower(
+        trainer.params, trainer.opt_state, nmt_batch(vocab=300, B=4, T=6),
+        jax.random.PRNGKey(0), jnp.asarray(4.0)).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    fed = ("data", "agent", "sequence_agent", "scatter_agent")   # no compute
+    for layer in tc.model_config.layers:
+        if layer.type not in fed:
+            scope = f"{layer.type}:{layer.name}"
+            assert any(scope in n for n in op_names), scope
+    assert any("/jvp(cost)/" in n for n in op_names)
+    assert any(n.startswith("jit(step)/optimizer/") for n in op_names)
+    # a layer of the group's step nests under the group, backward included
+    group = "recurrent_layer_group:decoder_group"
+    assert any(f"jvp({group})/" in n and "gru_step:gru_decoder" in n
+               for n in op_names)
+    assert any(f"transpose(jvp({group}))/" in n and "gru_step:gru_decoder" in n
+               for n in op_names)
+
+
+def test_stat_timer_alone_adds_only_the_statset_entry(monkeypatch):
+    obs_spans.configure("")
+    before = global_stats.snapshot()
+    with stat_timer("test/scope"):
+        pass
+    with stat_timer("test/step", step_num=7) as root:
+        assert root.step_num == 7
+    assert global_stats.growth_since(before).keys() == {"test/scope", "test/step"}
+    assert global_stats.get("test/scope").count == before.get(
+        "test/scope", (0, 0.0))[0] + 1
+    # a dropped scope and one left by an exception reach the trace only
+    before = global_stats.snapshot()
+    with stat_timer("test/scope") as sp:
+        sp.drop()
+    with pytest.raises(KeyError):
+        with stat_timer("test/scope"):
+            raise KeyError("x")
+    assert global_stats.growth_since(before) == {}
+    # with a collector the same scope is also a Chrome trace event
+    events = []
+    monkeypatch.setattr(obs_spans, "_collector", type(
+        "C", (), {"_t0": 0.0, "record": lambda self, *a: events.append(a)})())
+    with stat_timer("test/scope"):
+        pass
+    assert [e[0] for e in events] == ["test/scope"]
