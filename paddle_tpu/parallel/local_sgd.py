@@ -81,11 +81,13 @@ class LocalSgd:
 
     def __init__(self, step_body, mesh: Mesh, ratio: float):
         """``step_body(params, opt_state, batch, rng, batch_size) ->
-        (new_params, new_opt, loss, kept_outputs)`` is the SAME one-batch
-        closure the sync path jits (Trainer._one_batch_step /
+        (new_params, new_opt, loss, kept_outputs, *sums)`` is the SAME
+        one-batch closure the sync path jits (Trainer._one_batch_step /
         __graft_entry__._train_step) — taken whole, not rebuilt from
         grad_fn + updater, so the sync and local-SGD per-batch semantics
-        cannot diverge."""
+        cannot diverge. ``sums`` (the trainer's: the evaluators'
+        per-batch states) are statistics of a replica's sub-batch that
+        add up to the global batch's."""
         check_data_only(mesh)
         self.mesh = mesh
         self.R = data_axis_size(mesh)
@@ -155,7 +157,7 @@ class LocalSgd:
             rngs = jax.random.split(rng, R)
             # n (the GLOBAL sample count) broadcasts unmapped: every
             # replica advances its schedule counter by the global batch
-            new_pr, new_or, losses, keeps = jax.vmap(
+            new_pr, new_or, losses, keeps, *sums = jax.vmap(
                 body, in_axes=(0, 0, 0, 0, None)
             )(params_r, opt_r, batch_r, rngs, n)
             # kept outputs back to global batch order [B, ...] for the
@@ -164,15 +166,25 @@ class LocalSgd:
                 lambda x: x.reshape((-1,) + x.shape[2:]) if x.ndim >= 2 else x,
                 keeps,
             )
-            return new_pr, new_or, jnp.mean(losses), keep_flat
+            # summed over the replicas beside the loss's mean: a few
+            # numbers, the same on every process
+            sums = jax.tree_util.tree_map(lambda x: x.sum(axis=0), sums)
+            return (new_pr, new_or), (jnp.mean(losses), keep_flat, *sums)
 
         b_spec = jax.tree_util.tree_map(lambda _: self._stacked, batch_example)
-        return jax.jit(
+        jitted = jax.jit(
             lstep,
             in_shardings=(self._stacked, self._stacked, b_spec, self._repl, self._repl),
-            out_shardings=(self._stacked, self._stacked, None, None),
+            # the second group is as long as the body's own outputs
+            out_shardings=((self._stacked, self._stacked), None),
             donate_argnums=(0, 1),
         )
+
+        def step(*args):
+            stacks, rest = jitted(*args)
+            return (*stacks, *rest)
+
+        return step
 
     # ------------------------------------------------------------- merge
 

@@ -310,9 +310,9 @@ def shard_train_step(step, mesh: Mesh, gm, donate: bool = True,
     input buffers valid after the call (the trainer's skip/rollback
     divergence policies must be able to discard a poisoned update).
     ``extra_outs``: trailing aux outputs beyond the canonical
-    (params, opt_state, loss, keep) — the numerics health pytree rides
-    this way; shardings for aux are left to jit (tiny replicated
-    scalars)."""
+    (params, opt_state, loss, keep) — the evaluators' per-batch states
+    and the numerics health pytree ride this way, replicated: a few
+    numbers each, which every process reads back whole."""
     param_shards = _param_shardings(mesh, gm)
     repl = NamedSharding(mesh, P())
     bs = batch_sharding(mesh)
@@ -331,7 +331,7 @@ def shard_train_step(step, mesh: Mesh, gm, donate: bool = True,
                 step,
                 in_shardings=(p_spec, o_spec, b_spec, repl, repl),
                 out_shardings=(p_spec, o_spec, None, None)
-                + (None,) * extra_outs,
+                + (repl,) * extra_outs,
                 donate_argnums=(0, 1) if donate else (),
             )
             cache[treedef] = fn
@@ -353,7 +353,8 @@ def shard_train_step(step, mesh: Mesh, gm, donate: bool = True,
 def shard_accum_steps(astep, ustep, mesh: Mesh, gm, donate: bool = True):
     """Mesh-shard the gradient-accumulation pair
     (num_batches_per_send_parameter > 1): ``astep(params, acc, batch,
-    rng, n)`` accumulates one batch's gradients; ``ustep(params,
+    rng, n) -> (params, acc, loss, keep, eval_states)`` accumulates one
+    batch's gradients; ``ustep(params,
     opt_state, acc, total_n)`` applies one optimizer update. The
     accumulator tree mirrors the parameter tree, so it takes the
     parameter shardings. ``donate=False``: see shard_train_step."""
@@ -375,7 +376,7 @@ def shard_accum_steps(astep, ustep, mesh: Mesh, gm, donate: bool = True):
             fn = jax.jit(
                 astep,
                 in_shardings=(ps, ps, b_spec, repl, repl),
-                out_shardings=(ps, ps, None, None),
+                out_shardings=(ps, ps, None, None, repl),
                 donate_argnums=(0, 1) if donate else (),
             )
             a_cache[treedef] = fn

@@ -5,17 +5,27 @@ Reference: /root/reference/paddle/gserver/evaluators/Evaluator.cpp
 AucEvaluator Evaluator.h:155, PrecisionRecallEvaluator:234, printers
 :870-1235), ChunkEvaluator.cpp, CTCErrorEvaluator.cpp.
 
-Evaluators accumulate over batches on the host (numpy) from layer outputs —
-they're observability, not part of the jitted step.
+Evaluators accumulate over batches on the host, in float64. Where an
+evaluator's statistic is a masked reduction of its input layers
+(`MaskedReductionEvaluator`: classification_error, seq_classification_error,
+sum, last-column-sum) one batch's contribution is `batch_state(args)`, a
+traceable function of the layers' padded `Argument`s: the trainer calls it
+inside the jitted train step, so the step returns a few numbers and the
+evaluator's input layers are no program outputs; `eval_batch` is the same
+function, jitted, on whatever arrays it is handed (`Trainer.test()`). Every
+other evaluator reads its layers' values back and computes in numpy.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.graph.argument import Argument
+from paddle_tpu.observability import metrics as obs
 from paddle_tpu.proto import EvaluatorConfig, ModelConfig
 from paddle_tpu.utils.registry import Registry
 from paddle_tpu.utils.stats import stat_timer
@@ -37,6 +47,14 @@ class Evaluator:
 
     def eval_batch(self, args: List[Argument]) -> None:
         raise NotImplementedError
+
+    def batch_state(self, args: List[Argument]) -> Optional[jax.Array]:
+        """One batch's contribution to ``merge_state()`` as a traceable
+        function of the input layers' Arguments, or None where the
+        evaluator (or these shapes) has no such form and must be fed the
+        layers' values on the host. Decided from the evaluator's type and
+        the shapes alone, so inside a trace the choice is static."""
+        return None
 
     def result(self) -> Dict[str, float]:
         raise NotImplementedError
@@ -105,74 +123,206 @@ class Evaluator:
         return np.argmax(Evaluator._rows(arg), axis=-1)
 
 
-@register_evaluator("classification_error")
-class ClassificationErrorEvaluator(Evaluator):
+# ---- evaluators whose per-batch statistic is a masked reduction --------
+
+
+def _row_layout(arg: Argument) -> Optional[Tuple[int, ...]]:
+    """Shape of the axes that index an Argument's rows in the padded form
+    ([B]; [B, T] under ``seq_lengths``; [B, S, T] under
+    ``sub_seq_lengths``), or None where the lengths do not go with the
+    array's rank and the rows have to be gathered on the host."""
+    rows = arg.value.shape[:-1] if arg.value is not None else arg.ids.shape
+    rank = (3 if arg.sub_seq_lengths is not None
+            else 2 if arg.seq_lengths is not None else 1)
+    return tuple(rows) if len(rows) == rank else None
+
+
+def _row_mask(arg: Argument) -> Optional[jax.Array]:
+    """True on an Argument's real rows; None where every row is real."""
+    if arg.sub_seq_lengths is not None:
+        return arg.sub_seq_mask(bool)
+    if arg.seq_lengths is not None:
+        return arg.seq_mask(bool)
+    return None
+
+
+def _values(arg: Argument) -> jax.Array:
+    """[..., D] values in the layer's own dtype; ids read as one column."""
+    if arg.value is not None:
+        return arg.value
+    return arg.ids[..., None].astype(jnp.float32)
+
+
+def _over_real_rows(x: jax.Array, mask: Optional[jax.Array]) -> jax.Array:
+    """f32[2]: the sum of ``x`` (one entry a row) over the real rows, and
+    how many rows are real. Padding is masked, never gathered."""
+    if mask is None:
+        n = x.size
+    else:
+        x, n = jnp.where(mask, x, jnp.zeros_like(x)), mask.sum()
+    total = x.sum(dtype=jnp.int32 if x.dtype == bool else jnp.float32)
+    return jnp.stack([total.astype(jnp.float32),
+                      jnp.asarray(n, jnp.float32)])
+
+
+@jax.jit
+def _classification_state(out: Argument, label: Argument, threshold):
+    v = _values(out)
+    if v.shape[-1] == 1:
+        # one column is the probability of class 1, cut at the threshold
+        pred = ((threshold > 0) & (v[..., 0] > threshold)).astype(jnp.int32)
+    else:
+        # over the values the host would read, in the layer's own dtype;
+        # first index on ties, as np.argmax
+        pred = jnp.argmax(v, axis=-1)
+    labels = (label.ids if label.ids is not None
+              else jnp.argmax(label.value, axis=-1))
+    return _over_real_rows(pred != labels, _row_mask(out))
+
+
+@jax.jit
+def _seq_classification_state(out: Argument, label: Argument):
+    pred = jnp.argmax(out.value, axis=-1)            # [B, T] or [B, S, T]
+    ids = label.ids                                  # one a sample, or a frame
+    wrong = pred != ids.reshape(ids.shape + (1,) * (pred.ndim - ids.ndim))
+    mask = _row_mask(out)
+    if mask is not None:
+        wrong &= mask
+    return _over_real_rows(wrong.any(axis=tuple(range(1, pred.ndim))), None)
+
+
+@jax.jit
+def _sum_state(arg: Argument):
+    return _over_real_rows(
+        _values(arg).sum(axis=-1, dtype=jnp.float32), _row_mask(arg))
+
+
+@jax.jit
+def _column_sum_state(arg: Argument):
+    return _over_real_rows(
+        _values(arg)[..., -1].astype(jnp.float32), _row_mask(arg))
+
+
+class MaskedReductionEvaluator(Evaluator):
+    """An evaluator whose accumulated state is a short vector that merges
+    by summation and whose per-batch contribution is a masked reduction
+    of its input layers: ``batch_state(args)`` is the one definition of
+    the statistic, inside the train step and outside it."""
+
+    WIDTH = 2
+
     def start(self):
-        self.wrong = 0.0
-        self.total = 0.0
+        self.state = np.zeros(self.WIDTH, np.float64)
+
+    def add_state(self, state) -> None:
+        """Add per-batch states: f32[WIDTH], or stacked [..., WIDTH] (a
+        launch of several batches, one a replica), summed in float64."""
+        self.state += np.asarray(state, np.float64).reshape(
+            -1, self.WIDTH).sum(axis=0)
 
     def eval_batch(self, args):
-        out, label = args[0], args[1]
-        probs = self._rows(out)
-        labels = self._label_rows(label)
-        if self.cfg.classification_threshold > 0 and probs.shape[-1] == 1:
-            pred = (probs[:, 0] > self.cfg.classification_threshold).astype(np.int64)
-        else:
-            pred = np.argmax(probs, axis=-1)
-        n = min(len(pred), len(labels))
-        self.wrong += float(np.sum(pred[:n] != labels[:n]))
-        self.total += n
-
-    def result(self):
-        return {"classification_error": self.wrong / max(self.total, 1.0)}
+        state = self.batch_state(args)
+        if state is None:
+            state = self.batch_state(self._as_one_sequence(args))
+        self.add_state(state)
 
     def merge_state(self):
-        return np.array([self.wrong, self.total], np.float64)
+        return self.state.copy()
 
     def load_state(self, vec):
-        self.wrong, self.total = float(vec[0]), float(vec[1])
+        self.state = np.array(vec, np.float64)
+
+    def _mean(self) -> float:
+        """state[0] over the rows counted in state[1]."""
+        return float(self.state[0] / max(self.state[1], 1.0))
+
+    def _as_one_sequence(self, args: List[Argument]) -> List[Argument]:
+        """Host re-layout of inputs that are not in one padded form (an
+        output and a label whose layouts differ): every input's real rows
+        gathered, paired by position and cut to the shorter, then laid
+        out as ONE sequence padded to a power of two, so that the masked
+        form applies and jit sees few shapes."""
+        values = self._rows(args[0])
+        # a label where the evaluator has one; a weight input is not read
+        labels = [self._label_rows(a) for a in args[1:2]]
+        n = min(len(r) for r in [values] + labels)
+        padded = max(1, 1 << (n - 1).bit_length())
+        lens = np.array([n], np.int32)
+
+        def lay(r, shape):
+            r = r[:n].reshape(shape)
+            pad = np.zeros((padded - n,) + r.shape[1:], r.dtype)
+            return np.concatenate([r, pad])[None]
+
+        return [Argument(value=lay(values, (n, -1)), seq_lengths=lens)] + [
+            Argument(ids=lay(r, (n,)), seq_lengths=lens) for r in labels]
+
+
+@register_evaluator("classification_error")
+class ClassificationErrorEvaluator(MaskedReductionEvaluator):
+    """state: [wrong rows, rows]."""
+
+    def batch_state(self, args):
+        out, label = args[0], args[1]
+        if _row_layout(out) is None or _row_layout(out) != _row_layout(label):
+            return None
+        return _classification_state(
+            out, label, self.cfg.classification_threshold)
+
+    def result(self):
+        return {"classification_error": self._mean()}
 
 
 @register_evaluator("sum")
-class SumEvaluator(Evaluator):
-    def start(self):
-        self.sum = 0.0
-        self.total = 0.0
+class SumEvaluator(MaskedReductionEvaluator):
+    """state: [sum of every value, rows]."""
 
-    def eval_batch(self, args):
-        rows = self._rows(args[0])
-        self.sum += float(rows.sum())
-        self.total += rows.shape[0]
+    def batch_state(self, args):
+        return None if _row_layout(args[0]) is None else _sum_state(args[0])
 
     def result(self):
-        return {"sum": self.sum, "mean": self.sum / max(self.total, 1.0)}
-
-    def merge_state(self):
-        return np.array([self.sum, self.total], np.float64)
-
-    def load_state(self, vec):
-        self.sum, self.total = float(vec[0]), float(vec[1])
+        return {"sum": float(self.state[0]), "mean": self._mean()}
 
 
 @register_evaluator("last-column-sum")
-class ColumnSumEvaluator(Evaluator):
-    def start(self):
-        self.sum = 0.0
-        self.total = 0.0
+class ColumnSumEvaluator(MaskedReductionEvaluator):
+    """state: [sum of the last column, rows]."""
 
-    def eval_batch(self, args):
-        rows = self._rows(args[0])
-        self.sum += float(rows[:, -1].sum())
-        self.total += rows.shape[0]
+    def batch_state(self, args):
+        return (None if _row_layout(args[0]) is None
+                else _column_sum_state(args[0]))
 
     def result(self):
-        return {"column_sum": self.sum, "column_mean": self.sum / max(self.total, 1.0)}
+        return {"column_sum": float(self.state[0]),
+                "column_mean": self._mean()}
 
-    def merge_state(self):
-        return np.array([self.sum, self.total], np.float64)
 
-    def load_state(self, vec):
-        self.sum, self.total = float(vec[0]), float(vec[1])
+@register_evaluator("seq_classification_error")
+class SequenceClassificationErrorEvaluator(MaskedReductionEvaluator):
+    """Per-sequence error (ref: SequenceClassificationErrorEvaluator,
+    Evaluator.cpp:111): a sequence counts as wrong if ANY valid frame's
+    argmax disagrees with the label. state: [wrong sequences, sequences]."""
+
+    def batch_state(self, args):
+        out, label = args[0], args[1]
+        if out.value is None:
+            frames = None
+        elif out.seq_lengths is None:      # no lengths: every frame is real
+            frames = tuple(out.value.shape[:-1])
+        else:
+            frames = _row_layout(out)
+        ids = None if label.ids is None else tuple(label.ids.shape)
+        if (not frames or len(frames) < 2 or not ids
+                or ids != frames[:len(ids)]):
+            raise ValueError(
+                "seq_classification_error needs a sequence output "
+                "[B, T, C] and integer labels [B] or [B, T]; got output "
+                f"value {getattr(out.value, 'shape', None)}, label ids "
+                f"{getattr(label.ids, 'shape', None)}")
+        return _seq_classification_state(out, label)
+
+    def result(self):
+        return {"seq_classification_error": self._mean()}
 
 
 @register_evaluator("last-column-auc")
@@ -215,42 +365,6 @@ class AucEvaluator(Evaluator):
     def load_state(self, vec):
         self.pos = np.asarray(vec[: self.BINS], np.float64)
         self.neg = np.asarray(vec[self.BINS :], np.float64)
-
-
-@register_evaluator("seq_classification_error")
-class SequenceClassificationErrorEvaluator(Evaluator):
-    """Per-sequence error (ref: SequenceClassificationErrorEvaluator,
-    Evaluator.cpp:111): a sequence counts as wrong if ANY valid frame's
-    argmax disagrees with the label."""
-
-    def start(self):
-        self.wrong = 0.0
-        self.total = 0.0
-
-    def eval_batch(self, args):
-        out, label = args[0], args[1]
-        v = np.asarray(out.value)                       # [B, T, C]
-        pred = np.argmax(v, axis=-1)
-        labels = np.asarray(label.ids)
-        lens = (
-            np.asarray(out.seq_lengths)
-            if out.seq_lengths is not None
-            else np.full((v.shape[0],), v.shape[1], np.int64)
-        )
-        for b in range(v.shape[0]):
-            t = int(lens[b])
-            lb = labels[b] if labels.ndim > 1 else np.full((t,), labels[b])
-            self.wrong += float(np.any(pred[b, :t] != lb[:t]))
-            self.total += 1.0
-
-    def result(self):
-        return {"seq_classification_error": self.wrong / max(self.total, 1.0)}
-
-    def merge_state(self):
-        return np.array([self.wrong, self.total], np.float64)
-
-    def load_state(self, vec):
-        self.wrong, self.total = float(vec[0]), float(vec[1])
 
 
 @register_evaluator("rank-auc")
@@ -588,17 +702,26 @@ class EvaluatorChain:
         # distributeEval (Evaluator.h:81-82) — instead of gathering raw
         # activations every batch.
         self.merge_fn = None
+        # names of the evaluators fed by add_states: a state computed
+        # inside a jitted step over a mesh is already global and the same
+        # on every process, so merge_fn must not sum it again
+        self._in_step: set = set()
+        # how often the statistic was computed inside the step, and how
+        # often from layer outputs outside it: one an evaluator a batch
+        self._device_batches = obs.registry().counter("eval.device_batches")
+        self._host_batches = obs.registry().counter("eval.host_batches")
         for cfg in model.evaluators:
             if names is not None and cfg.name not in names:
                 continue
             if cfg.type in evaluator_registry:
                 self.evaluators.append(evaluator_registry.get(cfg.type)(cfg))
 
-    def partition(self):
+    @staticmethod
+    def partition(evaluators: List[Evaluator]):
         """(mergeable, unmergeable) evaluators: mergeable ones carry
         summable state and can accumulate on local rows."""
         merge, gather = [], []
-        for e in self.evaluators:
+        for e in evaluators:
             (merge if e.merge_state() is not None else gather).append(e)
         return merge, gather
 
@@ -614,7 +737,9 @@ class EvaluatorChain:
     def _merged(self, e: Evaluator) -> Evaluator:
         """A view of e with cross-process-merged state (e itself keeps
         accumulating local rows; merging at read time is idempotent)."""
-        vec = None if self.merge_fn is None else e.merge_state()
+        if self.merge_fn is None or e.cfg.name in self._in_step:
+            return e
+        vec = e.merge_state()
         if vec is None:
             return e
         clone = type(e)(e.cfg)
@@ -628,23 +753,57 @@ class EvaluatorChain:
     def needed_layers(self) -> List[str]:
         """Layer outputs the chain reads — multi-process runs gather only
         these to the host (distributeEval analog, Evaluator.h:81-82)."""
-        seen: List[str] = []
-        for e in self.evaluators:
-            for n in e.cfg.input_layers:
-                if n not in seen:
-                    seen.append(n)
-        return seen
+        return self.layers_for(self.evaluators)
 
     def start(self):
         for e in self.evaluators:
             e.start()
 
+    @staticmethod
+    def _inputs(e: Evaluator, outputs: Dict[str, Argument]):
+        """The evaluator's input Arguments, or None where one is missing."""
+        args = [outputs[n] for n in e.cfg.input_layers if n in outputs]
+        return args if len(args) == len(e.cfg.input_layers) else None
+
+    def batch_states(self, outputs: Dict[str, Argument]) -> Dict[str, jax.Array]:
+        """{evaluator name: f32[k]} for the evaluators whose per-batch
+        statistic is a traceable function of these layers — called on a
+        train step's layer outputs inside its trace, before the step
+        selects what to keep; the others are fed on the host."""
+        states = {}
+        for e in self.evaluators:
+            args = self._inputs(e, outputs)
+            if args is None:
+                continue
+            # `<type>:<name>`, as a layer's scope: a profile's device time
+            # splits by evaluator too
+            with jax.named_scope(f"{e.cfg.type}:{e.cfg.name}"):
+                state = e.batch_state(args)
+            if state is not None:
+                states[e.cfg.name] = state
+        return states
+
+    def add_states(self, states: Dict[str, Any]) -> List[Evaluator]:
+        """Accumulate the states a train step returned (per batch, or
+        stacked); returns the evaluators that were not in the step."""
+        rest = []
+        for e in self.evaluators:
+            state = states.get(e.cfg.name)
+            if state is None:
+                rest.append(e)
+            else:
+                e.add_state(state)
+                self._in_step.add(e.cfg.name)
+                self._device_batches.inc()
+        return rest
+
     def eval_batch(self, outputs: Dict[str, Argument], only: Optional[List[Evaluator]] = None):
         for e in (self.evaluators if only is None else only):
-            args = [outputs[n] for n in e.cfg.input_layers if n in outputs]
-            if len(args) == len(e.cfg.input_layers):
+            args = self._inputs(e, outputs)
+            if args is not None:
                 with stat_timer(f"eval/{e.cfg.type}"):
                     e.eval_batch(args)
+                self._host_batches.inc()
 
     def summary(self) -> str:
         parts = []

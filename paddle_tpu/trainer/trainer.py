@@ -5,8 +5,15 @@ TPU-native replacement for the reference's Trainer/TrainerInternal
 TrainerInternal.cpp:64-170): the per-batch
 startBatch → forwardBackward(updateCallback) → finishBatch pipeline
 becomes ONE jit-compiled train_step (forward + grad + optimizer update
-fused by XLA, buffers donated); the pass loop, periodic test, stats,
-checkpointing and evaluators stay on the host.
+fused by XLA, buffers donated); the pass loop, periodic test, stats and
+checkpointing stay on the host. So do the evaluators' accumulators, but
+not their per-batch arithmetic where it is a masked reduction of layer
+outputs (classification_error, seq_classification_error, sum,
+last-column-sum): the step computes those statistics where the layers'
+values already are and returns {evaluator name: f32[k]} beside the loss,
+read in the same transfer; only the layers of the other evaluators
+(AUC, chunk, CTC, printers, ...) are still program outputs
+(``_keep_and_eval_states``).
 
 When a mesh is configured (opt_config.mesh_shape / FLAGS.mesh_shape) the
 step is sharded over devices — see paddle_tpu.parallel.spmd — which is the
@@ -633,23 +640,34 @@ class Trainer:
 
     # ------------------------------------------------------------- steps
 
-    def _kept_out_layers(self):
-        """Layer outputs the train step must return: network outputs plus
-        everything the evaluator chain reads."""
-        eval_layers = set()
-        for e in self.config.model_config.evaluators:
-            eval_layers.update(e.input_layers)
-        return set(self.gm.network.output_layer_names) | eval_layers
+    def _keep_and_eval_states(self):
+        """What a step body hands the host of its layer outputs, as
+        ``select(outputs) -> (keep, eval_states)``, called inside the
+        step's trace: the per-batch statistics of the evaluators that have
+        a traceable form for these shapes ({evaluator name: f32[k]}), and
+        the layers the step must still return: the network's outputs plus
+        the input layers of the evaluators that stay on the host."""
+        chain = EvaluatorChain(self.config.model_config)
+        net_outputs = set(self.gm.network.output_layer_names)
+
+        def select(outputs):
+            states = chain.batch_states(outputs)
+            host = [e for e in chain.evaluators if e.cfg.name not in states]
+            kept = net_outputs | set(chain.layers_for(host))
+            return {k: v for k, v in outputs.items() if k in kept}, states
+
+        return select
 
     def _one_batch_step(self, sparse: bool = True):
         """The single-batch grad→update→state→keep body shared by the
         ordinary train step and the fused-launch scan, so the two paths
-        cannot diverge."""
+        cannot diverge. Returns (params, opt_state, loss, kept layers,
+        evaluator states) and, with numerics telemetry, the health aux."""
         grad_fn = self.gm.grad_fn(
             remat=self.config.opt_config.remat, sparse=sparse
         )
         updater = self.updater
-        out_layers = self._kept_out_layers()
+        select = self._keep_and_eval_states()
         nm_groups = self._numerics_groups
 
         def step(params, opt_state, in_args, rng, batch_size):
@@ -661,15 +679,15 @@ class Trainer:
                 new_params, new_opt = updater(params, grads, opt_state, batch_size)
             for k, v in state_updates.items():
                 new_params[k] = v
-            keep = {k: v for k, v in outputs.items() if k in out_layers}
+            keep, eval_states = select(outputs)
             if nm_groups is None:
-                return new_params, new_opt, loss, keep
+                return new_params, new_opt, loss, keep, eval_states
             # numerics aux: fused into THIS launch (grads and both
             # parameter trees are already live on device) — one extra
             # [4]-vector per layer in the outputs, zero extra launches
             with jax.named_scope("numerics"):
                 health = obs_num.step_health(params, new_params, grads, nm_groups)
-            return new_params, new_opt, loss, keep, health
+            return new_params, new_opt, loss, keep, eval_states, health
 
         return step
 
@@ -689,7 +707,7 @@ class Trainer:
 
             return shard_train_step(
                 step, self._mesh, self.gm, donate=self._donate_steps,
-                extra_outs=1 if self._numerics_groups is not None else 0,
+                extra_outs=2 if self._numerics_groups is not None else 1,
             )
         return jax.jit(
             step, donate_argnums=(0, 1) if self._donate_steps else ()
@@ -704,7 +722,7 @@ class Trainer:
         vary per batch and cannot live in a fixed-shape accumulator."""
         grad_fn = self.gm.grad_fn(remat=self.config.opt_config.remat, sparse=False)
         updater = self.updater
-        out_layers = self._kept_out_layers()
+        select = self._keep_and_eval_states()
 
         def astep(params, acc, in_args, rng, n):
             loss, grads, outputs, state_updates = grad_fn(params, in_args, rng)
@@ -712,8 +730,8 @@ class Trainer:
             new_params = dict(params)
             for k, v in state_updates.items():  # BN stats advance per batch
                 new_params[k] = v
-            keep = {k: v for k, v in outputs.items() if k in out_layers}
-            return new_params, new_acc, loss, keep
+            keep, eval_states = select(outputs)
+            return new_params, new_acc, loss, keep, eval_states
 
         def ustep(params, opt_state, acc, total_n):
             mean = jax.tree_util.tree_map(lambda a: a / total_n, acc)
@@ -747,9 +765,9 @@ class Trainer:
             def body(carry, xs):
                 p, o = carry
                 in_args, rng, n = xs
-                # 4-tuple, or 5 with the numerics health aux — the scan
-                # stacks whatever ys the body returns, so both shapes
-                # ride the same machinery
+                # loss, kept layers, evaluator states, and the numerics
+                # health aux where it is on — the scan stacks whatever ys
+                # the body returns, so both shapes ride the same machinery
                 out = one(p, o, in_args, rng, n)
                 return (out[0], out[1]), tuple(out[2:])
 
@@ -1417,15 +1435,15 @@ class Trainer:
                             analytic_flops=self._flops_cache.get(launch_key),
                             pass_id=pass_id, step=batch_id,
                         )
-                    self.params, self.opt_state, losses, keeps = fused_out[:4]
+                    self.params, self.opt_state, losses, keeps, states = fused_out[:5]
                     if self._numerics_groups is not None:
                         # stays on device: read back only at the log period
-                        self._numerics_last = fused_out[4]
-                    # ONE device→host transfer per launch (losses + kept
-                    # outputs together); numpy slicing below adds no further
-                    # device dispatches
+                        self._numerics_last = fused_out[5]
+                    # ONE device→host transfer per launch (losses, evaluator
+                    # states and kept outputs together); numpy slicing below
+                    # adds no further device dispatches
                     with stat_timer("trainer/loss_sync"):
-                        losses_host, keeps_host = jax.device_get((losses, keeps))  # lint: disable=PTL002 -- the one designed sync: amortized over the k-batch launch, feeds the nonfinite gate
+                        losses_host, keeps_host, states_host = jax.device_get((losses, keeps, states))  # lint: disable=PTL002 -- the one designed sync: amortized over the k-batch launch, feeds the nonfinite gate
                     losses_host = np.asarray(losses_host)
                     if faultinject.is_active():
                         losses_host = np.asarray([
@@ -1464,12 +1482,12 @@ class Trainer:
                         "fused_step", launch_key, launch_s, batches=kf
                     )
                     step_dt = launch_s / kf
+                    def batch_of(tree, i):
+                        return jax.tree_util.tree_map(lambda x: x[i], tree)
+
                     results = [
-                        (
-                            float(losses_host[i]),
-                            jax.tree_util.tree_map(lambda x, i=i: x[i], keeps_host),
-                            ns[i],
-                        )
+                        (float(losses_host[i]), batch_of(keeps_host, i),
+                         batch_of(states_host, i), ns[i])
                         for i in range(kf)
                     ]
                 else:
@@ -1488,9 +1506,9 @@ class Trainer:
                     snap = self._nf_snapshot()
                     with stat_timer("trainer/launch"):
                         if self._accum_n > 1:
-                            loss, outputs = self._accum_step(batch, step_rng, n)
+                            loss, outputs, states = self._accum_step(batch, step_rng, n)
                         elif self._async:
-                            loss, outputs = self._async_step(batch, step_rng, n)
+                            loss, outputs, states = self._async_step(batch, step_rng, n)
                         else:
                             step_out = self._compiles.call(
                                 "train_step", launch_key, self.train_step,
@@ -1499,19 +1517,20 @@ class Trainer:
                                 analytic_flops=self._flops_cache.get(launch_key),
                                 pass_id=pass_id, step=batch_id,
                             )
-                            self.params, self.opt_state, loss, outputs = step_out[:4]
+                            self.params, self.opt_state, loss, outputs, states = step_out[:5]
                             if self._numerics_groups is not None:
-                                self._numerics_last = step_out[4]
+                                self._numerics_last = step_out[5]
                     # the host blocked on the device: the only phase in
-                    # which an idle device is not the host's doing
+                    # which an idle device is not the host's doing. The
+                    # evaluators' states (a few floats) ride the loss's read
                     with stat_timer("trainer/loss_sync"):
-                        loss_f = float(loss)  # lint: disable=PTL002 -- single-step path: the per-launch loss read IS the nonfinite gate
-                    loss_f = self._poisoned_loss(loss_f, pass_id, batch_id)
+                        loss_f, states = jax.device_get((loss, states))  # lint: disable=PTL002 -- single-step path: the per-launch loss read IS the nonfinite gate
+                    loss_f = self._poisoned_loss(float(loss_f), pass_id, batch_id)
                     step_dt = time.perf_counter() - t_step
                     self._pass_train_s += step_dt
                     if launch_key is not None:
                         self._compiles.note_exec("train_step", launch_key, step_dt)
-                    results = [(loss_f, outputs, n)]
+                    results = [(loss_f, outputs, states, n)]
                 if self._restart_pending:
                     # the run's first completed launch: restart latency is
                     # now fully paid (restore + trace + compile + step 1) —
@@ -1528,7 +1547,7 @@ class Trainer:
                         resumed=self._restored_pass is not None,
                     )
                 batch_id_start = batch_id
-                for loss_f, outputs, n in results:
+                for loss_f, outputs, states, n in results:
                     step_times.append(step_dt)
                     if not np.isfinite(loss_f):
                         # FP trap role (ref: feenableexcept(FE_INVALID|FE_DIVBYZERO|
@@ -1548,7 +1567,7 @@ class Trainer:
                             continue
                     stats.add(loss_f * n, n)
                     with stat_timer("trainer/eval_outputs"):
-                        self._eval_outputs(evaluators, outputs)
+                        self._eval_outputs(evaluators, outputs, states)
                     batch_id += 1
                     if self.flags.dot_period and batch_id % self.flags.dot_period == 0:
                         print(".", end="", flush=True, file=sys.stderr)
@@ -1993,14 +2012,14 @@ class Trainer:
         astep, ustep = self._accum_fns
         if self._acc is None:
             self._acc = jax.tree_util.tree_map(jnp.zeros_like, dict(self.params))
-        self.params, self._acc, loss, outputs = astep(
+        self.params, self._acc, loss, outputs, states = astep(
             self.params, self._acc, batch, step_rng, jnp.asarray(float(n))
         )
         self._acc_batches += 1
         self._acc_samples += n
         if self._acc_batches >= self._accum_n:
             self._accum_flush()
-        return loss, outputs
+        return loss, outputs, states
 
     def _accum_flush(self) -> None:
         if self._acc_batches == 0 or self._acc is None:
@@ -2032,7 +2051,7 @@ class Trainer:
         if self._lsgd_state is None:
             self._lsgd_state = self._local_sgd.stack(self.params, self.opt_state)
         pr, po = self._lsgd_state
-        pr, po, loss, outputs = self._local_sgd.step(
+        pr, po, loss, outputs, states = self._local_sgd.step(
             pr, po, batch, step_rng, jnp.asarray(float(n))
         )
         self._lsgd_state = (pr, po)
@@ -2040,7 +2059,7 @@ class Trainer:
         self._lsgd_batches += 1
         if self._lsgd_batches >= self._sync_n:
             self._lsgd_merge()
-        return loss, outputs
+        return loss, outputs, states
 
     def _lsgd_merge(self) -> None:
         pr, po = self._lsgd_state
@@ -2148,33 +2167,40 @@ class Trainer:
             cur = nxt
         yield cur
 
-    def _eval_outputs(self, evaluators: EvaluatorChain, outputs, gathered=False) -> None:
-        """Feed one batch's outputs to the evaluator chain.
+    def _eval_outputs(self, evaluators: EvaluatorChain, outputs,
+                      states=None, gathered=False) -> None:
+        """Feed one batch to the evaluator chain: ``states``, the per-batch
+        statistics the train step computed (already global over a mesh),
+        are added as they are; the evaluators that were not in the step
+        read ``outputs``, the layers the step kept for them.
 
-        Multi-process: evaluators with summable state accumulate over this
-        process's LOCAL row block and merge their small state vectors once
-        per read period (the reference's getState/distributeEval split,
-        Evaluator.h:81-82) — no per-batch [B, V] activation gather.
-        Evaluators without mergeable state (raw-record, printers) still
-        get their layers gathered per batch. The local/gather split is
-        decided ONCE per chain from global sharding metadata so every
-        process runs the same collectives. ``gathered``: outputs are
-        already full host values."""
+        Multi-process, for those: evaluators with summable state
+        accumulate over this process's LOCAL row block and merge their
+        small state vectors once per read period (the reference's
+        getState/distributeEval split, Evaluator.h:81-82) — no per-batch
+        [B, V] activation gather. Evaluators without mergeable state
+        (raw-record, printers) still get their layers gathered per batch.
+        The local/gather split is decided ONCE per chain from global
+        sharding metadata so every process runs the same collectives.
+        ``gathered``: outputs are already full host values."""
         if not evaluators:
+            return
+        host_evs = evaluators.add_states(states or {})
+        if not host_evs:
             return
         if self._multiproc and not gathered:
             from paddle_tpu.parallel import spmd
 
             plan = getattr(evaluators, "_dist_plan", None)
             if plan is None:
-                merge_evs, gather_evs = evaluators.partition()
+                merge_evs, gather_evs = evaluators.partition(host_evs)
                 local_layers = evaluators.layers_for(merge_evs)
                 if merge_evs and spmd.rows_locally_assemblable(outputs, local_layers):
                     evaluators.merge_fn = spmd.merge_eval_states
                 else:
                     # e.g. a vocab-sharded output: local rows are partial —
                     # fall back to gathering for everything
-                    gather_evs = evaluators.evaluators
+                    gather_evs = host_evs
                     merge_evs, local_layers = [], []
                 plan = evaluators._dist_plan = (
                     merge_evs, local_layers, gather_evs,
@@ -2190,7 +2216,7 @@ class Trainer:
                     self._gather_host(outputs, gather_layers), only=gather_evs
                 )
             return
-        evaluators.eval_batch(outputs)
+        evaluators.eval_batch(outputs, only=host_evs)
 
     def _warn_remainder(self, n: int) -> None:
         if not getattr(self, "_remainder_warned", False):

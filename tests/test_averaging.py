@@ -93,7 +93,7 @@ def test_nan_loss_aborts_training(tmp_path, monkeypatch):
         cfg_path.write_text(src)
         trainer = Trainer(parse_config(str(cfg_path)))
         # force a poisoned step: the trap must abort, not train through it
-        trainer._train_step_fn = lambda p, o, b, r, n: (p, o, jnp.nan, {})
+        trainer._train_step_fn = lambda p, o, b, r, n: (p, o, jnp.nan, {}, {})
         with pytest.raises(FloatingPointError, match="non-finite loss"):
             trainer.train(num_passes=1)
     finally:

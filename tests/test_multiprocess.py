@@ -29,6 +29,7 @@ from paddle_tpu.trainer import Trainer
 from paddle_tpu.utils.flags import FLAGS
 
 FLAGS.save_dir = ""
+FLAGS.metrics_path = os.path.join(ws, "mp_metrics")
 FLAGS.mesh_shape = "data=8"
 FLAGS.log_period = 0
 FLAGS.seed = 7
@@ -87,6 +88,8 @@ def _write_config(ws):
     data = data_layer(name="word", size=100)
     output = fc_layer(input=data, size=2, act=SoftmaxActivation(), name="output")
     label = data_layer(name="label", size=2)
+    sum_evaluator(output, name="rows")
+    auc_evaluator(input=output, label=label, name="auc")
     outputs(classification_cost(input=output, label=label))
     """)
     path = os.path.join(ws, "cfg.py")
@@ -107,7 +110,10 @@ def test_two_process_training_matches_single(tmp_path):
     from paddle_tpu.trainer import Trainer
     from paddle_tpu.utils.flags import FLAGS
 
+    from paddle_tpu.observability import metrics as obs
+
     FLAGS.save_dir = ""
+    FLAGS.metrics_path = os.path.join(ws, "ref_metrics")
     FLAGS.mesh_shape = "data=8"
     FLAGS.log_period = 0
     FLAGS.seed = 7
@@ -116,6 +122,8 @@ def test_two_process_training_matches_single(tmp_path):
         ref.train(num_passes=1)
     finally:
         FLAGS.mesh_shape = ""
+        FLAGS.metrics_path = ""
+        obs.configure("")
         sys.path.remove(PROVIDERS)
 
     outs = mp_harness.run_two_workers(
@@ -130,6 +138,28 @@ def test_two_process_training_matches_single(tmp_path):
             np.asarray(ref_v), mp_params[name], rtol=2e-4, atol=1e-5,
             err_msg=name,
         )
+
+    # the train pass's evaluators are computed inside the jitted step over
+    # the mesh, where the reduction is global already: every process reports
+    # the single-process numbers, and none counts a row process_count times
+    # (`rows.sum` adds up softmax rows, so it counts the samples) though the
+    # chain does merge `auc`, which is fed local rows on the host
+    def pass_end(run, name="metrics.jsonl"):
+        recs = obs.read_records(os.path.join(ws, run, name))
+        return [r for r in recs if r["kind"] == "pass_end"][-1]
+
+    ref_end = pass_end("ref_metrics")
+    assert abs(ref_end["rows.sum"] - ref_end["samples"]) < 1e-2
+    for name in ("metrics.jsonl", "metrics.host1.jsonl"):
+        end = pass_end("mp_metrics", name)
+        assert end["samples"] == ref_end["samples"]
+        assert abs(end["rows.sum"] - ref_end["rows.sum"]) < 1e-2, name
+        assert abs(end["rows.mean"] - 1.0) < 1e-5, name
+        err = "__cost_0__.classification_error.classification_error"
+        assert abs(end[err] - ref_end[err]) <= 5e-3, name
+        assert abs(end["auc.auc"] - ref_end["auc.auc"]) <= 5e-3, name
+        assert end["counters"]["eval.host_batches"] == (
+            end["counters"]["eval.device_batches"] / 2) > 0
 
     # merged evaluator metrics: identical on every process, and the
     # classification error matches the single-process run over the same

@@ -91,7 +91,7 @@ provider = trainer._provider(for_test=False)
 from paddle_tpu.parallel.spmd import globalize_batch
 import jax.numpy as jnp
 batch = globalize_batch(next(iter(provider.batches())), trainer._mesh)
-trainer.params, trainer.opt_state, loss, _ = trainer.train_step(
+trainer.params, trainer.opt_state, loss, *_ = trainer.train_step(
     trainer.params, trainer.opt_state, batch, jax.random.PRNGKey(0),
     jnp.asarray(64.0))
 assert np.isfinite(float(loss))

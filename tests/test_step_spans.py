@@ -30,12 +30,16 @@ STEPS = 3
 PHASES = ("trainer/data_wait", "trainer/flops_count", "trainer/launch",
           "trainer/loss_sync", "trainer/eval_outputs", "trainer/housekeeping")
 # the rest of the table of doc/observability.md that this run must show
+# (`eval/classification_error` from `trainer/test` alone: in a train step
+# the statistic is computed inside the step)
 OTHERS = ("trainer/step", "trainer/pass", "trainer/test", "data/prefetch_wait",
           "data/h2d", "data/provider_next", "data/pack",
-          "eval/classification_error", "eval/readback", "checkpoint/save")
+          "eval/classification_error", "checkpoint/save")
+# an evaluator with no in-step form: its layer stays a program output
+HOST_EVALUATOR = "value_printer_evaluator(input=output)"
 
 
-def _config(tmp):
+def _config(tmp, evaluator=""):
     (tmp / "train.list").write_text("1\n")
     (tmp / "test.list").write_text("99\n")
     # synthetic_bow yields 400 samples a file: 160 + 160 + 80 = three steps
@@ -49,12 +53,13 @@ def _config(tmp):
     data = data_layer(name="word", size=100)
     output = fc_layer(input=data, size=2, act=SoftmaxActivation(), name="output")
     label = data_layer(name="label", size=2)
+    {evaluator}
     outputs(classification_cost(input=output, label=label))
     """))
     return str(tmp / "conf.py")
 
 
-def _train(tmp, metrics_path):
+def _train(tmp, metrics_path, evaluator=""):
     """One pass of three steps; returns the run's records."""
     flags = dict(save_dir=str(tmp / "out"), metrics_path=metrics_path,
                  num_passes=1, start_pass=0, log_period=0, init_model_path="",
@@ -62,7 +67,7 @@ def _train(tmp, metrics_path):
     before = {k: getattr(FLAGS, k) for k in flags}
     sys.path.insert(0, PROVIDER_DIR)
     try:
-        cfg = parse_config(_config(tmp))
+        cfg = parse_config(_config(tmp, evaluator))
         for k, v in flags.items():
             setattr(FLAGS, k, v)
         obs.registry().reset()
@@ -96,18 +101,28 @@ def _program_spans(trace_dir):
     return lines
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    tmp = tmp_path_factory.mktemp("step_spans")
+def _traced(tmp, evaluator=""):
     trace_dir = str(tmp / "trace")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
-        records = _train(tmp, str(tmp / "metrics"))
+        records = _train(tmp, str(tmp / "metrics"), evaluator)
     finally:
         jax.profiler.stop_trace()
     return _program_spans(trace_dir), records, tmp
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """Every evaluator of the run is computed inside the train step."""
+    return _traced(tmp_path_factory.mktemp("step_spans"))
+
+
+@pytest.fixture(scope="module")
+def traced_host(tmp_path_factory):
+    """The same run with one evaluator more that stays on the host."""
+    return _traced(tmp_path_factory.mktemp("step_spans_host"), HOST_EVALUATOR)
 
 
 def _trainer_line(lines):
@@ -143,24 +158,55 @@ def test_each_phase_lies_inside_a_step_and_steps_tile_the_pass(traced):
     (_, p0, p1, _), = [s for s in line if s[0] == "trainer/pass"]
     assert all(p0 <= a and b <= p1 for _, a, b, _ in steps)
     assert sum(b - a for _, a, b, _ in steps) >= 0.95 * (p1 - p0)
-    # the evaluator's read-back is inside its evaluator, inside eval_outputs
-    evs = [s for s in line if s[0] == "trainer/eval_outputs"]
+    # no evaluator runs and nothing is read back inside a step whose
+    # evaluators are all computed in the step: `eval/*` is `test()`'s
+    tests = [s for s in line if s[0] == "trainer/test"]
+    assert not any(s[0] == "eval/readback" for s in line)
     for name, start, end, _ in line:
-        if name == "eval/readback" and any(a <= start < b for _, a, b, _ in full):
-            assert any(a <= start and end <= b for _, a, b, _ in evs)
+        if name.startswith("eval/"):
+            assert any(a <= start and end <= b for _, a, b, _ in tests), name
 
 
-def test_pass_end_record_counts_the_same_spans(traced):
-    _, records, _ = traced
-    (end,) = [r for r in records if r["kind"] == "pass_end"]
+def test_host_evaluator_reads_back_inside_its_span_inside_eval_outputs(
+        traced_host):
+    line = _trainer_line(traced_host[0])
+    steps = [s for s in line if s[0] == "trainer/step"]
+
+    def in_steps(name):          # `test()` runs the evaluators as well
+        return [s for s in line if s[0] == name
+                and any(a <= s[1] < b for _, a, b, _ in steps)]
+
+    evs = in_steps("trainer/eval_outputs")
+    printers = in_steps("eval/value_printer")
+    reads = in_steps("eval/readback")
+    assert len(evs) == len(printers) == len(reads) == STEPS
+    for inner, outer in ((reads, printers), (printers, evs)):
+        for _, start, end, _ in inner:
+            assert any(a <= start and end <= b for _, a, b, _ in outer)
+    # the other evaluator of the run is still computed in the step
+    assert not in_steps("eval/classification_error")
+
+
+def test_pass_end_record_counts_the_same_spans(traced, traced_host):
+    (end,) = [r for r in traced[1] if r["kind"] == "pass_end"]
     spans = end["spans"]
-    for name in PHASES + ("trainer/step", "eval/classification_error", "data/h2d"):
+    for name in PHASES + ("trainer/step", "data/h2d"):
         assert spans[name][0] == STEPS, (name, spans[name])
         assert spans[name][1] >= 0
-    assert spans["eval/readback"][0] == 2 * STEPS      # outputs, then labels
     assert spans["data/prefetch_wait"][0] >= STEPS
     # the pass's own span closes after its record is written
     assert "trainer/pass" not in spans
+    # one increment an evaluator a batch, and no `eval/*` span in a pass
+    # whose evaluators are all in the step
+    assert not [n for n in spans if n.startswith("eval/")]
+    assert end["counters"]["eval.device_batches"] == STEPS
+    assert end["counters"]["eval.host_batches"] == 0
+    (end,) = [r for r in traced_host[1] if r["kind"] == "pass_end"]
+    assert end["spans"]["eval/value_printer"][0] == STEPS
+    assert end["spans"]["eval/readback"][0] == STEPS
+    assert "eval/classification_error" not in end["spans"]
+    assert end["counters"]["eval.device_batches"] == STEPS
+    assert end["counters"]["eval.host_batches"] == STEPS
 
 
 def test_compile_record_names_the_kept_hlo_text(traced):
@@ -200,6 +246,8 @@ def test_step_hlo_holds_a_scope_for_every_layer_cost_and_optimizer():
             assert any(scope in n for n in op_names), scope
     assert any("/jvp(cost)/" in n for n in op_names)
     assert any(n.startswith("jit(step)/optimizer/") for n in op_names)
+    (ev,) = tc.model_config.evaluators        # its statistic is in the step
+    assert any(n.startswith(f"jit(step)/{ev.type}:{ev.name}/") for n in op_names)
     # a layer of the group's step nests under the group, backward included
     group = "recurrent_layer_group:decoder_group"
     assert any(f"jvp({group})/" in n and "gru_step:gru_decoder" in n
