@@ -60,6 +60,8 @@ class ConfigContext:
         self.config_args: Dict[str, str] = {}
         # memory links declared in the current recurrent group
         self._counters: Dict[str, int] = {}
+        # the remat_block() scope layers are being added under ("" = none)
+        self.remat_block: str = ""
 
     # ------------------------------------------------------------ layers
 
@@ -85,6 +87,8 @@ class ConfigContext:
         if cfg.name in self.layer_map:
             raise ValueError(f"duplicate layer name {cfg.name!r}")
         self.layer_map[cfg.name] = cfg
+        if self.remat_block and not cfg.remat_block:
+            cfg.remat_block = self.remat_block
         self.model.layers.append(cfg)
         if self.submodel_stack:
             self.submodel_stack[-1].layer_names.append(cfg.name)

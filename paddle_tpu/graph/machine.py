@@ -109,6 +109,7 @@ class GradientMachine:
         rng: Optional[Array] = None,
         table_overrides=None,
         gen_capture=None,
+        remat_blocks: bool = False,
     ) -> Tuple[Dict[str, Argument], Dict[str, Array]]:
         """Run the graph; returns (all layer outputs, state updates).
 
@@ -123,6 +124,7 @@ class GradientMachine:
             pallas_flat=self.pallas_flat,
             conv_s2d=self.conv_s2d, conv_stats_mode=self.conv_stats_mode,
             pallas_decoder=self.pallas_decoder, gen_capture=gen_capture,
+            remat_blocks=remat_blocks,
         )
         self.network.forward(ctx, in_args)
         return ctx.outputs, ctx.state_updates
@@ -195,8 +197,10 @@ class GradientMachine:
         in_args: Dict[str, Argument],
         rng: Optional[Array] = None,
         pass_type: str = "train",
+        remat_blocks: bool = False,
     ):
-        outputs, state_updates = self.forward(params, in_args, pass_type, rng)
+        outputs, state_updates = self.forward(
+            params, in_args, pass_type, rng, remat_blocks=remat_blocks)
         return self.total_cost(outputs), (outputs, state_updates)
 
     # --------------------------------------------------- sparse prefetch
@@ -252,11 +256,16 @@ class GradientMachine:
 
         ``remat="full"`` (OptimizationConfig.remat) wraps the loss in
         jax.checkpoint: backward recomputes the forward instead of
-        storing activations — the HBM-for-FLOPs trade."""
+        storing activations — the HBM-for-FLOPs trade. ``remat="block"``
+        checkpoints each run of layers the config tagged with one
+        ``remat_block`` (Network._forward_block): a block's activations
+        are recomputed from its saved input, one block at a time."""
         plan = self.sparse_prefetch_plan() if sparse else []
         loss_fn = self.loss_fn
         if remat == "full":
             loss_fn = jax.checkpoint(loss_fn)
+        elif remat == "block":
+            loss_fn = functools.partial(self.loss_fn, remat_blocks=True)
         elif remat not in ("", "none"):
             raise ValueError(f"unsupported remat mode {remat!r}")
 
@@ -293,7 +302,8 @@ class GradientMachine:
         def loss2(dense_params, rows):
             full = dict(dense_params, **frozen)
             outputs, state_updates = self.forward(
-                full, in_args, "train", rng, table_overrides=rows
+                full, in_args, "train", rng, table_overrides=rows,
+                remat_blocks=remat == "block",
             )
             return self.total_cost(outputs), (outputs, state_updates)
 

@@ -13,6 +13,7 @@ analog of RecurrentGradientMachine.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional
 
 import jax
@@ -54,8 +55,16 @@ class Network:
 
     def forward(self, ctx: LayerContext, in_args: Dict[str, Argument]) -> Dict[str, Argument]:
         """Run all layers; returns ctx.outputs (every layer's output)."""
-        for cfg in self.layers:
+        layers = self.layers
+        for i, cfg in enumerate(layers):
             if cfg.name in ctx.outputs:
+                continue
+            if ctx.remat_blocks and cfg.remat_block and cfg.type not in (
+                    "data", "recurrent_layer_group"):
+                j = i
+                while j < len(layers) and layers[j].remat_block == cfg.remat_block:
+                    j += 1
+                self._forward_block(ctx, layers[i:j])
                 continue
             if cfg.type == "data":
                 if cfg.name not in in_args:
@@ -71,11 +80,44 @@ class Network:
                 forward_layer(cfg, ins, ctx)
         return ctx.outputs
 
+    def _forward_block(self, ctx: LayerContext, block: List[LayerConfig]) -> None:
+        """One run of layers that share a `remat_block`, under one
+        jax.checkpoint: what the block reads of earlier layers (and the
+        parameters) are its saved inputs, every layer output and published
+        extra (`<layer>@<name>`) of it is a result, and backward recomputes
+        the inside. Side tables (`ctx.logits`, `ctx.nhwc`, ...) do not
+        cross the block's edge."""
+        inside = {c.name for c in block}
+        reads = {(ic.input_layer_name, ic.input_layer_argument)
+                 for c in block for ic in c.inputs
+                 if ic.input_layer_name and ic.input_layer_name not in inside}
+        read = {self._key(n, a): self._lookup_input(ctx, n, a) for n, a in sorted(reads)}
+
+        def run(params, read):
+            sub = dataclasses.replace(
+                ctx, params=params, outputs=dict(read),
+                state_updates={}, nhwc={}, logits={}, conv_stats={})
+            for c in block:
+                ins = [self._lookup_input(sub, ic.input_layer_name, ic.input_layer_argument)
+                       for ic in c.inputs]
+                forward_layer(c, ins, sub)
+            made = {k: v for k, v in sub.outputs.items() if k not in read}
+            return made, sub.state_updates
+
+        with jax.named_scope(f"remat_block:{block[0].remat_block}"):
+            made, updates = jax.checkpoint(run)(ctx.params, read)
+        ctx.outputs.update(made)
+        ctx.state_updates.update(updates)
+
+    @staticmethod
+    def _key(name: str, arg_name: str = "") -> str:
+        return f"{name}@{arg_name}" if arg_name else name
+
     def _lookup_input(self, ctx: LayerContext, name: str, arg_name: str = "") -> Argument:
         if not name:
             # parameter-only input slot (e.g. batch_norm moving stats)
             return Argument()
-        key = f"{name}@{arg_name}" if arg_name else name
+        key = self._key(name, arg_name)
         if key not in ctx.outputs:
             raise KeyError(
                 f"layer output {key!r} not available; computed: {sorted(ctx.outputs)}"

@@ -10,6 +10,17 @@ sequence_parallel) when the active mesh has a "seq" axis.
 
 Parameters: ``_<name>.wqkv`` [D, 3·H·Dh] fused projection, ``_<name>.wo``
 [H·Dh, D] output projection.
+
+With ``head_dim`` set the layer is the grouped-query form: ``num_heads``
+query heads over ``num_kv_heads`` key/value heads of ``head_dim`` each
+(so H·Dh need not be the model width), separate ``_<name>.wq`` [D, H·Dh],
+``.wk`` / ``.wv`` [D, Hkv·Dh] and ``.wo`` [H·Dh, size]; optionally an RMS
+norm over each head's q and k (``.q_norm`` / ``.k_norm`` [1, Dh]) and a
+rotary embedding (rotate-half, ``rope_theta``) at the positions the mask
+rule gives each index; and the mask is a rule over positions
+(``attention_mask``: full | causal | block_diffusion, `ops/attention_mask.py`)
+shared by the XLA path and the Pallas kernel. Device time splits into the
+scopes ``qkv``, ``core`` (scores, softmax, values) and ``out``.
 """
 
 from __future__ import annotations
@@ -21,6 +32,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.graph.argument import Argument
 from paddle_tpu.layers.base import LayerContext, register_layer, finalize_output, with_seq_meta
+from paddle_tpu.ops.precision import hp
 from paddle_tpu.proto import LayerConfig
 
 Array = jax.Array
@@ -38,6 +50,8 @@ def multi_head_attention(cfg: LayerConfig, inputs: List[Argument], ctx: LayerCon
     assert arg.is_seq and arg.value is not None, (
         f"{cfg.name}: multi_head_attention needs a dense sequence input"
     )
+    if cfg.head_dim:
+        return _grouped_query_attention(cfg, arg, ctx)
     x = arg.value                                   # [B, T, D]
     B, T, D = x.shape
     H = max(cfg.num_heads, 1)
@@ -64,5 +78,74 @@ def multi_head_attention(cfg: LayerConfig, inputs: List[Argument], ctx: LayerCon
     value = finalize_output(cfg, value, ctx, mask=arg.seq_mask())
     # zero padded positions so downstream pooling/costs see clean zeros
     # (mask cast keeps bf16 activations bf16)
+    value = value * arg.seq_mask(dtype=value.dtype)[..., None]
+    return with_seq_meta(arg, value)
+
+
+def rms_normalize(x: Array, gain: Array, eps: float) -> Array:
+    """x / sqrt(mean(x^2) + eps) * gain over the last axis: statistics in
+    float32, the result in x's dtype."""
+    return _rms(hp(x), gain, eps).astype(x.dtype)
+
+
+def _rms(xf: Array, gain: Array, eps: float) -> Array:
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return xf * inv * hp(gain)
+
+
+def _rotary(xf: Array, positions: Array, theta: float) -> Array:
+    """Rotary embedding of float32 [B, T, H, Dh] at integer ``positions``
+    [T], rotate-half convention: the two halves of a head are the pairs."""
+    half = xf.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]   # [T, half]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _head_prologue(x: Array, gain, eps: float, positions, theta: float, scale: float) -> Array:
+    """A projection's heads made ready for the scores, in float32 and
+    rounded ONCE: the per-head RMS norm (``gain`` None: none), the rotary
+    turn (``theta`` 0: none), then ``scale`` (the scores' 1/sqrt(Dh) folded
+    into q, so that the kernel multiplies no score)."""
+    xf = hp(x)
+    if gain is not None:
+        xf = _rms(xf, gain, eps)
+    if theta:
+        xf = _rotary(xf, positions, theta)
+    return (xf * scale if scale != 1.0 else xf).astype(x.dtype)
+
+
+def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext) -> Argument:
+    from paddle_tpu.ops.attention_mask import rule_of
+    from paddle_tpu.parallel.sequence_parallel import rule_attention
+
+    if ctx.mesh is not None and cfg.seq_parallel_mode:
+        raise NotImplementedError(
+            f"{cfg.name}: grouped-query attention with a mask rule does not "
+            "run sequence-parallel yet; drop seq_parallel or the mesh's seq axis")
+    x = arg.value                                   # [B, T, D]
+    B, T, _ = x.shape
+    H, Dh = cfg.num_heads, cfg.head_dim
+    Hkv = cfg.num_kv_heads or H
+    assert H % Hkv == 0, f"{cfg.name}: {H} query heads over {Hkv} key/value heads"
+    rule = rule_of(cfg.attention_mask, cfg.mask_block_length, cfg.causal_attention)
+    rule.check(T)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wq")).reshape(B, T, H, Dh)
+        k = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wk")).reshape(B, T, Hkv, Dh)
+        v = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wv")).reshape(B, T, Hkv, Dh)
+        gains = [ctx.param(f"_{cfg.name}.{n}", cast=False)[0] if cfg.qk_norm else None
+                 for n in ("q_norm", "k_norm")]
+        pos = rule.positions(jnp.arange(T, dtype=jnp.int32), T)
+        q = _head_prologue(q, gains[0], cfg.norm_epsilon, pos, cfg.rope_theta, Dh ** -0.5)
+        k = _head_prologue(k, gains[1], cfg.norm_epsilon, pos, cfg.rope_theta, 1.0)
+    with jax.named_scope("core"):
+        out = rule_attention(q, k, v, arg.seq_lengths, rule, scale=1.0)
+    with jax.named_scope("out"):
+        value = jnp.einsum("bte,ed->btd", out.reshape(B, T, H * Dh),
+                           ctx.param(f"_{cfg.name}.wo"))
+    value = finalize_output(cfg, value, ctx, mask=arg.seq_mask())
     value = value * arg.seq_mask(dtype=value.dtype)[..., None]
     return with_seq_meta(arg, value)

@@ -103,6 +103,9 @@ class LayerContext:
     # OptimizationConfig.pallas_decoder: attention-GRU decoder groups
     # run as one fused Pallas launch (graph/fused_decoder.py)
     pallas_decoder: bool = False
+    # OptimizationConfig.remat="block": runs of layers that share a
+    # LayerConfig.remat_block go under one jax.checkpoint (graph/network.py)
+    remat_blocks: bool = False
     # recurrent-group prologue hoisting (graph/recurrent_group.py
     # _plan_prologue): mixed layer name -> (skip_input_indices,
     # precomputed [B, out] slice) for scan-input projections computed
@@ -255,6 +258,60 @@ def forward_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) -
         ctx.conv_stats.pop(cfg.name, None)
     ctx.outputs[cfg.name] = out
     return out
+
+
+# ------------------------------------------------------- layer counters
+# A layer that has a number worth counting a step (pairs computed, rows
+# dropped) publishes it with `publish_counter`: an extra output of the layer,
+# so that it crosses a recomputation block like any other. The train step
+# folds every layer's into one small dict (`step_counters`, in the trace),
+# the host reads it back with the loss and `note_counters` puts it into the
+# registry that `pass_end` snapshots. The trainer names no layer type.
+
+COUNTER_EXTRA = "counter"
+# the key a train step's evaluator states carry `step_counters` under ("@"
+# separates a layer's name from its extra's: no evaluator's name holds it)
+LAYER_COUNTERS = "@layer_counters"
+
+
+def publish_counter(cfg: LayerConfig, ctx: "LayerContext", name: str, value,
+                    how: str = "sum") -> None:
+    """This layer's part of the counter `name` for this step. `how`: "sum"
+    adds the layers' parts and counts up over the steps (a registry
+    counter); "max" takes the largest layer's and holds the last step's (a
+    gauge)."""
+    if how not in ("sum", "max"):
+        raise ValueError(f"{cfg.name}: counter {name!r}: how={how!r}")
+    ctx.outputs[f"{cfg.name}@{COUNTER_EXTRA}.{how}:{name}"] = Argument(
+        value=jnp.asarray(value, jnp.float32))
+
+
+def step_counters(outputs: Dict[str, Argument]) -> Dict[str, Dict[str, Array]]:
+    """{"sum" | "max": {counter name: f32[]}} over every layer that
+    published one in this step; {} where none did."""
+    parts: Dict[tuple, list] = {}
+    for key, arg in outputs.items():
+        extra = key.partition("@")[2]
+        if extra.startswith(COUNTER_EXTRA + "."):
+            how, _, name = extra[len(COUNTER_EXTRA) + 1:].partition(":")
+            parts.setdefault((how, name), []).append(arg.value)
+    out: Dict[str, Dict[str, Array]] = {}
+    for (how, name), values in parts.items():
+        fold = jnp.sum if how == "sum" else jnp.max
+        out.setdefault(how, {})[name] = fold(jnp.stack(values))
+    return out
+
+
+def note_counters(values) -> None:
+    """The host's half: one step's `step_counters`, read back."""
+    if not values:
+        return
+    from paddle_tpu.observability import metrics as obs
+
+    for name, v in values.get("sum", {}).items():
+        obs.registry().counter(name).inc(float(v))
+    for name, v in values.get("max", {}).items():
+        obs.registry().gauge(name).set(float(v))
 
 
 def first_seq_meta(inputs: List[Argument]) -> Argument:

@@ -6,8 +6,13 @@ config_parser's define_cost type strings (config_parser.py:1700-1708).
 
 Each cost layer outputs per-sample cost [B, 1] (sequences: summed over
 valid timesteps — the padded-batch equivalent of the reference's ragged
-per-row costs), already scaled by ``coeff`` and the optional per-sample
-weight. The gradient machine averages over the batch to form the scalar
+per-row costs), already scaled by ``coeff`` and the optional weight. The
+weight is per SAMPLE where it is a [B, 1] value (the reference's
+`Argument::weight`: the sample's summed cost is multiplied), and per
+POSITION where it is a sequence shaped like the per-step cost ([B, T, 1]
+over a [B, T] cost): each step's cost is multiplied before the sum over
+time (a diffusion loss weighs each masked position by 1/t and every other
+by 0). The gradient machine averages over the batch to form the scalar
 loss that jax.grad differentiates.
 """
 
@@ -33,14 +38,19 @@ _USE_FUSED_CE = True
 
 def _finish_cost(cfg: LayerConfig, per_step: Array, arg: Argument, weight_arg: Optional[Argument]) -> Argument:
     """Reduce per-step cost over time (masked) and apply coeff/weight."""
+    w = weight_arg.value if weight_arg is not None else None
+    if w is not None and w.size == per_step.size and per_step.ndim > 1:
+        # a weight a position: applied before the reduction over time
+        per_step = per_step * _hp(w).reshape(per_step.shape)
+        w = None
     if arg.is_nested_seq:
         cost = jnp.sum(per_step * arg.sub_seq_mask(), axis=(1, 2))
     elif arg.is_seq:
         cost = jnp.sum(per_step * arg.seq_mask(), axis=1)
     else:
         cost = per_step
-    if weight_arg is not None and weight_arg.value is not None:
-        cost = cost * weight_arg.value.reshape(cost.shape)
+    if w is not None:
+        cost = cost * w.reshape(cost.shape)
     return Argument(value=(cfg.coeff * cost)[:, None])
 
 

@@ -237,3 +237,16 @@ def nce_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) -> Ar
     if weight is not None and weight.value is not None:
         cost = cost * weight.value.reshape(cost.shape)
     return Argument(value=cost[:, None])
+
+
+@register_layer("rms_norm")
+def rms_norm_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) -> Argument:
+    # TPU extension: x / sqrt(mean(x^2) + norm_epsilon) * g over the feature
+    # axis, one gain vector `_<name>.w0` [1, D] (kept float32); statistics
+    # in float32 whatever the compute dtype
+    from paddle_tpu.layers.attention import rms_normalize
+
+    arg = inputs[0]
+    gain = ctx.param(cfg.inputs[0].input_parameter_name, cast=False)[0]
+    value = rms_normalize(arg.value, gain, cfg.norm_epsilon)
+    return with_seq_meta(arg, finalize_output(cfg, value, ctx, mask=input_mask(arg)))

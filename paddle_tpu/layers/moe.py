@@ -1,0 +1,83 @@
+"""Sparse-expert (mixture of experts) feed-forward layer.
+
+A TPU extension beyond the 2016 reference. Every token is routed to
+``experts_per_token`` of ``experts`` SwiGLU experts:
+
+    r   = softmax(x Wr) over all the experts, in float32
+    S   = the experts_per_token largest of r
+    w_e = r_e / sum_{e' in S} r_e'        (norm_topk_prob; else w_e = r_e)
+    y   = sum_{e in S, held here} w_e * (silu(x Wg_e) * (x Wu_e)) Wd_e
+
+The program HOLDS a contiguous range of the experts (`experts_held_first`,
+`experts_held_count`; all of them by default): it routes over all of them,
+renormalises over all the chosen, and computes its own experts' part; the
+sum of every holder's output is the whole layer's. On one chip there is
+no exchange. No capacity and no dropped pair (`ops/grouped_matmul.py`).
+
+Parameters: ``_<name>.router`` [D, experts] (kept float32), and the held
+experts stacked: ``_<name>.gate`` / ``.up`` [held, D, width], ``.down``
+[held, width, D]. Device time splits into the scopes ``router``,
+``dispatch`` (sort, gather), ``experts`` (the grouped products) and
+``combine`` (scatter-add). The layer counts (`base.publish_counter`)
+``moe.pairs_held``, the pairs it computed, and ``moe.load_max_over_mean``,
+its fullest held expert's pairs over the mean, and publishes the extra
+output ``<name>@chosen``, int32 [..., experts_per_token]: the experts it
+chose for each token, for a config that wants them
+(``get_output_layer(moe, "chosen")``); unread, it costs nothing.
+"""
+
+from __future__ import annotations
+
+from typing import List
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.graph.argument import Argument
+from paddle_tpu.layers.base import (
+    LayerContext,
+    finalize_output,
+    publish_counter,
+    register_layer,
+    with_seq_meta,
+)
+from paddle_tpu.ops.grouped_matmul import expert_ffn, route
+from paddle_tpu.proto import LayerConfig
+
+
+@register_layer("moe")
+def moe_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) -> Argument:
+    if ctx.mesh is not None:
+        raise NotImplementedError(
+            f"{cfg.name}: the sparse-expert layer does not run under a mesh yet "
+            "(experts across chips need the all-to-all exchange): train it on "
+            "one chip, each holding its experts_held range")
+    arg = inputs[0]
+    x = arg.value
+    lead, D = x.shape[:-1], x.shape[-1]
+    xf = x.reshape(-1, D)
+    first = cfg.experts_held_first
+    count = cfg.experts_held_count or cfg.experts - first
+    k = cfg.experts_per_token
+    with jax.named_scope("router"):
+        logits = jnp.dot(xf.astype(jnp.float32),
+                         ctx.param(f"_{cfg.name}.router", cast=False).astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top, chosen = jax.lax.top_k(probs, k)
+        weight = top / jnp.sum(top, axis=-1, keepdims=True) if cfg.norm_topk_prob else top
+    with jax.named_scope("dispatch"):
+        routing = route(chosen.astype(jnp.int32), first, count)
+    y = expert_ffn(xf, weight, ctx.param(f"_{cfg.name}.gate"),
+                   ctx.param(f"_{cfg.name}.up"), ctx.param(f"_{cfg.name}.down"),
+                   routing)
+    sizes = routing.group_sizes.astype(jnp.float32)
+    publish_counter(cfg, ctx, "moe.pairs_held", jnp.sum(sizes))
+    publish_counter(cfg, ctx, "moe.load_max_over_mean",
+                    jnp.max(sizes) * count / jnp.maximum(jnp.sum(sizes), 1.0), how="max")
+    ctx.outputs[f"{cfg.name}@chosen"] = with_seq_meta(
+        arg, chosen.astype(jnp.int32).reshape(*lead, k))
+    value = finalize_output(cfg, y.reshape(*lead, D), ctx)
+    if arg.is_seq:
+        value = value * arg.seq_mask(dtype=value.dtype)[..., None]
+    return with_seq_meta(arg, value)

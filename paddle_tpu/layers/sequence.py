@@ -221,3 +221,22 @@ def sub_seq_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) -
     valid = pos < s[:, None]
     out = jnp.where(valid[..., None], out, 0.0)
     return Argument(value=finalize_output(cfg, out, ctx), seq_lengths=s)
+
+
+@register_layer("seq_slice")
+def seq_slice_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) -> Argument:
+    # TPU extension: the (padded) time axis cut into `seq_parts` equal parts,
+    # part `seq_part` kept: [B, T, D] -> [B, T / parts, D]. A sequence's
+    # length becomes what of it lies inside the part. (Block-diffusion
+    # training feeds the noised and the clean copy as one 2L-long sequence
+    # and takes its loss over the first L.)
+    arg = inputs[0]
+    ref = arg.value if arg.value is not None else arg.ids
+    assert arg.is_seq and not arg.is_nested_seq, f"{cfg.name}: seq_slice needs a plain sequence"
+    T = ref.shape[1]
+    assert T % cfg.seq_parts == 0, f"{cfg.name}: {T} positions do not cut into {cfg.seq_parts} parts"
+    n = T // cfg.seq_parts
+    lo = cfg.seq_part * n
+    cut = lambda x: None if x is None else x[:, lo:lo + n]
+    return Argument(value=cut(arg.value), ids=cut(arg.ids),
+                    seq_lengths=jnp.clip(arg.seq_lengths - lo, 0, n))

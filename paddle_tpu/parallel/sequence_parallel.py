@@ -104,6 +104,49 @@ def full_attention(
     return out.astype(q.dtype)
 
 
+def rule_attention(q: Array, k: Array, v: Array, lengths: Optional[Array], rule,
+                   scale: Optional[float] = None) -> Array:
+    """Single-device attention of [B, T, H, D] queries over [B, T, Hkv, D]
+    keys and values (query head h reads K/V head ``h // (H / Hkv)``) under
+    a mask RULE over positions (`ops/attention_mask.MaskRule`).
+
+    Where a Pallas kernel can run (a TPU backend, or interpret mode off
+    it) and its gate admits the shape, the flash kernel of
+    `ops/pallas_attention` does it, walking only the tiles the rule
+    leaves; else the XLA path below, which materializes [B, H, T, T]
+    scores and is for small T only. Padded query rows are unspecified, as
+    in :func:`full_attention`. ``scale`` multiplies the scores (1/sqrt(D)
+    by default; 1 where the caller folded it into q)."""
+    from paddle_tpu.ops import pallas_attention
+    from paddle_tpu.utils import device
+
+    B, T, H, D = q.shape
+    Hkv = k.shape[2]
+    site = f"T={T} D={D} {rule.kind}"
+    mode = device.pallas_mode()
+    if mode is None:
+        why = device.why_no_pallas()
+    elif not pallas_attention.supported(T, D, q.dtype.itemsize):
+        why = "kernel gate refuses the shape"
+    else:
+        device.log_selection("rule_attention", site, f"Pallas kernel, {mode}")
+        return pallas_attention.flash_attention(
+            q, k, v, lengths=lengths, rule=rule, interpret=mode == "interpret",
+            scale=scale)
+    device.log_selection("rule_attention", site, f"XLA path ({why})")
+    acc_t = jnp.promote_types(q.dtype, jnp.float32)
+    qg = q.reshape(B, T, Hkv, H // Hkv, D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=acc_t)
+    s = s * (1.0 / math.sqrt(D) if scale is None else scale)
+    idx = jnp.arange(T)
+    mask = jnp.broadcast_to(rule.allowed(idx, idx, T), (T, T))[None, None, None]
+    if lengths is not None:
+        mask = mask & (idx[None, None, None, None, :] < lengths[:, None, None, None, None])
+    p = jax.nn.softmax(jnp.where(mask, s, _NEG), axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v, preferred_element_type=acc_t)
+    return out.reshape(B, T, H, D).astype(q.dtype)
+
+
 def _ring_attention_local(q, k, v, lengths, causal, axis_name):
     """Per-shard body: stream the K/V ring through an online-softmax
     accumulator. q/k/v: [B, T_loc, H, D] (this shard's block)."""

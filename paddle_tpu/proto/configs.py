@@ -225,6 +225,36 @@ class LayerConfig(Message):
     num_heads: int = 0
     causal_attention: bool = False
     seq_parallel_mode: str = ""   # "" | ring | alltoall
+    # grouped-query form of multi_head_attention (head_dim > 0 selects it):
+    # num_heads query heads over num_kv_heads K/V heads of head_dim,
+    # per-head q/k RMS norm, rotary positions, the mask as a rule
+    # (ops/attention_mask.py: "" = causal_attention's flag | full | causal
+    # | block_diffusion with mask_block_length)
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    qk_norm: bool = False
+    rope_theta: float = 0.0
+    attention_mask: str = ""
+    mask_block_length: int = 0
+    # rms_norm (and attention's q/k norm): x / sqrt(mean(x^2) + epsilon)
+    norm_epsilon: float = 1e-6
+    # sparse-expert layer (layers/moe.py): `experts` router outputs,
+    # `experts_per_token` chosen, SwiGLU experts `expert_width` wide, of
+    # which this program holds `experts_held_count` from
+    # `experts_held_first` on (0 = all); norm_topk_prob renormalises the
+    # chosen probabilities over all the chosen, held or not
+    experts: int = 0
+    experts_per_token: int = 0
+    expert_width: int = 0
+    experts_held_first: int = 0
+    experts_held_count: int = 0
+    norm_topk_prob: bool = True
+    # seq_slice: the time axis cut into seq_parts equal parts, part seq_part kept
+    seq_parts: int = 1
+    seq_part: int = 0
+    # OptimizationConfig.remat="block": consecutive layers that share a
+    # non-empty remat_block run under one jax.checkpoint (graph/network.py)
+    remat_block: str = ""
 
 
 @dataclass
@@ -375,7 +405,10 @@ class OptimizationConfig(Message):
     # "full" wraps the loss in jax.checkpoint so backward recomputes the
     # forward — trades ~33% more FLOPs for O(1) activation memory, the
     # HBM lever for big models/long sequences (SURVEY.md: jax.checkpoint)
-    remat: str = "none"          # none|full
+    # "block" checkpoints each run of layers the config tagged with one
+    # remat_block name (a transformer block): backward recomputes a block
+    # from its saved input, so activations cost one block's worth
+    remat: str = "none"          # none|full|block
     # lax.scan unroll factor for recurrent layers / recurrent groups:
     # unrolling k steps per scan iteration lets XLA pipeline the per-step
     # MXU matmuls and amortize loop overhead, at k× program size. 1 = off.

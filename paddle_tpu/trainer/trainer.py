@@ -38,6 +38,7 @@ from paddle_tpu.data.feeder import DataProvider, create_data_provider
 from paddle_tpu.graph.argument import Argument
 from paddle_tpu.resilience import NonFiniteLossError, faultinject
 from paddle_tpu.graph.machine import GradientMachine
+from paddle_tpu.layers.base import LAYER_COUNTERS, note_counters, step_counters
 from paddle_tpu.optimizer import Updater
 from paddle_tpu.proto import TrainerConfig
 from paddle_tpu.trainer import checkpoint as ckpt
@@ -350,6 +351,11 @@ class Trainer:
         )
         self._numerics_groups = None
         self._numerics_last = None  # newest launch's device health tree
+        # the last train batch's kept layers, {layer name: Argument}, as the
+        # step returned them (the network's declared outputs and what host
+        # evaluators read): the reference API's Trainer::getForwardOutput.
+        # Device arrays; nothing here reads them
+        self.forward_output: Dict[str, Argument] = {}
         if self._numerics_period:
             if (self._accum_n > 1 or self._async
                     or self._batch_method is not None):
@@ -654,6 +660,14 @@ class Trainer:
             states = chain.batch_states(outputs)
             host = [e for e in chain.evaluators if e.cfg.name not in states]
             kept = net_outputs | set(chain.layers_for(host))
+            # what the layers counted this step (`base.publish_counter`)
+            # rides with the evaluator states: one read in
+            # `trainer/loss_sync`, then `note_counters` on the host
+            counters = step_counters(outputs)
+            if counters:
+                if LAYER_COUNTERS in states:
+                    raise ValueError(f"an evaluator may not be named {LAYER_COUNTERS!r}")
+                states[LAYER_COUNTERS] = counters
             return {k: v for k, v in outputs.items() if k in kept}, states
 
         return select
@@ -1548,6 +1562,7 @@ class Trainer:
                     )
                 batch_id_start = batch_id
                 for loss_f, outputs, states, n in results:
+                    self.forward_output = outputs
                     step_times.append(step_dt)
                     if not np.isfinite(loss_f):
                         # FP trap role (ref: feenableexcept(FE_INVALID|FE_DIVBYZERO|
@@ -2183,6 +2198,7 @@ class Trainer:
         The local/gather split is decided ONCE per chain from global
         sharding metadata so every process runs the same collectives.
         ``gathered``: outputs are already full host values."""
+        note_counters((states or {}).get(LAYER_COUNTERS))
         if not evaluators:
             return
         host_evs = evaluators.add_states(states or {})

@@ -10,6 +10,7 @@ compiles the resulting ModelConfig (paddle_tpu.graph).
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Callable, List, Optional, Sequence, Union
 
@@ -113,6 +114,10 @@ __all__ = [
     "out_prod_layer",
     "multiplex_layer",
     "multi_head_attention_layer",
+    "rms_norm_layer",
+    "moe_layer",
+    "seq_slice_layer",
+    "remat_block",
     "mdlstm_layer",
     "sub_network",
 ]
@@ -1463,9 +1468,11 @@ def classification_cost(
     cost: str = "multi-class-cross-entropy",
     evaluator=None,
     coeff: float = 1.0,
+    weight: Optional[LayerOutput] = None,
 ) -> LayerOutput:
     name = _name(name, "cost")
-    out = _cost_layer(cost, name, [input, label], coeff=coeff)
+    inputs = [input, label] + ([weight] if weight is not None else [])
+    out = _cost_layer(cost, name, inputs, coeff=coeff)
     # default classification-error evaluator (reference behavior)
     from paddle_tpu.trainer_config_helpers.evaluators import classification_error_evaluator
 
@@ -1506,8 +1513,12 @@ def pnpair_validation(input, label, info, weight=None, name=None, coeff=1.0):
     return out
 
 
-def cross_entropy(input, label, name=None, coeff=1.0):
-    return _cost_layer("multi-class-cross-entropy", _name(name, "cost"), [input, label], coeff)
+def cross_entropy(input, label, name=None, coeff=1.0, weight=None):
+    """``weight``: a [B, 1] layer weighs each sample's cost; a sequence
+    shaped like ``label`` ([B, T, 1]) weighs each position's before the
+    sum over time (layers/cost.py)."""
+    inputs = [input, label] + ([weight] if weight is not None else [])
+    return _cost_layer("multi-class-cross-entropy", _name(name, "cost"), inputs, coeff)
 
 
 def cross_entropy_with_selfnorm(input, label, name=None, coeff=1.0, softmax_selfnorm_alpha=0.1):
@@ -1779,12 +1790,30 @@ def multi_head_attention_layer(
     param_attr: Optional[ParameterAttribute] = None,
     bias_attr: Union[bool, ParameterAttribute] = False,
     layer_attr=None,
+    num_kv_heads: Optional[int] = None,
+    head_dim: Optional[int] = None,
+    qk_norm: bool = False,
+    rope_theta: float = 0.0,
+    attention_mask: str = "",
+    block_length: int = 0,
+    norm_epsilon: float = 1e-6,
 ) -> LayerOutput:
     """Transformer-style multi-head self-attention over a sequence (TPU
     extension; the reference's only attention is simple_attention inside
     recurrent groups). ``seq_parallel``: "" | "ring" | "alltoall" — shard
     the context over the mesh "seq" axis (paddle_tpu.parallel.
-    sequence_parallel)."""
+    sequence_parallel).
+
+    With ``head_dim`` the grouped-query form: ``num_heads`` query heads
+    over ``num_kv_heads`` key/value heads (default: as many) of
+    ``head_dim`` each, separate parameters ``_<name>.wq`` [in, H*Dh],
+    ``.wk`` / ``.wv`` [in, Hkv*Dh], ``.wo`` [H*Dh, size]; ``qk_norm``: an
+    RMS norm over each head's q and k (``.q_norm`` / ``.k_norm`` [1, Dh],
+    ``norm_epsilon``); ``rope_theta`` > 0: rotary positions, rotate-half;
+    ``attention_mask``: "full" | "causal" | "block_diffusion" (with
+    ``block_length``; the input then holds the noised and the clean copy
+    of an L-long sequence as one 2L-long one) — a rule over positions,
+    `paddle_tpu/ops/attention_mask.py`."""
     assert seq_parallel in ("", "ring", "alltoall"), (
         f"seq_parallel must be '', 'ring' or 'alltoall', got {seq_parallel!r}"
     )
@@ -1799,14 +1828,131 @@ def multi_head_attention_layer(
     cfg.num_heads = num_heads
     cfg.causal_attention = causal
     cfg.seq_parallel_mode = seq_parallel
-    wqkv = _create_parameter(
-        f"_{name}.wqkv", input.size * 3 * size, [input.size, 3 * size], param_attr
-    )
-    _create_parameter(f"_{name}.wo", size * size, [size, size], param_attr)
-    cfg.inputs.append(_input(input, wqkv))
+    if head_dim:
+        from paddle_tpu.ops.attention_mask import rule_of
+
+        rule_of(attention_mask, block_length, causal)      # refuses a bad rule here
+        kv = num_kv_heads or num_heads
+        cfg.num_kv_heads, cfg.head_dim, cfg.qk_norm = kv, head_dim, qk_norm
+        cfg.rope_theta, cfg.norm_epsilon = float(rope_theta), float(norm_epsilon)
+        cfg.attention_mask, cfg.mask_block_length = attention_mask, block_length
+        wq = _create_parameter(f"_{name}.wq", input.size * num_heads * head_dim,
+                               [input.size, num_heads * head_dim], param_attr)
+        for leaf in ("wk", "wv"):
+            _create_parameter(f"_{name}.{leaf}", input.size * kv * head_dim,
+                              [input.size, kv * head_dim], param_attr)
+        _create_parameter(f"_{name}.wo", num_heads * head_dim * size,
+                          [num_heads * head_dim, size], param_attr)
+        if qk_norm:
+            for leaf in ("q_norm", "k_norm"):
+                _create_parameter(f"_{name}.{leaf}", head_dim, [1, head_dim], _ones_attr())
+        cfg.inputs.append(_input(input, wq))
+    else:
+        assert not (num_kv_heads or qk_norm or rope_theta or attention_mask), (
+            "grouped-query heads, q/k norm, rotary positions and mask rules "
+            "need head_dim")
+        wqkv = _create_parameter(
+            f"_{name}.wqkv", input.size * 3 * size, [input.size, 3 * size], param_attr
+        )
+        _create_parameter(f"_{name}.wo", size * size, [size, size], param_attr)
+        cfg.inputs.append(_input(input, wqkv))
     cfg.bias_parameter_name = _bias_name(name, size, bias_attr)
     _add_layer(cfg, layer_attr)
     return LayerOutput(name, "multi_head_attention", [input], size, act)
+
+
+def _ones_attr() -> ParameterAttribute:
+    """A gain vector starts at 1."""
+    return ParameterAttribute(initial_mean=1.0, initial_std=0.0)
+
+
+def rms_norm_layer(
+    input: LayerOutput,
+    epsilon: float = 1e-6,
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr=None,
+) -> LayerOutput:
+    """RMS norm over the feature axis (TPU extension):
+    ``x / sqrt(mean(x^2) + epsilon) * g``, one gain vector ``_<name>.w0``
+    [1, size] starting at 1; statistics in float32."""
+    name = _name(name, "rms_norm")
+    cfg = LayerConfig(name=name, type="rms_norm", size=input.size)
+    cfg.norm_epsilon = float(epsilon)
+    w = _create_parameter(f"_{name}.w0", input.size, [1, input.size],
+                          param_attr or _ones_attr())
+    cfg.inputs.append(_input(input, w))
+    _add_layer(cfg, layer_attr)
+    return LayerOutput(name, "rms_norm", [input], input.size)
+
+
+def moe_layer(
+    input: LayerOutput,
+    experts: int,
+    experts_per_token: int,
+    expert_width: int,
+    experts_held: Optional[Sequence[int]] = None,
+    norm_topk_prob: bool = True,
+    name: Optional[str] = None,
+    param_attr: Optional[ParameterAttribute] = None,
+    layer_attr=None,
+) -> LayerOutput:
+    """Sparse-expert feed-forward (TPU extension, `paddle_tpu/layers/
+    moe.py`): a float32 softmax router over ``experts``, the
+    ``experts_per_token`` largest chosen (renormalised over the chosen
+    with ``norm_topk_prob``), SwiGLU experts ``expert_width`` wide.
+    ``experts_held``: ``(first, count)``, the experts this program holds
+    and computes (all by default); the router always has ``experts``
+    outputs. Parameters: ``_<name>.router`` [size, experts], ``.gate`` /
+    ``.up`` [count, size, width], ``.down`` [count, width, size]."""
+    first, count = experts_held if experts_held is not None else (0, experts)
+    assert 0 <= first and count >= 1 and first + count <= experts, (
+        f"experts_held {(first, count)} is no range of {experts} experts")
+    assert 1 <= experts_per_token <= experts
+    name = _name(name, "moe")
+    d = input.size
+    cfg = LayerConfig(name=name, type="moe", size=d)
+    cfg.experts, cfg.experts_per_token, cfg.expert_width = experts, experts_per_token, expert_width
+    cfg.experts_held_first, cfg.experts_held_count = first, count
+    cfg.norm_topk_prob = bool(norm_topk_prob)
+    router = _create_parameter(f"_{name}.router", d * experts, [d, experts], param_attr)
+    for leaf, dims in (("gate", [count, d, expert_width]), ("up", [count, d, expert_width]),
+                       ("down", [count, expert_width, d])):
+        pname = _create_parameter(f"_{name}.{leaf}", count * d * expert_width, dims, param_attr)
+        pc = _ctx().param_map[pname]
+        if pc.initial_smart:
+            pc.initial_std = 1.0 / math.sqrt(dims[1])      # fan-in of ONE expert
+    cfg.inputs.append(_input(input, router))
+    _add_layer(cfg, layer_attr)
+    return LayerOutput(name, "moe", [input], d)
+
+
+def seq_slice_layer(input: LayerOutput, parts: int, part: int = 0,
+                    name: Optional[str] = None, layer_attr=None) -> LayerOutput:
+    """One of ``parts`` equal parts of every sequence's (padded) time
+    axis: [B, T, D] -> [B, T / parts, D], part ``part`` (0 = the first)."""
+    assert parts >= 1 and 0 <= part < parts
+    name = _name(name, "seq_slice")
+    cfg = LayerConfig(name=name, type="seq_slice", size=input.size)
+    cfg.seq_parts, cfg.seq_part = parts, part
+    cfg.inputs.append(_input(input))
+    _add_layer(cfg, layer_attr)
+    return LayerOutput(name, "seq_slice", [input], input.size)
+
+
+@contextlib.contextmanager
+def remat_block(name: str):
+    """Layers made inside belong to one recomputation block: with
+    ``settings(remat="block")`` the block runs under one jax.checkpoint,
+    so backward recomputes it from its saved inputs (a transformer
+    block's activations are then never all alive at once). Ignored under
+    any other ``remat``."""
+    ctx = _ctx()
+    prev, ctx.remat_block = ctx.remat_block, name
+    try:
+        yield
+    finally:
+        ctx.remat_block = prev
 
 
 def mdlstm_layer(

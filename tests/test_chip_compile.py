@@ -106,6 +106,35 @@ def _flash(T, D, dtype, B=2, H=4):
     return fn, shapes, pa.supported(T, D)
 
 
+def _rule_attention(kind, T=8192, H=32, Hkv=4, D=128, B=1):
+    """The flash kernel of `ops/pallas_attention.py` under a mask rule at
+    the block-diffusion cell's shapes (perfbench `sdar.train`: 32 query / 4
+    key-value heads of 128, 8192 positions), as `rule_attention` runs it
+    inside the train step: forward and both backward kernels."""
+    from paddle_tpu.ops import pallas_attention as pa
+    from paddle_tpu.ops.attention_mask import MaskRule
+
+    rule = MaskRule(kind, 4 if kind == "block_diffusion" else 0)
+    shapes = [((B, T, H, D), BF16), ((B, T, Hkv, D), BF16), ((B, T, Hkv, D), BF16)]
+    fn = lambda q, k, v: pa.flash_attention(q, k, v, rule=rule)
+    return fn, shapes, pa.supported(T, D, 2)
+
+
+def _expert_ffn(N=4096, D=2048, F=768, held=16, k=8):
+    """The sparse-expert layer's dispatch and grouped products
+    (`ops/grouped_matmul.py`) at the cell's shapes: chunks of 8,192 rows of
+    2048, 16 held experts of width 768, 8 choices a token (4,096 positions
+    here, 32,768 in the cell: the loops' trip counts are data and the
+    kernels' shapes a chunk's, so the fewer positions only compile faster)."""
+    from paddle_tpu.ops.grouped_matmul import expert_ffn, route
+
+    shapes = [((N, D), BF16), ((N, k), F32), ((N, k), jnp.int32),
+              ((held, D, F), BF16), ((held, D, F), BF16), ((held, F, D), BF16)]
+    fn = lambda x, w, chosen, wg, wu, wd: expert_ffn(
+        x, w, wg, wu, wd, route(chosen, 0, held))
+    return fn, shapes, True
+
+
 def _conv1x1(M, K, N, dtype):
     from paddle_tpu.ops import pallas_conv1x1_bn as pcb
 
@@ -132,6 +161,10 @@ CASES = {
     # demo/long_context (seq_len=2048; dim 64 over 4 heads, and a 64-wide head)
     "flash-t2048-d16": lambda: _flash(2048, 16, F32),
     "flash-t2048-d64": lambda: _flash(2048, 64, BF16),
+    # perfbench sdar.train: block-diffusion attention and the held experts
+    "rule-attention-block-diffusion-t8192": lambda: _rule_attention("block_diffusion"),
+    "rule-attention-causal-t8192": lambda: _rule_attention("causal"),
+    "expert-ffn-16x768": _expert_ffn,
     # a ResNet-50 1x1 at B=256: stage-1 expand, 56x56 pixels, 64 -> 256
     "conv1x1-bf16": lambda: _conv1x1(256 * 56 * 56, 64, 256, BF16),
 }
