@@ -254,12 +254,15 @@ class GradientMachine:
         gradients must be accumulated across batches — RowSparseGrad
         shapes vary per batch).
 
-        ``remat="full"`` (OptimizationConfig.remat) wraps the loss in
-        jax.checkpoint: backward recomputes the forward instead of
-        storing activations — the HBM-for-FLOPs trade. ``remat="block"``
-        checkpoints each run of layers the config tagged with one
-        ``remat_block`` (Network._forward_block): a block's activations
-        are recomputed from its saved input, one block at a time."""
+        ``remat="full"`` (OptimizationConfig.remat) wraps the loss in a
+        bare jax.checkpoint: backward recomputes the whole forward instead
+        of storing activations — the HBM-for-FLOPs trade, for least
+        memory; it keeps nothing, a kernel's named residuals neither (no
+        cell or demo config runs it with the rule attention).
+        ``remat="block"`` checkpoints each run of layers the config tagged
+        with one ``remat_block`` (Network._forward_block): a block's
+        activations are recomputed from its saved input, one block at a
+        time, all but the residuals its kernels named."""
         plan = self.sparse_prefetch_plan() if sparse else []
         loss_fn = self.loss_fn
         if remat == "full":

@@ -23,6 +23,22 @@ from paddle_tpu.layers.base import LayerContext, forward_layer
 from paddle_tpu.proto import LayerConfig, ModelConfig, SubModelConfig
 
 
+def recompute_block(run):
+    """``run`` under one jax.checkpoint whose backward recomputes all of it
+    but the residuals an op module NAMED (`jax.ad_checkpoint.
+    checkpoint_name`; the modules are imported here, when a block is
+    traced: they pull in Pallas). A name is admitted where the residual is
+    one that only a kernel's forward can remake, and is no larger than one
+    of the kernel's own inputs: dear to redo, small to hold. Today the
+    flash kernel's ``out`` and ``lse``; a block with no such kernel keeps
+    nothing."""
+    from paddle_tpu.ops import pallas_attention
+
+    names = pallas_attention.KEPT_RESIDUALS
+    return jax.checkpoint(
+        run, policy=jax.checkpoint_policies.save_only_these_names(*names))
+
+
 class Network:
     """Executable view of (a sub-model of) a ModelConfig."""
 
@@ -85,8 +101,9 @@ class Network:
         jax.checkpoint: what the block reads of earlier layers (and the
         parameters) are its saved inputs, every layer output and published
         extra (`<layer>@<name>`) of it is a result, and backward recomputes
-        the inside. Side tables (`ctx.logits`, `ctx.nhwc`, ...) do not
-        cross the block's edge."""
+        the inside, all but the residuals an op module named
+        (`recompute_block`). Side tables (`ctx.logits`, `ctx.nhwc`, ...) do
+        not cross the block's edge."""
         inside = {c.name for c in block}
         reads = {(ic.input_layer_name, ic.input_layer_argument)
                  for c in block for ic in c.inputs
@@ -105,7 +122,7 @@ class Network:
             return made, sub.state_updates
 
         with jax.named_scope(f"remat_block:{block[0].remat_block}"):
-            made, updates = jax.checkpoint(run)(ctx.params, read)
+            made, updates = recompute_block(run)(ctx.params, read)
         ctx.outputs.update(made)
         ctx.state_updates.update(updates)
 

@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -344,8 +345,17 @@ def _flash(q, k, v, lengths, rule, blocks, interpret):  # blocks: (bq, bk, scale
     return out
 
 
+# What the backward needs and only the forward KERNEL can remake: named, so
+# that a recomputation block (`graph/network.py::_forward_block`) keeps them
+# and does not run `attention_fwd` a second time. `out` is as large as q,
+# `lse` a 32nd of it in float32 at D = 128. Outside a `jax.checkpoint` with a
+# policy a name is the identity.
+KEPT_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
+
+
 def _flash_fwd(q, k, v, lengths, rule, blocks, interpret):
     out, lse = _run_fwd(q, k, v, lengths, rule, *blocks, interpret)
+    out, lse = map(checkpoint_name, (out, lse), KEPT_RESIDUALS)
     return out, (q, k, v, out, lse, lengths)
 
 

@@ -407,7 +407,8 @@ class OptimizationConfig(Message):
     # HBM lever for big models/long sequences (SURVEY.md: jax.checkpoint)
     # "block" checkpoints each run of layers the config tagged with one
     # remat_block name (a transformer block): backward recomputes a block
-    # from its saved input, so activations cost one block's worth
+    # from its saved input, so activations cost one block's worth, plus
+    # the residuals its kernels named (the flash kernel's out and lse)
     remat: str = "none"          # none|full|block
     # lax.scan unroll factor for recurrent layers / recurrent groups:
     # unrolling k steps per scan iteration lets XLA pipeline the per-step

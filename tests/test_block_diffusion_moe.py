@@ -310,7 +310,7 @@ def test_seq_slice_takes_a_part_of_the_time_axis():
 DEMO = os.path.join(REPO, "demo", "block_diffusion_moe")
 
 
-def _demo_machine(remat):
+def _demo_machine(args=""):
     from paddle_tpu.config import parse_config
     from paddle_tpu.graph.machine import GradientMachine
 
@@ -318,31 +318,64 @@ def _demo_machine(remat):
     os.chdir(DEMO)
     sys.path.insert(0, DEMO)
     try:
-        conf = parse_config("trainer_config.py", "")
+        conf = parse_config("trainer_config.py", args)
     finally:
         os.chdir(cwd)
         sys.path.remove(DEMO)
-    return GradientMachine(conf.model_config), remat
+    return GradientMachine(conf.model_config)
 
 
-def test_block_recomputation_changes_no_number():
-    gm, _ = _demo_machine("block")
+def _pallas_calls(jaxpr, into=None):
+    """The names of every `pallas_call` of a jaxpr, sub-jaxprs included."""
+    into = [] if into is None else into
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            into.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, into)
+    return into
+
+
+@pytest.mark.parametrize("rule,seq_len", [
+    ("block_diffusion", 64), ("causal", 64),     # 2L = 128: the flash kernel, interpreted
+    ("block_diffusion", 32),                     # the kernel's gate refuses 64: the XLA path
+], ids=["block_diffusion", "causal", "xla_path"])
+def test_block_recomputation_changes_no_number(rule, seq_len, monkeypatch):
+    """`remat="block"` against `remat="none"`: the same loss and gradients,
+    and the flash forward ONCE a layer (the block keeps the kernel's named
+    `out` and `lse`; a bare checkpoint ran it twice); a block with no such
+    kernel keeps nothing."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    gm = _demo_machine(f"seq_len={seq_len}")
     assert {l.remat_block for l in gm.model.layers} == {"", "block0", "block1"}
+    for l in gm.model.layers:
+        if l.type == "multi_head_attention":
+            l.attention_mask = rule
+    n = seq_len                                  # corpus tokens; 2n positions fed
     params = gm.init_params(seed=3)
     rng = np.random.RandomState(11)
     lens = lambda t: jnp.full((2,), t, jnp.int32)
     batch = {
-        "tokens": Argument(ids=jnp.asarray(rng.randint(0, 97, (2, 2 * L)), jnp.int32),
-                           seq_lengths=lens(2 * L)),
-        "labels": Argument(ids=jnp.asarray(rng.randint(0, 96, (2, L)), jnp.int32),
-                           seq_lengths=lens(L)),
-        "weights": Argument(value=jnp.asarray(rng.rand(2, L, 1).astype(np.float32)),
-                            seq_lengths=lens(L)),
+        "tokens": Argument(ids=jnp.asarray(rng.randint(0, 97, (2, 2 * n)), jnp.int32),
+                           seq_lengths=lens(2 * n)),
+        "labels": Argument(ids=jnp.asarray(rng.randint(0, 96, (2, n)), jnp.int32),
+                           seq_lengths=lens(n)),
+        "weights": Argument(value=jnp.asarray(rng.rand(2, n, 1).astype(np.float32)),
+                            seq_lengths=lens(n)),
     }
     plain = jax.jit(gm.grad_fn("none"))(params, batch, None)
     blocks = jax.jit(gm.grad_fn("block"))(params, batch, None)
     np.testing.assert_allclose(plain[0], blocks[0], rtol=1e-6)
-    for n in params:
-        np.testing.assert_allclose(plain[1][n], blocks[1][n], atol=1e-5, err_msg=n)
+    for name in params:
+        np.testing.assert_allclose(plain[1][name], blocks[1][name], atol=1e-5, err_msg=name)
     # a block's extras cross its edge
     assert {"l1_moe@chosen", "l1_moe@counter.sum:moe.pairs_held"} <= set(blocks[2])
+    # the kernels of the gradient's program: a layer's forward once, with
+    # or without blocks (2 layers)
+    a_layer = ["attention_dkv", "attention_dq", "attention_fwd"] if seq_len == 64 else []
+    for remat in ("none", "block"):
+        jaxpr = jax.make_jaxpr(gm.grad_fn(remat))(params, batch, None).jaxpr
+        assert sorted(_pallas_calls(jaxpr)) == sorted(2 * a_layer), remat

@@ -182,3 +182,25 @@ def test_kernel_compiles_where_its_gate_says_yes(case, grad, chip):
             f"{type(e).__name__}: {str(e)[:400]}")
         return
     assert "tpu_custom_call" in hlo, f"{case}: no Mosaic kernel in the HLO"
+
+
+def test_a_recomputation_block_runs_the_flash_forward_once(chip):
+    """The kernel's in-step form (the cases above prove it alone): under a
+    recomputation block (`graph/network.py::recompute_block`) and a
+    gradient, the block keeps the kernel's named `out` and `lse`, so the
+    program holds `attention_fwd` once beside `attention_dq` and
+    `attention_dkv`; a bare `jax.checkpoint` holds it twice."""
+    from paddle_tpu.graph.network import recompute_block
+    from paddle_tpu.observability.compile_log import hlo_census
+
+    fn, shapes, gate = _rule_attention("block_diffusion")
+    assert gate
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+
+    def calls(block):
+        loss = lambda *a: jnp.sum(block(*a).astype(F32))
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*args).compile()
+        return hlo_census(compiled, compiled.as_text())["mosaic_calls"]
+
+    assert calls(recompute_block(fn)) == 3
+    assert calls(jax.checkpoint(fn)) == 4
