@@ -15,7 +15,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def flagship_config(dict_dim=1000, emb_dim=64, hidden=64, classes=2, mesh_shape=""):
     """Stacked-LSTM text classifier (the sentiment-demo shape) built via the
-    DSL; the secondary bench flagship next to ResNet."""
+    DSL."""
     from paddle_tpu.config.builder import fresh_context
     from paddle_tpu.trainer_config_helpers import (
         AdamOptimizer,

@@ -36,11 +36,9 @@ class TimeMajorLogits(NamedTuple):
     vocab projection's native flat [T*B, V] form instead of the
     transposed [B, T, V] view. The fused-CE consumer reduces over V
     directly on this layout and transposes only the tiny [T, B]
-    per-step costs — transposing the V-sized tensor itself forced a
-    full-tensor relayout copy inside the train step (~0.5 GB/step on
-    the NMT flagship, 3.6% of device time in the 2026-08-01 TPU trace:
-    %copy.167 bf16[3750,8,32,B] between the projection's {0,1} layout
-    and the [B,T,V] consumers)."""
+    per-step costs — transposing the V-sized tensor itself forces a
+    full-tensor relayout copy inside the train step (between the
+    projection's {0,1} layout and the [B,T,V] consumers)."""
 
     flat: jax.Array   # [T*B, V]
     T: int

@@ -172,9 +172,10 @@ def _conv1x1_stats_forward(cfg: LayerConfig, inputs: List[Argument],
     full HBM re-read of this output. Returns None whenever any gate fails
     — the caller falls through to the XLA conv, identical semantics.
 
-    Measured end-to-end LOSER on v5e (doc/performance.md round-5
-    conv-stats A/B: layout-boundary copies); kept as the
-    conv_stats_mode="pallas" A/B knob. Gates beyond the shared ones
+    The kernel's row-major [M, K] interface costs layout copies at its
+    boundary (XLA lays conv outputs batch-near-minor), which the "gram"
+    mode avoids; this is the conv_stats_mode="pallas" knob, off by
+    default and not measured on the chip. Gates beyond the shared ones
     mirror the fused-RNN path (layers/recurrent.py): single-device only
     (no GSPMD partitioning rule for the custom call), TPU backend or
     forced interpret mode, and kernel shape/VMEM support.
@@ -252,8 +253,8 @@ def _publish_gram_stats(cfg: LayerConfig, ctx: LayerContext, x_nhwc: Array,
     batch_norm consumes the entry
     (XLA dead-code-eliminates the unused reduces). All plain jnp ops:
     autodiff composes the stats' gradient with the conv's naturally, and
-    XLA keeps its own conv layouts — the measured failure mode of the
-    pallas variant (doc/performance.md round-5 conv-stats A/B).
+    XLA keeps its own conv layouts, which the pallas variant's
+    row-major interface cannot.
 
     Semantics note: these are statistics of the UNROUNDED x@w (the
     activation-dtype path reduces the bf16-rounded y) — a ~1e-3-relative
@@ -442,8 +443,7 @@ def batch_norm_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext
     # (XLA fuses the widening convert into the reduce) and the NORMALIZATION
     # applies as a per-channel scale/offset in the activation dtype. The
     # previous hp(xr)-then-normalize-in-f32 formulation materialized f32
-    # copies/reshapes of every BN input — ~60% of the ResNet-50 bf16 step's
-    # device time on TPU (see benchmarks/RESULTS.md round-4 trace analysis).
+    # copies/reshapes of every BN input.
     # gamma/beta/running stats are master-dtype params (cast=False).
     gamma = ctx.param(cfg.inputs[0].input_parameter_name, cast=False).reshape(C)
     beta = (

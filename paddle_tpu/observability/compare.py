@@ -404,13 +404,13 @@ def _bench_side(path: str, raw: str) -> Dict[str, float]:
         out["compile_total_s"] = float(line["compile_s"]) + float(
             line.get("trace_s") or 0.0
         )
-    # memory trajectory: bench legs stamp static_mem_bytes (the leg's
+    # memory trajectory: a bench line stamps static_mem_bytes (the
     # compiled plan — deterministic, comparable) AND peak_hbm_bytes
     # (allocator peak). Only the static plan joins the verdict surface:
-    # the allocator peak is cumulative over the PROCESS, so a ladder
-    # leg that stepped down past an OOM'd larger attempt inherits that
-    # attempt's peak — diffing it against a straight-to-size baseline
-    # would manufacture a phantom footprint regression.
+    # the allocator peak is cumulative over the PROCESS, so whatever
+    # ran before the measured part (a larger attempt, a calibration
+    # pass) is in it — diffing it would manufacture a phantom
+    # footprint regression.
     v = line.get("static_mem_bytes")
     if isinstance(v, (int, float)) and not isinstance(v, bool):
         out["static_mem_bytes"] = float(v)
@@ -491,7 +491,7 @@ def _bench_side(path: str, raw: str) -> Dict[str, float]:
         ):
             out[leg] = float(payload["value"])
             # peak_hbm_bytes deliberately NOT copied — see the
-            # ladder-inheritance note above
+            # process-cumulative note above
             for key in ("mfu", "compile_s", "trace_s", "static_mem_bytes"):
                 v = payload.get(key)
                 # bool is an int subclass — exclude it explicitly

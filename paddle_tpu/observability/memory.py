@@ -91,7 +91,7 @@ def is_oom_error(e: BaseException) -> bool:
     """True only for device-memory exhaustion. The match is message-based
     (XlaRuntimeError carries no typed subclass for it) and deliberately
     narrow: a shape bug must crash loudly, not masquerade as an OOM
-    pre-mortem (same contract as bench.py's ladder gate)."""
+    pre-mortem."""
     msg = f"{type(e).__name__}: {e}".lower()
     return any(
         s in msg

@@ -1,8 +1,7 @@
 """Request-level serving telemetry + the offered-load serve harness.
 
 Training got end-to-end observability in PR 3 (metrics stream) and PR 7
-(compile/roofline attribution); generation had none — ``bench_nmt_gen``
-reports one aggregate tokens/s for a static batch, and the embedding
+(compile/roofline attribution); generation had none — the embedding
 API's ``SequenceGenerator`` emits nothing. This module is the telemetry
 contract the continuous-batching server (ROADMAP item 1) must keep,
 built and exercised *before* that server exists so it lands on
@@ -74,9 +73,9 @@ SERVE_GROUPS = (SERVE_GROUP, "serve_decode", "serve_prefill",
                 "serve_verify")
 
 # mean exec seconds per launch at or below which a rung is classified
-# dispatch-bound: the launch is latency-floor sized (per-launch dispatch
-# overhead ~1-3ms through the runtime — doc/performance.md "Fused
-# launches"), so wider batching, not a kernel fix, is the lever
+# dispatch-bound: the launch is no longer than what dispatching it
+# through the runtime costs (doc/performance.md "Fused launches"), so
+# wider batching, not a kernel fix, is the lever
 DISPATCH_FLOOR_S = 3e-3
 
 # a rung saturates when it completes less than this share of arrivals,

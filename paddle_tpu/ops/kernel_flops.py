@@ -1,13 +1,13 @@
 """Analytic FLOP accounting for the fused Pallas recurrent kernels.
 
-XLA's cost analysis (``compiled.cost_analysis()['flops']`` — the basis of
-benchmarks/mfu.py) cannot see inside a ``pallas_call`` custom call, so a
-train step that runs the fused LSTM/GRU kernels would report an MFU that
-excludes the kernels' matmul FLOPs — the dominant term. The kernel
-wrappers therefore ``record()`` their analytic FLOP count at TRACE time;
-bench.py wraps its one AOT ``step.lower(...)`` in ``capture()`` and adds
-the recorded counts to the cost-analysis number, restoring a
-comparable-basis MFU between the pallas and XLA-scan paths.
+XLA's cost analysis (``compiled.cost_analysis()['flops']``) cannot see
+inside a ``pallas_call`` custom call, so a train step that runs the fused
+LSTM/GRU kernels would report an MFU that excludes the kernels' matmul
+FLOPs — the dominant term. The kernel wrappers therefore ``record()``
+their analytic FLOP count at TRACE time; a caller that wraps its
+``step.lower(...)`` in ``capture()`` can add the recorded counts to the
+cost-analysis number, for a comparable-basis MFU between the pallas and
+XLA-scan paths.
 
 FLOP conventions match HloCostAnalysis: a [M,K]x[K,N] dot is 2·M·K·N;
 elementwise add/mul count 1 per output element; transcendentals
@@ -65,9 +65,8 @@ def gru_bwd_flops(T: int, B: int, H: int) -> float:
 #
 # XLA's HloCostAnalysis counts a while/scan BODY once regardless of trip
 # count, so `compiled.cost_analysis()['flops']` understates any scanned
-# computation by ~T — on the recurrent bench legs the recurrence is the
-# dominant FLOP term, which made their round-4 MFU figures several-fold
-# pessimistic (the hoisted x-projections were counted, the T-step
+# computation by ~T — in a recurrent model the recurrence is the
+# dominant FLOP term (the hoisted x-projections are counted, the T-step
 # recurrence effectively not). The honest basis for MFU is analytic MODEL
 # matmul FLOPs (the MLPerf / scaling-book convention); this counter
 # computes them exactly by walking the train step's jaxpr: dot_general and
@@ -130,7 +129,7 @@ def jaxpr_flops(jaxpr, scale: float = 1.0) -> float:
             total += jaxpr_flops(eqn.params["jaxpr"], scale * _prod(grid or (1,)))
         elif name == "while":
             # trip count is dynamic: count the body once (the generation
-            # decoder is the only while user; bench legs are scans)
+            # decoder is the only while user; train steps are scans)
             total += jaxpr_flops(eqn.params["body_jaxpr"], scale)
         elif name == "cond":
             total += max(

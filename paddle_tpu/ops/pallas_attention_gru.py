@@ -2,10 +2,10 @@
 
 The seqToseq NMT decoder's per-step machinery — Bahdanau attention
 (transform/combine/softmax/scaling/pooling), the context projection and
-the GRU cell — is ~57% of the measured NMT train step (the
-2026-08-01 traces_nmt_flat summary: per-step scan/while bodies and
-their small fusions), because every decoder step pays XLA while-loop
-bookkeeping plus a handful of sub-MXU kernel launches. This kernel runs
+the GRU cell — is the larger part of the NMT train step's device time
+(`decoder_scan_ms.train` in PERF.md), because every decoder step pays
+XLA while-loop bookkeeping plus a handful of sub-MXU kernel launches.
+The kernel compiles for the chip and has never run on it. It runs
 the WHOLE decoder time loop in one launch, batch-blocked so the encoder
 states stay VMEM-resident across all decoder steps of a batch block:
 

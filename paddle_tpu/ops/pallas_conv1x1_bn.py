@@ -2,9 +2,8 @@
 
 ResNet-style conv+BN chains pay a full extra HBM read per layer: the
 conv writes its output y, then the BN statistics pass re-reads all of y
-to reduce per-channel sum/sum-of-squares (30.7% of the measured
-ResNet-50 bf16 step — benchmarks/RESULTS.md round-5 trace, the
-`convert_reduce_fusion` category). XLA:TPU cannot fuse a reduction into
+to reduce per-channel sum/sum-of-squares (XLA's
+`convert_reduce_fusion`s). XLA:TPU cannot fuse a reduction into
 a convolution's epilogue from lax-level code, but a 1x1 stride-1 conv
 IS a matmul over [B*H*W, Cin] x [Cin, Cout] — so this kernel computes
 the matmul tile-by-tile and accumulates the per-channel statistics of

@@ -3,14 +3,13 @@ kernel launch.
 
 The XLA path (`layers/recurrent.py` ``_scan_time``) compiles the LSTM to a
 `lax.while` whose per-step body is a small [B, H]x[H, 4H] matmul plus ~7
-separate gate/mask/slice fusions — on the traced bench leg those per-step
-fusions are ~36% of device time and the while-loop wrappers dominate the
-rest. Here one Pallas kernel walks the sequential grid over T with the
-recurrent weight and the (h, c) carry resident in VMEM: per step, one MXU
-dot plus VPU gate math, no HBM round-trips for the carry and no per-step
-kernel launches. Backward is a second sequential kernel (reverse grid)
-that accumulates dW / peephole grads in VMEM across steps — the classic
-fused-LSTM backward.
+separate gate/mask/slice fusions, each its own small launch inside the
+while-loop's wrappers. Here one Pallas kernel walks the sequential grid
+over T with the recurrent weight and the (h, c) carry resident in VMEM:
+per step, one MXU dot plus VPU gate math, no HBM round-trips for the
+carry and no per-step kernel launches. Backward is a second sequential
+kernel (reverse grid) that accumulates dW / peephole grads in VMEM across
+steps — the classic fused-LSTM backward.
 
 Cell semantics are exactly `lstm_cell_step` (reference LstmLayer.cpp /
 LstmCompute.cu contract, see layers/recurrent.py:79): gate order
@@ -259,9 +258,8 @@ def _run_fwd(x4, mask_tb1, w, peep, acts, interpret, residuals=True,
     reshape) and ys comes back [B, T*H]; the per-step blocks are the
     same [B, 4H]/[B, H] tiles, addressed at lane offset t*width, so the
     boundary transposes the time-major interface forced on the x4/ys
-    cotangent path disappear (measured 16.9% of the pallas-leg step —
-    benchmarks/RESULTS.md round-5 trace note). Residual streams stay
-    time-major: they never cross the kernel boundary."""
+    cotangent path disappear. Residual streams stay time-major: they
+    never cross the kernel boundary."""
     if flat:
         B = mask_tb1.shape[1]
         T = mask_tb1.shape[0]
@@ -360,8 +358,8 @@ def fused_lstm(x4, mask, w, peep, acts, interpret, flat=False):
     Time-major interface (flat=False): x4 [T, B, 4H], ys [T, B, H].
     Flat interface (flat=True): x4 [B, T*4H] — the x-projection's
     row-major reshape, no transpose — and ys [B, T*H]; removes the
-    boundary transposes on the x4/ys cotangent path (a measured 16.9%
-    of the pallas-leg step). mask is [T, B] in BOTH modes (tiny).
+    boundary transposes on the x4/ys cotangent path. mask is [T, B] in
+    BOTH modes (tiny).
     x4 carries the gate biases already added; w [H, 4H]; peep [3, H]
     (zeros when absent); acts = (act_in, act_gate, act_state).
     """
@@ -416,8 +414,7 @@ def lstm_layer_forward(cfg, x, mask, w, bias, interpret, x_bt=None):
     ``x_bt`` (PADDLE_TPU_PALLAS_FLAT=1): the batch-major [B, T, 4H]
     projection output — the kernel then runs on its free row-major
     [B, T*4H] reshape and returns ys without any boundary transpose
-    (the time-major interface's x4/ys/dx4 relayouts were a measured
-    16.9% of the pallas-leg step)."""
+    (the time-major interface relayouts x4, ys and dx4)."""
     H = cfg.size
     flat = x_bt is not None
     T = mask.shape[0]

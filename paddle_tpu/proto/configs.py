@@ -435,18 +435,16 @@ class OptimizationConfig(Message):
     # _stem_s2d_conv). Off by default until measured on the target chip.
     conv_s2d: bool = False
     # fused 1x1-conv + batch-norm statistics, to eliminate the BN stats
-    # pass's full re-read of the conv output from HBM (30.7% of the
-    # measured ResNet-50 bf16 step). Two modes:
+    # pass's full re-read of the conv output from HBM. Two modes:
     #  - "gram": compute sum/sumsq of y = x@w + b from the INPUT side
     #    (colsum(x)@w and w^T(x^Tx)w, exact algebra) — pure XLA, keeps
     #    every conv layout/fusion, applied when N >= 2K so the two x
     #    reads beat the saved y read (layers/vision.py).
     #  - "pallas": the ops/pallas_conv1x1_bn kernel accumulates stats in
-    #    the matmul epilogue. Measured END-TO-END LOSER on v5e
-    #    (2026-08-01: 1272 vs 2220 imgs/s): XLA lays conv outputs
-    #    batch-near-minor and the kernel's row-major [M,K] interface
-    #    forces ~33% of the step into relayout copies. Kept for A/B.
-    #  - "": off (default until a measured win).
+    #    the matmul epilogue. XLA lays conv outputs batch-near-minor,
+    #    so the kernel's row-major [M,K] interface forces relayout
+    #    copies at its boundary.
+    #  - "": off (the default: neither mode is measured on the chip).
     conv_stats_mode: str = ""
     # run a matching attention-GRU decoder recurrent group (the seqToseq
     # template) as ONE fused Pallas launch per train step, encoder

@@ -162,7 +162,7 @@ class JaxDecodeBackend:
         # with host copies in flight, block, dispatch wall time)
         self._inflight: collections.deque = collections.deque()
         # union-of-spans anchor: exec seconds must not double-count
-        # overlapping dispatch->done spans (doc/performance.md
+        # overlapping dispatch->done spans (doc/serving.md
         # "Pipelined decode")
         self._exec_anchor = cc.perf_counter()
 

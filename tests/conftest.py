@@ -20,6 +20,7 @@ from paddle_tpu.utils.backend_guard import ensure_cpu_mesh  # noqa: E402
 ensure_cpu_mesh(8)
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_threefry_partitionable", True)
 # persistent compilation cache: repeat suite runs skip recompiling the
@@ -35,3 +36,18 @@ assert len(jax.devices()) == 8, (
     "test suite expects 8 virtual CPU devices; got "
     f"{jax.devices()} — check conftest ordering"
 )
+
+
+@pytest.fixture(autouse=True)
+def _flags_restored_after_test():
+    """FLAGS is one process-wide object and an xdist worker runs many
+    files: whatever a test sets is put back when it ends, so no file's
+    outcome depends on which files ran before it on the same worker.
+    (A module's shared fixture builds `_Flags()` of its own and hands
+    them to `Trainer`: this restore runs per test.)"""
+    from paddle_tpu.utils.flags import FLAGS
+
+    saved = dict(vars(FLAGS))
+    yield
+    vars(FLAGS).clear()
+    vars(FLAGS).update(saved)

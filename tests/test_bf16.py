@@ -23,7 +23,9 @@ from paddle_tpu.graph.machine import compute_dtype_of
 from paddle_tpu.optimizer import Updater
 
 
-def _train(tc, batch, steps=5, seed=1):
+def _jit_step(tc, seed=1):
+    """The train step of `tc` as the tests of this file drive it:
+    (jitted step, params, optimizer state, machine)."""
     gm = GradientMachine(tc.model_config, compute_dtype=compute_dtype_of(tc.opt_config))
     up = Updater(tc.opt_config, tc.model_config)
     params = gm.init_params(seed=seed)
@@ -38,6 +40,11 @@ def _train(tc, batch, steps=5, seed=1):
             new_params[k] = v
         return new_params, new_st, loss, grads
 
+    return step, params, st, gm
+
+
+def _train(tc, batch, steps=5, seed=1):
+    step, params, st, gm = _jit_step(tc, seed)
     losses = []
     rng = jax.random.PRNGKey(7)
     grads = None
@@ -230,28 +237,19 @@ def test_sparse_table_grads_stay_f32_under_bf16():
 
 
 def test_resnet_bf16_reaches_every_convolution():
-    """The perf contract behind the headline bench: under
-    dtype='bfloat16', EVERY convolution (forward and backward) in the
-    lowered ResNet train step takes/produces bf16 — what the TPU backend
-    maps onto the MXU's bf16 path. Checked on the pre-backend StableHLO
-    (XLA:CPU would legalize bf16 convs to f32, hiding a regression)."""
-    import os
-    import sys as _sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    _sys.path.insert(0, repo)
-    try:
-        import bench
-    finally:
-        _sys.path.remove(repo)
+    """Under dtype='bfloat16', EVERY convolution (forward and backward)
+    in the lowered ResNet train step takes/produces bf16 — what the TPU
+    backend maps onto the MXU's bf16 path. Checked on the pre-backend
+    StableHLO (XLA:CPU would legalize bf16 convs to f32, hiding a
+    regression)."""
     from paddle_tpu.flagship import make_image_batch, resnet_config
 
     tc = resnet_config(50, 32, 16)
     tc.opt_config.batch_size = 4
     tc.opt_config.dtype = "bfloat16"
-    step, params, opt_state, _one = bench._jit_train_step(tc)
+    step, params, opt_state, _gm = _jit_step(tc)
     batch = make_image_batch(4, 32, 16)
-    txt = step.lower(params, opt_state, batch, jnp.asarray(4.0)).as_text()
+    txt = step.lower(params, opt_state, batch, jax.random.PRNGKey(0)).as_text()
     convs = [l for l in txt.splitlines() if "stablehlo.convolution" in l]
     assert len(convs) > 100, f"expected ResNet-50 fwd+bwd convs, got {len(convs)}"
     f32_convs = [l for l in convs if "xbf16>" not in l.split("->")[-1]]

@@ -144,11 +144,12 @@ def _conv1x1(M, K, N, dtype):
 
 
 CASES = {
-    # flagship LSTM classifier (bench_lstm_classifier: B=256, H=512)
+    # flagship LSTM classifier (flagship.flagship_config at B=256, H=512)
     "lstm-bf16": lambda: _lstm(256, 512, BF16, False),
     "lstm-bf16-flat": lambda: _lstm(256, 512, BF16, True),
     "lstm-f32": lambda: _lstm(256, 512, F32, False),
-    # seqToseq NMT encoder (H=512; 448 tops the bench ladder)
+    # seqToseq NMT encoder (H=512; nmt.train runs 256, 448 is the most
+    # the kernel ALONE compiles at: PERF.md section 7, fault 1)
     "gru-bf16-b256": lambda: _gru(256, 512, BF16, False),
     "gru-bf16-b448": lambda: _gru(448, 512, BF16, False),
     "gru-bf16-b448-flat": lambda: _gru(448, 512, BF16, True),

@@ -11,6 +11,7 @@ variant of the step (single, fused launch, accumulation, skip policy).
 
 import os
 import sys
+import tempfile
 import textwrap
 
 import jax
@@ -266,13 +267,13 @@ def _config(tmp, net, obj, settings="", extra=""):
 
 def _pass_end(cfg, tmp, **flags):
     """One pass of `Trainer.train()`; its `pass_end` record and the trainer."""
-    flags = dict(save_dir="", metrics_path=str(tmp / "metrics"), num_passes=1,
+    flags = dict(save_dir=tempfile.mkdtemp(dir=tmp),
+                 metrics_path=str(tmp / "metrics"), num_passes=1,
                  start_pass=0, log_period=0, init_model_path="",
                  trace_events_path="", seed=7, **flags)
-    before = {k: getattr(FLAGS, k) for k in flags}
+    for k, v in flags.items():
+        setattr(FLAGS, k, v)
     try:
-        for k, v in flags.items():
-            setattr(FLAGS, k, v)
         obs.registry().reset()
         trainer = Trainer(cfg)
         trainer.train(num_passes=1)
@@ -280,8 +281,6 @@ def _pass_end(cfg, tmp, **flags):
     finally:
         obs.configure("")
         obs_spans.configure("")
-        for k, v in before.items():
-            setattr(FLAGS, k, v)
     return [r for r in records if r["kind"] == "pass_end"][-1], trainer
 
 

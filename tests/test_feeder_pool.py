@@ -2,11 +2,8 @@
 the --data_packer_threads pool must preserve batch order and shuffle
 semantics exactly, keep the stall watchdog / fault-site / bad-sample
 budget contracts of the single-thread prefetch path, respect the
---prefetch_depth bound, and publish the pack_threads_busy telemetry.
-Also covers the bench.py feeder microbenchmark leg's shape."""
+--prefetch_depth bound, and publish the pack_threads_busy telemetry."""
 
-import os
-import sys
 import threading
 import time
 
@@ -23,9 +20,6 @@ from paddle_tpu.data.provider import (
 from paddle_tpu.observability import metrics as obs
 from paddle_tpu.resilience import BadSampleError, DataStallError, faultinject
 from paddle_tpu.utils.retry import RetryPolicy
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(autouse=True)
 def _fresh():
@@ -187,20 +181,3 @@ def _mk_dp_seq(p, **kw):
 
 def _values_seq(batches):
     return [np.asarray(b["x"].seq_lengths).tolist() for b in batches]
-
-
-# ------------------------------------------------------- bench feeder leg
-
-
-def test_bench_feeder_leg_small():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    rate, extras = bench.bench_feeder(B=16, dim=32, n_batches=6, repeats=1)
-    assert rate > 0
-    assert extras["packer_threads"] == 2
-    assert extras["samples_per_sec_1thread"] > 0
-    assert extras["bytes_per_sec"] > 0
-    assert "speedup_vs_1thread" in extras

@@ -250,12 +250,9 @@ def test_fused_nan_gate_fires_before_housekeeping(tmp_path):
     # SECOND batch of the first launch sees a non-finite loss
     cfg.opt_config.learning_rate = float("inf")
     FLAGS.saving_period_by_batches = 1  # housekeeping WOULD save each batch
-    try:
-        t = Trainer(cfg)
-        with pytest.raises(FloatingPointError, match="launch of"):
-            t.train(num_passes=1)
-    finally:
-        FLAGS.saving_period_by_batches = 0
+    t = Trainer(cfg)
+    with pytest.raises(FloatingPointError, match="launch of"):
+        t.train(num_passes=1)
     # the gate fired before per-batch housekeeping: despite a save period
     # of one batch, no checkpoint of the poisoned params was written
     # (telemetry artifacts — metrics.jsonl — are fine; pass dirs are not)

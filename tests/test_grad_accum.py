@@ -6,6 +6,7 @@ sample-weighted mean gradient is identical).
 
 import os
 import sys
+import tempfile
 import textwrap
 
 import numpy as np
@@ -62,18 +63,15 @@ def ws(tmp_path):
 
 
 def _train(tmp_path, batch_size, accum, mesh_shape=""):
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = tempfile.mkdtemp(dir=tmp_path)
     FLAGS.log_period = 0
     FLAGS.start_pass = 0
     FLAGS.init_model_path = ""
     FLAGS.mesh_shape = mesh_shape
-    try:
-        cfg = parse_config(_config(tmp_path, batch_size, accum))
-        tr = Trainer(cfg)
-        tr.train(num_passes=2)
-        return {k: np.asarray(v) for k, v in tr.params.items()}
-    finally:
-        FLAGS.mesh_shape = ""
+    cfg = parse_config(_config(tmp_path, batch_size, accum))
+    tr = Trainer(cfg)
+    tr.train(num_passes=2)
+    return {k: np.asarray(v) for k, v in tr.params.items()}
 
 
 def test_accum_matches_large_batch(ws):
@@ -136,7 +134,7 @@ def test_accum_with_sparse_table_falls_back_dense(ws):
             toks = rng.randint(25 * y, 25 * y + 25, rng.randint(3, 8))
             yield [int(t) for t in toks], int(y)
     """))
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = str(ws / "model")
     FLAGS.log_period = 0
     FLAGS.start_pass = 0
     FLAGS.init_model_path = ""

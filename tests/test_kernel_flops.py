@@ -5,8 +5,8 @@ scan-path computation (fully unrolled so every step is visible to
 HloCostAnalysis — a rolled while body is counted once regardless of trip
 count), and the trace-time capture must collect exactly one fwd + one bwd
 record when a train-shaped jit containing a fused kernel is lowered —
-that sum is what bench.py adds to cost_analysis()['flops'] so pallas and
-XLA legs report comparable-basis MFU.
+that sum is what a caller adds to cost_analysis()['flops'] so the pallas
+and XLA paths report comparable-basis MFU.
 """
 
 import types
@@ -95,8 +95,8 @@ def test_gru_analytic_matches_unrolled_scan_cost_analysis():
 
 def test_capture_collects_fwd_and_bwd_records_at_lower_time():
     """Lowering a value_and_grad jit over the fused LSTM must record
-    exactly one fwd + one bwd analytic count (what bench's AOT lower
-    collects); outside capture() recording is a no-op."""
+    exactly one fwd + one bwd analytic count; outside capture()
+    recording is a no-op."""
     from paddle_tpu.ops import pallas_lstm as pk
 
     T, B, H = 3, 8, 128

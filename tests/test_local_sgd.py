@@ -14,6 +14,7 @@ Semantics pinned here:
 """
 
 import sys
+import tempfile
 import textwrap
 
 import numpy as np
@@ -72,21 +73,16 @@ def ws(tmp_path):
 
 
 def _train(tmp_path, is_async, period=1, ratio=None, passes=2, stats_period=0):
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = tempfile.mkdtemp(dir=tmp_path)
     FLAGS.log_period = 0
     FLAGS.start_pass = 0
     FLAGS.init_model_path = ""
     FLAGS.mesh_shape = "data=8"
-    prev_stats = FLAGS.show_parameter_stats_period
     FLAGS.show_parameter_stats_period = stats_period
-    try:
-        cfg = parse_config(_config(tmp_path, is_async, period, ratio))
-        tr = Trainer(cfg)
-        tr.train(num_passes=passes)
-        return tr, {k: np.asarray(v) for k, v in tr.params.items()}
-    finally:
-        FLAGS.mesh_shape = ""
-        FLAGS.show_parameter_stats_period = prev_stats
+    cfg = parse_config(_config(tmp_path, is_async, period, ratio))
+    tr = Trainer(cfg)
+    tr.train(num_passes=passes)
+    return tr, {k: np.asarray(v) for k, v in tr.params.items()}
 
 
 def test_async_period1_matches_sync_momentum(ws):
@@ -210,13 +206,10 @@ def test_async_merge_period_not_rejected_as_accumulation(ws):
     (its reference meaning), so combining it with batches_per_launch
     must not trip the accumulation/fuse conflict check — fuse is simply
     ignored (mesh + async are not single-chip dispatch paths)."""
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = str(ws / "model")
     FLAGS.mesh_shape = "data=8"
-    try:
-        cfg = parse_config(_config(ws, is_async=True, period=4))
-        cfg.opt_config.batches_per_launch = 8
-        tr = Trainer(cfg)
-        assert tr._async and tr._sync_n == 4
-        assert tr._accum_n == 1 and tr._fuse_k == 1
-    finally:
-        FLAGS.mesh_shape = ""
+    cfg = parse_config(_config(ws, is_async=True, period=4))
+    cfg.opt_config.batches_per_launch = 8
+    tr = Trainer(cfg)
+    assert tr._async and tr._sync_n == 4
+    assert tr._accum_n == 1 and tr._fuse_k == 1

@@ -204,21 +204,15 @@ def smoke_run(tmp_path_factory):
     sys.path.insert(0, PROVIDER_DIR)
     from paddle_tpu.config import parse_config
     from paddle_tpu.trainer import Trainer
-    from paddle_tpu.utils.flags import FLAGS
+    from paddle_tpu.utils.flags import _Flags
 
+    # flags of its own: a module's shared run leaves nothing in FLAGS
     save_dir = str(tmp_path / "out")
-    FLAGS.config = cfg
-    FLAGS.save_dir = save_dir
-    FLAGS.num_passes = 2
-    FLAGS.log_period = 0
-    FLAGS.start_pass = 0
-    FLAGS.init_model_path = ""
-    FLAGS.seed = 7
-    FLAGS.metrics_path = ""
-    FLAGS.numerics_log_period = 0
+    flags = _Flags(config=cfg, save_dir=save_dir, num_passes=2, log_period=0,
+                   seed=7)
     obs.registry().reset()
     try:
-        trainer = Trainer(parse_config(cfg, ""), FLAGS)
+        trainer = Trainer(parse_config(cfg, ""), flags)
         trainer.train()
     finally:
         obs.configure("")

@@ -28,7 +28,7 @@ from paddle_tpu.config import parse_config
 from paddle_tpu.trainer import Trainer
 from paddle_tpu.utils.flags import FLAGS
 
-FLAGS.save_dir = ""
+FLAGS.save_dir = os.path.join(ws, "mp_model")
 FLAGS.metrics_path = os.path.join(ws, "mp_metrics")
 FLAGS.mesh_shape = "data=8"
 FLAGS.log_period = 0
@@ -112,7 +112,7 @@ def test_two_process_training_matches_single(tmp_path):
 
     from paddle_tpu.observability import metrics as obs
 
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = os.path.join(ws, "ref_model")
     FLAGS.metrics_path = os.path.join(ws, "ref_metrics")
     FLAGS.mesh_shape = "data=8"
     FLAGS.log_period = 0
@@ -121,8 +121,6 @@ def test_two_process_training_matches_single(tmp_path):
         ref = Trainer(parse_config(cfg_path))
         ref.train(num_passes=1)
     finally:
-        FLAGS.mesh_shape = ""
-        FLAGS.metrics_path = ""
         obs.configure("")
         sys.path.remove(PROVIDERS)
 

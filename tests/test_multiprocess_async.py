@@ -26,7 +26,7 @@ from paddle_tpu.config import parse_config
 from paddle_tpu.trainer import Trainer
 from paddle_tpu.utils.flags import FLAGS
 
-FLAGS.save_dir = ""
+FLAGS.save_dir = os.path.join(ws, "mp_model")
 FLAGS.mesh_shape = "data=8"
 FLAGS.log_period = 0
 FLAGS.seed = 7
@@ -74,7 +74,7 @@ def test_two_process_async_matches_single(tmp_path):
     from paddle_tpu.trainer import Trainer
     from paddle_tpu.utils.flags import FLAGS
 
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = os.path.join(ws, "ref_model")
     FLAGS.mesh_shape = "data=8"
     FLAGS.log_period = 0
     FLAGS.seed = 7
@@ -83,7 +83,6 @@ def test_two_process_async_matches_single(tmp_path):
         assert ref._async
         ref.train(num_passes=1)
     finally:
-        FLAGS.mesh_shape = ""
         sys.path.remove(PROVIDERS)
 
     mp_harness.run_two_workers(WORKER.format(repo=REPO, providers=PROVIDERS), ws)

@@ -23,7 +23,7 @@ from paddle_tpu.config import parse_config
 from paddle_tpu.trainer import Trainer
 from paddle_tpu.utils.flags import FLAGS
 
-FLAGS.save_dir = ""
+FLAGS.save_dir = os.path.join(ws, "mp_model")
 FLAGS.mesh_shape = "data=8"
 FLAGS.log_period = 0
 FLAGS.seed = 13
@@ -68,7 +68,7 @@ def test_two_process_recurrent_group_matches_single(tmp_path):
     from paddle_tpu.trainer import Trainer
     from paddle_tpu.utils.flags import FLAGS
 
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = os.path.join(ws, "ref_model")
     FLAGS.mesh_shape = "data=8"
     FLAGS.log_period = 0
     FLAGS.seed = 13
@@ -76,7 +76,6 @@ def test_two_process_recurrent_group_matches_single(tmp_path):
         ref = Trainer(parse_config(os.path.join(ws, "cfg.py")))
         ref.train(num_passes=1)
     finally:
-        FLAGS.mesh_shape = ""
         sys.path.remove(PROVIDERS)
 
     outs = mp_harness.run_two_workers(

@@ -108,7 +108,7 @@ def test_validation_layers_parse_train_and_report(tmp_path):
 
     providers = os.path.join(REPO, "tests", "providers")
     _sys.path.insert(0, providers)
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = str(tmp_path / "model")
     FLAGS.log_period = 0
     try:
         cfg = parse_config(str(cfg_path))

@@ -64,26 +64,16 @@ def _write_config(tmp_path):
 
 
 def _trainer(cfg, save_dir, **flag_overrides):
+    """A trainer on flags of its own: the module's shared run below
+    leaves nothing in the process-wide FLAGS."""
     from paddle_tpu.config import parse_config
     from paddle_tpu.trainer import Trainer
-    from paddle_tpu.utils.flags import FLAGS
+    from paddle_tpu.utils.flags import _Flags
 
-    FLAGS.config = cfg
-    FLAGS.save_dir = save_dir
-    FLAGS.num_passes = 2
-    FLAGS.log_period = 0
-    FLAGS.start_pass = 0
-    FLAGS.init_model_path = ""
-    FLAGS.seed = 7
-    FLAGS.metrics_path = ""
-    FLAGS.mesh_shape = ""
-    FLAGS.nonfinite_policy = "abort"
-    FLAGS.max_nonfinite_steps = 3
-    FLAGS.fault_spec = ""
-    FLAGS.numerics_log_period = 0
-    for k, v in flag_overrides.items():
-        setattr(FLAGS, k, v)
-    return Trainer(parse_config(cfg, ""), FLAGS)
+    defaults = dict(config=cfg, save_dir=save_dir, num_passes=2,
+                    log_period=0, seed=7)
+    flags = _Flags(**{**defaults, **flag_overrides})
+    return Trainer(parse_config(cfg, ""), flags)
 
 
 def _records(run_dir):

@@ -258,7 +258,6 @@ def test_nonfinite_events_recorded(tmp_path):
         Trainer(cfg).train(num_passes=1)
     finally:
         faultinject.configure("")
-        FLAGS.nonfinite_policy = "abort"
     records = list(obs.read_records(os.path.join(run_dir, "metrics.jsonl")))
     nf = [r for r in records if r["kind"] == "nonfinite"]
     assert len(nf) == 1 and nf[0]["policy"] == "skip"
@@ -457,13 +456,13 @@ def test_bench_emit_mirrors_metrics_schema(tmp_path, monkeypatch, capsys):
     finally:
         sys.path.remove(REPO)
     monkeypatch.setenv("PADDLE_TPU_BENCH_METRICS_DIR", str(tmp_path / "bm"))
-    bench._emit("resnet50_train_imgs_per_sec_per_chip", 123.4, "imgs/s", 1.0,
-                backend="cpu")
+    bench._emit("serve_goodput_tokens_per_sec", 123.4, "tokens/s",
+                engine="static")
     capsys.readouterr()  # swallow the stdout JSON line
     recs = list(obs.read_records(str(tmp_path / "bm" / "metrics.jsonl")))
     bench_recs = [r for r in recs if r["kind"] == "bench"]
     assert len(bench_recs) == 1
     rec = bench_recs[0]
     assert obs.validate_record(rec) == []
-    assert rec["metric"] == "resnet50_train_imgs_per_sec_per_chip"
-    assert rec["value"] == 123.4 and rec["unit"] == "imgs/s"
+    assert rec["metric"] == "serve_goodput_tokens_per_sec"
+    assert rec["value"] == 123.4 and rec["unit"] == "tokens/s"

@@ -496,7 +496,7 @@ def test_compare_shed_and_error_rates_lower_is_better(tmp_path):
         rung.update(rung_extra)
         p.write_text(json.dumps({
             "metric": "serve_cpu_smoke_goodput_tokens_per_sec",
-            "value": 5000.0, "unit": "tokens/s", "vs_baseline": 1.0,
+            "value": 5000.0, "unit": "tokens/s",
             "rungs": [rung],
         }))
         return str(p)
@@ -621,7 +621,7 @@ def test_compare_shed_ab_verdict_improved_with_abs_floor(tmp_path):
         p = tmp_path / name
         p.write_text(json.dumps({
             "metric": "serve_cpu_smoke_goodput_tokens_per_sec",
-            "value": 5000.0, "unit": "tokens/s", "vs_baseline": 1.0,
+            "value": 5000.0, "unit": "tokens/s",
             "rungs": [{"offered_rps": 300.0, "p50_ms": 20.0, "p99_ms": p99,
                        "goodput_tok_s": 5000.0, "shed_rate": shed_rate,
                        "error_rate": 0.0}],

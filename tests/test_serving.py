@@ -469,7 +469,7 @@ def test_compare_serve_bench_artifacts(tmp_path):
         p = tmp_path / name
         p.write_text(json.dumps({
             "metric": "serve_cpu_smoke_goodput_tokens_per_sec",
-            "value": 5000.0, "unit": "tokens/s", "vs_baseline": 1.0,
+            "value": 5000.0, "unit": "tokens/s",
             "knee_rps": knee,
             "rungs": [{"offered_rps": 50.0, "p50_ms": 2.0, "p99_ms": p99,
                        "ttft_p50_ms": 2.0, "ttft_p99_ms": p99,
@@ -505,7 +505,7 @@ def test_compare_pipeline_modes_never_cross_join(tmp_path):
         p = tmp_path / name
         p.write_text(json.dumps({
             "metric": "serve_cpu_smoke_goodput_tokens_per_sec",
-            "value": 5000.0, "unit": "tokens/s", "vs_baseline": 1.0,
+            "value": 5000.0, "unit": "tokens/s",
             "rungs": rungs,
         }))
         return str(p)

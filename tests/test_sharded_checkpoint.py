@@ -202,7 +202,7 @@ def test_sharded_ckpt_reshards_to_single_process(two_proc_ckpt):
     from paddle_tpu.trainer import Trainer, checkpoint as ckpt
     from paddle_tpu.utils.flags import FLAGS
 
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = os.path.join(two_proc_ckpt, "ref_model")
     FLAGS.mesh_shape = "data=4,model=2"
     FLAGS.log_period = 0
     FLAGS.seed = 11
@@ -210,7 +210,6 @@ def test_sharded_ckpt_reshards_to_single_process(two_proc_ckpt):
         ref = Trainer(parse_config(os.path.join(two_proc_ckpt, "cfg.py")))
         ref.train(num_passes=1)
     finally:
-        FLAGS.mesh_shape = ""
         sys.path.remove(PROVIDERS)
 
     path = os.path.join(two_proc_ckpt, "model", "pass-00000")

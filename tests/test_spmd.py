@@ -23,9 +23,9 @@ PROVIDER_DIR = os.path.join(os.path.dirname(__file__), "providers")
 
 
 @pytest.fixture(autouse=True)
-def _provider_path():
+def _provider_path(tmp_path):
     sys.path.insert(0, PROVIDER_DIR)
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = str(tmp_path / "model")
     FLAGS.mesh_shape = ""
     FLAGS.start_pass = 0
     FLAGS.init_model_path = ""

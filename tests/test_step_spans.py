@@ -19,7 +19,7 @@ from paddle_tpu.config import parse_config
 from paddle_tpu.observability import metrics as obs
 from paddle_tpu.observability import spans as obs_spans
 from paddle_tpu.trainer import Trainer
-from paddle_tpu.utils.flags import FLAGS
+from paddle_tpu.utils.flags import _Flags
 from paddle_tpu.utils.stats import global_stats, stat_timer
 
 pytestmark = pytest.mark.obs
@@ -61,25 +61,20 @@ def _config(tmp, evaluator=""):
 
 def _train(tmp, metrics_path, evaluator=""):
     """One pass of three steps; returns the run's records."""
-    flags = dict(save_dir=str(tmp / "out"), metrics_path=metrics_path,
-                 num_passes=1, start_pass=0, log_period=0, init_model_path="",
-                 trace_events_path="", seed=7)
-    before = {k: getattr(FLAGS, k) for k in flags}
+    # flags of its own: the module's shared runs leave nothing in FLAGS
+    flags = _Flags(save_dir=str(tmp / "out"), metrics_path=metrics_path,
+                   num_passes=1, log_period=0, seed=7)
     sys.path.insert(0, PROVIDER_DIR)
     try:
         cfg = parse_config(_config(tmp, evaluator))
-        for k, v in flags.items():
-            setattr(FLAGS, k, v)
         obs.registry().reset()
-        Trainer(cfg).train(num_passes=1)
-        path = os.path.join(metrics_path or FLAGS.save_dir, "metrics.jsonl")
+        Trainer(cfg, flags).train(num_passes=1)
+        path = os.path.join(metrics_path or flags.save_dir, "metrics.jsonl")
         return list(obs.read_records(path))
     finally:
         sys.path.remove(PROVIDER_DIR)
         obs.configure("")
         obs_spans.configure("")
-        for k, v in before.items():
-            setattr(FLAGS, k, v)
 
 
 def _program_spans(trace_dir):

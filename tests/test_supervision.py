@@ -283,7 +283,8 @@ def test_nonfinite_skip_finishes_where_abort_dies(tmp_path, bow_cfg):
     faultinject.configure("trainer.nonfinite=raise@3")
     t2 = Trainer(
         bow_cfg(),
-        _Flags(log_period=0, nonfinite_policy="skip", max_nonfinite_steps=2),
+        _Flags(log_period=0, nonfinite_policy="skip", max_nonfinite_steps=2,
+               save_dir=str(tmp_path / "model")),
     )
     t2.train(num_passes=1)  # completes
     assert t2._nf_count == 1

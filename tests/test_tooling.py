@@ -5,6 +5,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -67,6 +69,14 @@ def _write_fake_ssh(bin_dir, body):
             "PYTHONPATH": f"{REPO}:{REPO}/compat"}
 
 
+def _await_lines(path, n):
+    """Shell for a stub host that is about to fail: wait until ``n``
+    hosts have logged their call. The launcher kills the survivors the
+    moment it sees a failure, so a host that fails at once can take a
+    neighbour down before that neighbour's `echo` has run."""
+    return f"while [ \"$(wc -l < {path})\" -lt {n} ]; do sleep 0.05; done;"
+
+
 def test_cluster_launch_tears_down_on_first_host_failure(tmp_path):
     """One dead host must fail the whole launch promptly (and kill the
     surviving hosts) instead of leaving the launcher blocked in a serial
@@ -107,8 +117,8 @@ def test_cluster_launch_relaunches_with_auto_resume(tmp_path):
     env = _write_fake_ssh(tmp_path, (
         f"echo \"$remote\" >> {calls}\n"
         "case \"$host\" in\n"
-        f"  *once*) if [ ! -f {marker} ]; then touch {marker}; exit 2; fi;"
-        " exit 0;;\n"
+        f"  *once*) if [ ! -f {marker} ]; then touch {marker};"
+        f" {_await_lines(calls, 2)} exit 2; fi; exit 0;;\n"
         "  *) exit 0;;\n"
         "esac\n"
     ))
@@ -162,8 +172,8 @@ def test_cluster_launch_preemption_exit_is_budget_free(tmp_path):
     env = _write_fake_ssh(tmp_path, (
         f"echo \"$remote\" >> {calls}\n"
         "case \"$host\" in\n"
-        f"  *pre*) if [ ! -f {marker} ]; then touch {marker}; exit 18; fi;"
-        " exit 0;;\n"
+        f"  *pre*) if [ ! -f {marker} ]; then touch {marker};"
+        f" {_await_lines(calls, 2)} exit 18; fi; exit 0;;\n"
         "  *) exit 0;;\n"
         "esac\n"
     ))
@@ -381,115 +391,6 @@ def test_supervise_dry_run_prints_plan_without_launching(tmp_path):
     assert not (tmp_path / "sup").exists()
 
 
-def test_trace_summary_reads_cpu_trace(tmp_path):
-    """benchmarks/trace_summary.py parses a jax.profiler xplane trace and
-    surfaces the dominant op (the HLO dot — SSA instances like "dot.4"
-    folded onto their opcode; older jax exposed the framework name
-    "dot_general", also accepted) for a matmul-heavy step."""
-    import io
-    import re
-    import sys as _sys
-    from contextlib import redirect_stdout
-
-    import jax
-    import jax.numpy as jnp
-
-    _sys.path.insert(0, str(REPO))
-    try:
-        from benchmarks.trace_summary import print_summary
-    finally:
-        _sys.path.remove(str(REPO))
-
-    f = jax.jit(lambda a, b: jnp.tanh(a @ b).sum())
-    a = jnp.ones((256, 256))
-    f(a, a).block_until_ready()
-    with jax.profiler.trace(str(tmp_path)):
-        for _ in range(3):
-            f(a, a).block_until_ready()
-    buf = io.StringIO()
-    with redirect_stdout(buf):
-        rc = print_summary(str(tmp_path), 10)
-    out = buf.getvalue()
-    assert rc == 0
-    assert re.search(r"^dot(_general)?\b", out, re.M), out
-    assert "matmul/conv" in out and "%" in out
-
-
-def test_mfu_flops_accounting_matches_known_matmul():
-    """benchmarks/mfu.py counts FLOPs via XLA cost analysis of the
-    compiled step — pin it against a matmul whose FLOPs are known
-    (2*M*N*K), so the bench's MFU denominator can't silently drift."""
-    import jax
-    import jax.numpy as jnp
-
-    _sys = __import__("sys")
-    _sys.path.insert(0, str(REPO))
-    try:
-        from benchmarks.mfu import flops_of_compiled, mfu, peak_tflops
-    finally:
-        _sys.path.remove(str(REPO))
-
-    M = N = K = 256
-    f = jax.jit(lambda a, b: a @ b)
-    compiled = f.lower(
-        jax.ShapeDtypeStruct((M, K), jnp.float32),
-        jax.ShapeDtypeStruct((K, N), jnp.float32),
-    ).compile()
-    flops = flops_of_compiled(compiled)
-    expected = 2 * M * N * K
-    assert flops is not None
-    assert 0.9 * expected <= flops <= 1.2 * expected, (flops, expected)
-    # mfu: known device kinds produce a ratio, unknown produce None
-    got = mfu(flops, step_time_s=1e-3, device_kind="TPU v5e")
-    assert got is not None and 0 < got < 1e-3
-    assert mfu(flops, 1e-3, "mystery-chip") is None
-    assert peak_tflops("TPU v4") == 275.0
-
-
-def test_bench_ladder_steps_down_only_on_oom():
-    """bench._try_ladder must step down a rung ONLY for OOM-class errors
-    (RESOURCE_EXHAUSTED / out-of-memory), re-raise anything else at the
-    failing rung, and record every skipped rung + reason in the winning
-    rung's extras so the emitted JSON can't hide a silent downgrade."""
-    sys.path.insert(0, REPO)  # bench.py pins REPO on sys.path itself anyway
-    from bench import _try_ladder
-
-    # OOM at 256 steps down; 128 wins and reports the skipped rung
-    def run_oom(b, r):
-        if b == 256:
-            raise RuntimeError("RESOURCE_EXHAUSTED: Out of memory allocating ...")
-        return 100.0 * b, {"batch": b}
-
-    v, extras = _try_ladder([(256, "none"), (128, "none")], run_oom)
-    assert v == 12800.0
-    assert extras["skipped_rungs"][0]["rung"] == [256, "none"]
-    assert "RESOURCE_EXHAUSTED" in extras["skipped_rungs"][0]["error"]
-
-    # a non-OOM failure (shape bug) re-raises immediately — no downgrade
-    def run_bug(b, r):
-        if b == 256:
-            raise ValueError("dot_general shape mismatch")
-        return 100.0 * b, {}
-
-    try:
-        _try_ladder([(256, "none"), (128, "none")], run_bug)
-    except ValueError:
-        pass
-    else:
-        raise AssertionError("non-OOM error must fail the leg loudly")
-
-    # OOM on the LAST rung re-raises too (nothing left to step to)
-    def run_all_oom(b, r):
-        raise RuntimeError("RESOURCE_EXHAUSTED")
-
-    try:
-        _try_ladder([(64, "none")], run_all_oom)
-    except RuntimeError:
-        pass
-    else:
-        raise AssertionError("exhausted ladder must raise")
-
-
 def test_show_pb_inspects_shard_and_checkpoint(tmp_path, capsys):
     """show_pb analog (ref python/paddle/utils/show_pb.py): dumps binary
     shards, checkpoint trees, and merged models."""
@@ -558,108 +459,10 @@ def test_torch2paddle_converts_and_trains(tmp_path):
     np.testing.assert_allclose(ours, theirs, rtol=1e-5, atol=1e-6)
 
 
-def test_pallas_fallback_decorator(monkeypatch):
-    """A leg failing with PADDLE_TPU_BENCH_PALLAS_RNN=1 reruns on the
-    scan path with an honest JSON tag; without the env it fails loudly;
-    the env value is restored either way."""
-    sys.path.insert(0, REPO)
-    from bench import _pallas_fallback
-
-    calls = []
-
-    @_pallas_fallback
-    def leg(**kw):
-        calls.append(os.environ.get("PADDLE_TPU_BENCH_PALLAS_RNN"))
-        if os.environ.get("PADDLE_TPU_BENCH_PALLAS_RNN") == "1":
-            raise RuntimeError("Mosaic lowering failed: vmem exceeded")
-        return 42.0, {"mfu": 0.1}
-
-    monkeypatch.setenv("PADDLE_TPU_BENCH_PALLAS_RNN", "1")
-    v, extras = leg()
-    assert v == 42.0 and calls == ["1", "0"]
-    assert "FELL BACK" in extras["pallas_rnn"] and "Mosaic" in extras["pallas_rnn"]
-    assert os.environ["PADDLE_TPU_BENCH_PALLAS_RNN"] == "1"
-
-    # knob off: failures propagate (no silent downgrade)
-    monkeypatch.setenv("PADDLE_TPU_BENCH_PALLAS_RNN", "0")
-
-    @_pallas_fallback
-    def bad(**kw):
-        raise ValueError("real bug")
-
-    import pytest
-
-    with pytest.raises(ValueError):
-        bad()
-
-
-def test_pallas_fallback_double_failure(monkeypatch):
-    """When the scan-path rerun ALSO fails, the raised error must carry
-    the original pallas diagnosis, and the env flag must still be
-    restored for later legs."""
-    sys.path.insert(0, REPO)
-    import pytest
-
-    from bench import _pallas_fallback
-
-    @_pallas_fallback
-    def leg(**kw):
-        if os.environ.get("PADDLE_TPU_BENCH_PALLAS_RNN") == "1":
-            raise RuntimeError("Mosaic lowering failed")
-        raise ValueError("scan path oom")
-
-    monkeypatch.setenv("PADDLE_TPU_BENCH_PALLAS_RNN", "1")
-    with pytest.raises(RuntimeError) as ei:
-        leg()
-    msg = str(ei.value)
-    assert "scan path oom" in msg and "Mosaic lowering failed" in msg
-    assert os.environ["PADDLE_TPU_BENCH_PALLAS_RNN"] == "1"
-
-
-def test_bench_gen_leg_micro():
-    """bench.py's generation leg wiring: builds the beam-search graph,
-    runs it, and reports best-beam tokens/s with the beam knobs tagged."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    v, extras = bench.bench_nmt_gen(B=2, T=4, vocab=60, dim=32, beam_size=2,
-                                    max_length=5, steps=2, warmup=1,
-                                    dtype="float32")
-    assert v > 0
-    assert extras["beam_size"] == 2 and extras["max_length"] == 5
-    assert extras["tokens"] == "best-beam generated"
-
-
-def test_resnet_ladder_order_plain_before_remat(monkeypatch):
-    """All plain-batch rungs must precede any remat rung: if 512/none
-    OOMs, the known-good 256/none wins the headline — never a 512/full
-    whose +33% recompute would swap mfu for hw_flops_util."""
-    sys.path.insert(0, REPO)
-    import bench
-
-    seen = []
-
-    def fake_try_ladder(configs, run_one):
-        seen.extend(configs)
-        return 1.0, {}
-
-    monkeypatch.setattr(bench, "_try_ladder", fake_try_ladder)
-    monkeypatch.setattr(bench, "_jit_train_step",
-                        lambda *a, **k: (_ for _ in ()).throw(AssertionError))
-    bench.bench_resnet50()
-    kinds = [r for _, r in seen]
-    assert kinds == ["none"] * 4 + ["full"] * 4, seen
-    # 256 leads: measured 2026-08-01 batch A/B (2201 imgs/s at 256 vs
-    # 2082 at 512, 1957 at 768)
-    assert [b for b, _ in seen][:4] == [256, 512, 128, 64], seen
-
-
-
-
-def _run_bench_with(patch, leg):
-    """`python bench.py <leg>` in a child, on the CPU, with one leg
-    function replaced first."""
-    code = (f"import sys; sys.argv = ['bench.py', {leg!r}]\n"
+def _run_bench_with(patch, *argv):
+    """`python bench.py <argv>` in a child, on the CPU, with `patch`
+    applied to the module first."""
+    code = (f"import sys; sys.argv = ['bench.py', *{list(argv)!r}]\n"
             f"import bench\n{patch}\nsys.exit(bench.main())\n")
     return subprocess.run(
         [sys.executable, "-c", code], cwd=REPO, capture_output=True,
@@ -673,26 +476,38 @@ def test_bench_exits_nonzero_when_a_leg_raises():
     never a retry on another backend."""
     r = _run_bench_with(
         "def boom(**kw):\n    raise RuntimeError('leg exploded')\n"
-        "bench.bench_sparse = boom", "sparse")
+        "bench.bench_serve = boom", "serve")
     assert r.returncode == 1, (r.returncode, r.stderr[-2000:])
     assert "RuntimeError: leg exploded" in r.stderr
     assert not [l for l in r.stdout.splitlines() if l.startswith("{")]
 
 
-def test_bench_line_names_the_device_it_ran_on():
+def test_bench_line_names_the_device_it_ran_on(tmp_path):
     """Every result line carries platform / device_kind / device_count as
     jax reports them, and no field from an earlier run."""
     r = _run_bench_with(
-        "bench.bench_sparse = lambda **kw: (123.0, {'vocab': kw['V']})",
-        "sparse")
+        "bench.bench_serve = lambda **kw: "
+        f"(123.0, {{'dtype': kw['dtype'], 'run_dir': {str(tmp_path)!r}}})",
+        "serve")
     assert r.returncode == 0, r.stderr[-2000:]
     (line,) = [json.loads(l) for l in r.stdout.splitlines()
                if l.startswith("{")]
-    assert line["metric"] == "sparse_cpu_smoke_rows_per_sec"
-    assert line["value"] == 123.0 and line["vocab"] == 20_000
+    assert line["metric"] == "serve_cpu_smoke_goodput_tokens_per_sec"
+    assert line["value"] == 123.0 and line["dtype"] == "float32"
+    assert line["unit"] == "tokens/s"
     assert (line["platform"], line["device_kind"]) == ("cpu", "cpu")
     assert line["device_count"] >= 1
-    assert "last_measured" not in line
+    assert "last_measured" not in line and "backend" not in line
+
+
+@pytest.mark.parametrize("argv", [("nmt",), ()], ids=["nmt", "no-argument"])
+def test_bench_takes_only_the_serve_leg(argv):
+    """`serve` is the one leg left: anything else, or nothing, exits 2
+    with no result line and names the benchmark for training speed."""
+    r = _run_bench_with("bench.bench_serve = None", *argv)
+    assert r.returncode == 2, (r.returncode, r.stderr[-2000:])
+    assert '{"metric"' not in r.stdout
+    assert "perfbench.run" in r.stderr
 
 
 def test_bench_has_no_harness_that_hides_the_device():

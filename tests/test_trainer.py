@@ -94,7 +94,7 @@ def test_resume_from_checkpoint(tmp_path):
 
 def test_checkgrad_job(tmp_path):
     cfg = parse_config(lr_config(tmp_path))
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = str(tmp_path / "model")
     FLAGS.start_pass = 0
     FLAGS.init_model_path = ""
     trainer = Trainer(cfg)
@@ -120,7 +120,7 @@ def test_lstm_sequence_trains(tmp_path):
     cfg_path = tmp_path / "lstm_config.py"
     cfg_path.write_text(src)
     cfg = parse_config(str(cfg_path))
-    FLAGS.save_dir = ""
+    FLAGS.save_dir = str(tmp_path / "model")
     FLAGS.log_period = 0
     FLAGS.start_pass = 0
     trainer = Trainer(cfg)
@@ -186,7 +186,6 @@ def test_multi_pass_test_job(tmp_path, caplog):
                            f"--save_dir={tmp_path / 'out'}",
                            "--num_passes=3", "--test_pass=0"])
     finally:
-        FLAGS.test_pass = -1
         ptu_logger.removeHandler(caplog.handler)
     assert rc == 0
     # all three saved passes actually evaluated

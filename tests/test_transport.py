@@ -299,6 +299,15 @@ def test_reconnect_hello_answer_arrives_exactly_once():
     once, no re-submit."""
     eng = _FakeEngine()
     srv = EngineSocketServer(eng, "127.0.0.1:0")
+    hellos = []
+    handle = srv._handle
+
+    def handle_and_note_hello(doc, wire):
+        handle(doc, wire)
+        if doc.get("op") == "hello":
+            hellos.append(doc)
+
+    srv._handle = handle_and_note_hello
     srv.start()
     try:
         got = []
@@ -313,8 +322,13 @@ def test_reconnect_hello_answer_arrives_exactly_once():
         # sever the wire server-side: the client must reconnect
         with srv._lock:
             conn = srv._conn
+        n_hellos = len(hellos)
         transport._close_wire(conn)
         _wait_for(lambda: t.reconnects >= 1, msg="reconnect")
+        # the request must still be in flight when the server reads the
+        # reconnect's hello: answered first, the hello would (rightly,
+        # the wire is at-least-once) have it sent a second time
+        _wait_for(lambda: len(hellos) > n_hellos, msg="hello after reconnect")
         eng.subs["h0"][0].resolve(_Res())
         _wait_for(lambda: len(got) >= 1, msg="answer after reconnect")
         cc.sleep(0.2)  # absorb any (wrong) duplicate delivery
