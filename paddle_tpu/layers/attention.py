@@ -20,7 +20,11 @@ rotary embedding (rotate-half, ``rope_theta``) at the positions the mask
 rule gives each index; and the mask is a rule over positions
 (``attention_mask``: full | causal | block_diffusion, `ops/attention_mask.py`)
 shared by the XLA path and the Pallas kernel. Device time splits into the
-scopes ``qkv``, ``core`` (scores, softmax, values) and ``out``.
+scopes ``qkv`` (the three products, and the head prologue of q and k: norm,
+turn and the scores' scale in one pass each way under a hand-written
+backward, `ops/pallas_head_prologue.py`, the kernels ``head_prologue_fwd``
+/ ``head_prologue_bwd`` where a kernel can run), ``core`` (scores, softmax,
+values) and ``out``.
 """
 
 from __future__ import annotations
@@ -85,40 +89,14 @@ def multi_head_attention(cfg: LayerConfig, inputs: List[Argument], ctx: LayerCon
 def rms_normalize(x: Array, gain: Array, eps: float) -> Array:
     """x / sqrt(mean(x^2) + eps) * gain over the last axis: statistics in
     float32, the result in x's dtype."""
-    return _rms(hp(x), gain, eps).astype(x.dtype)
-
-
-def _rms(xf: Array, gain: Array, eps: float) -> Array:
-    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return xf * inv * hp(gain)
-
-
-def _rotary(xf: Array, positions: Array, theta: float) -> Array:
-    """Rotary embedding of float32 [B, T, H, Dh] at integer ``positions``
-    [T], rotate-half convention: the two halves of a head are the pairs."""
-    half = xf.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]   # [T, half]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _head_prologue(x: Array, gain, eps: float, positions, theta: float, scale: float) -> Array:
-    """A projection's heads made ready for the scores, in float32 and
-    rounded ONCE: the per-head RMS norm (``gain`` None: none), the rotary
-    turn (``theta`` 0: none), then ``scale`` (the scores' 1/sqrt(Dh) folded
-    into q, so that the kernel multiplies no score)."""
     xf = hp(x)
-    if gain is not None:
-        xf = _rms(xf, gain, eps)
-    if theta:
-        xf = _rotary(xf, positions, theta)
-    return (xf * scale if scale != 1.0 else xf).astype(x.dtype)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (xf * inv * hp(gain)).astype(x.dtype)
 
 
 def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext) -> Argument:
     from paddle_tpu.ops.attention_mask import rule_of
+    from paddle_tpu.ops.pallas_head_prologue import head_prologue, turn_tables
     from paddle_tpu.parallel.sequence_parallel import rule_attention
 
     if ctx.mesh is not None and cfg.seq_parallel_mode:
@@ -133,14 +111,19 @@ def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext)
     rule = rule_of(cfg.attention_mask, cfg.mask_block_length, cfg.causal_attention)
     rule.check(T)
     with jax.named_scope("qkv"):
-        q = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wq")).reshape(B, T, H, Dh)
-        k = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wk")).reshape(B, T, Hkv, Dh)
+        q = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wq"))
+        k = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wk"))
         v = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wv")).reshape(B, T, Hkv, Dh)
         gains = [ctx.param(f"_{cfg.name}.{n}", cast=False)[0] if cfg.qk_norm else None
                  for n in ("q_norm", "k_norm")]
         pos = rule.positions(jnp.arange(T, dtype=jnp.int32), T)
-        q = _head_prologue(q, gains[0], cfg.norm_epsilon, pos, cfg.rope_theta, Dh ** -0.5)
-        k = _head_prologue(k, gains[1], cfg.norm_epsilon, pos, cfg.rope_theta, 1.0)
+        turn = turn_tables(pos, cfg.rope_theta, Dh) if cfg.rope_theta else None
+        # the scores' 1/sqrt(Dh) is folded into q: the kernel multiplies no
+        # score. The prologue leaves [B, H, T, Dh], as the flash kernels read
+        # it; relabelled to rule_attention's [B, T, H, Dh] here, its Pallas
+        # path's own transpose undoes this one and neither moves anything
+        q = head_prologue(q, gains[0], turn, Dh, cfg.norm_epsilon, Dh ** -0.5).transpose(0, 2, 1, 3)
+        k = head_prologue(k, gains[1], turn, Dh, cfg.norm_epsilon, 1.0).transpose(0, 2, 1, 3)
     with jax.named_scope("core"):
         out = rule_attention(q, k, v, arg.seq_lengths, rule, scale=1.0)
     with jax.named_scope("out"):

@@ -1,0 +1,266 @@
+"""The grouped-query head prologue: per-head RMS norm, rotary turn and
+scale of a projection's heads, one pass over the data each way.
+
+What it computes for every head row ``x`` of ``Dh`` (float32 statistics
+and arithmetic inside a pass, rounded ONCE to the input's dtype)::
+
+    n = x * rsqrt(mean(x^2) + eps) * gain          (gain None: n = x)
+    y = (n * C + roll(n, Dh/2) * S) * scale        (tables None: y = n * scale)
+
+with ``C = [cos, cos]`` and ``S = [-sin, sin]`` at the row's position: the
+rotate-half turn as ONE roll of the whole head row and two multiplies, no
+half-row slices and no concatenate. The turn is orthogonal, so the
+backward turns the cotangent by the opposite angle, and the norm's
+backward is its closed form; autodiff sees neither::
+
+    dn = dy * scale * C - roll(dy * scale, Dh/2) * S
+    u = x * inv,  g = dn * gain
+    dx = inv * (g - u * mean(g * u)),   d gain = sum over rows of dn * u
+
+:func:`head_prologue` carries that backward (`jax.custom_vjp`) and keeps
+``x`` alone for it. The mathematics is written once (`_forward_rows`,
+`_backward_rows`); where a Pallas kernel can run (`device.pallas_mode`)
+the two passes are the kernels ``head_prologue_fwd`` / ``head_prologue_bwd``
+over row tiles, else the same functions over the whole array under XLA.
+
+Layout: x and dx are the projection as the product leaves it, ``[B, T,
+H*Dh]`` (a head is a 128-lane column block, a position a sublane row, so
+the tables apply to a head's slab as they are); y and dy are ``[B, H, T,
+Dh]``, as the flash kernels of `ops/pallas_attention.py` read q and k and
+write their cotangents, so no transpose of either is left in the program
+(XLA made the relayout of a ``[B, T, H*Dh]`` result two copies a pass).
+Correctness is tested on the CPU, both ways
+(tests/test_block_diffusion_moe.py); what Mosaic accepts, by compiling for
+a described v5e (tests/test_chip_compile.py).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+Array = jax.Array
+F32 = jnp.float32
+
+_LANES = 128
+# a tile: at most this many heads (a kernel's body is written out once a
+# head, and tracing and lowering 32 of them a call, six calls a layer, cost
+# a process 8 s of set-up where 4 cost one) by rows up to this many bytes of
+# one operand (double-buffered; the backward has three), and the scoped VMEM
+# the kernels may use
+_TILE_HEADS = 4
+_TILE_BYTES = 2 * 1024 * 1024
+_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def turn_tables(positions: Array, theta: float, head_dim: int) -> Tuple[Array, Array]:
+    """``(C, S)``, float32 ``[T, Dh]``: ``[cos, cos]`` and ``[-sin, sin]``
+    of the rotate-half angles at integer ``positions`` [T]."""
+    half = head_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=F32) / half)
+    ang = positions.astype(F32)[:, None] * inv_freq[None, :]              # [T, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1)
+
+
+# ------------------------------------------------- the mathematics, once
+
+
+def _inv_rms(xf, eps):
+    return jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+
+
+def _forward_rows(xf, gain, c, s, eps, scale, roll):
+    """float32 head rows ``[..., Dh]`` -> float32 y. ``gain``, ``c`` and
+    ``s`` broadcast against the rows; ``roll`` turns a row by Dh/2."""
+    if gain is not None:
+        xf = xf * _inv_rms(xf, eps) * gain
+    if c is not None:
+        xf = xf * c + roll(xf) * s
+    return xf if scale == 1.0 else xf * scale
+
+
+def _backward_rows(xf, d, gain, c, s, eps, scale, roll):
+    """float32 head rows and their cotangent -> (dx, dn * u); the second
+    is None without a norm, else what sums over rows to ``d gain``."""
+    if scale != 1.0:
+        d = d * scale
+    if c is not None:
+        d = d * c - roll(d) * s
+    if gain is None:
+        return d, None
+    inv = _inv_rms(xf, eps)
+    u = xf * inv
+    du = d * u
+    g_u = du * gain                                                       # g * u
+    return inv * (d * gain - u * jnp.mean(g_u, axis=-1, keepdims=True)), du
+
+
+# ------------------------------------------------------------ the kernels
+
+
+def _tiling(T: int, width: int, head_dim: int, itemsize: int) -> Tuple[int, int]:
+    """(rows, lanes) of a tile of a ``[B, T, width]`` projection: as many
+    whole heads as divide the projection's, ``_TILE_HEADS`` at most, by the
+    largest power of two of rows from 1024 down to 16 that divides T and
+    keeps the tile within ``_TILE_BYTES``; rows 0 where none does."""
+    heads = width // head_dim
+    lanes = head_dim * next(n for n in range(min(heads, _TILE_HEADS), 0, -1) if heads % n == 0)
+    rows = next((rows for rows in (1024, 512, 256, 128, 64, 32, 16)
+                 if T % rows == 0 and rows * lanes * itemsize <= _TILE_BYTES), 0)
+    return rows, lanes
+
+
+def supported(T: int, width: int, head_dim: int, itemsize: int) -> bool:
+    """Whether the kernels take a ``[B, T, width]`` projection of
+    ``head_dim`` heads: a head is whole lane tiles, and T tiles."""
+    return (head_dim % _LANES == 0 and width % head_dim == 0
+            and _tiling(T, width, head_dim, itemsize)[0] > 0)
+
+
+def _unpack(refs, has_gain, has_turn):
+    """(gain, C, S: values or None; the other refs). A head's whole
+    ``[rows, Dh]`` slab is one value: a loop over fewer rows at a time only
+    adds its own latency (on a v5e, 32 rows a trip: 1.8 ms a pass of q
+    against 0.9)."""
+    refs = list(refs)
+    gain = refs.pop(0)[...] if has_gain else None                         # [1, Dh]
+    c, s = (refs.pop(0)[...], refs.pop(0)[...]) if has_turn else (None, None)   # [rows, Dh]
+    return gain, c, s, refs
+
+
+def _fwd_kernel(*refs, head_dim, eps, scale, has_gain, has_turn):
+    gain, c, s, (x_ref, y_ref) = _unpack(refs, has_gain, has_turn)
+    roll = lambda a: pltpu.roll(a, head_dim // 2, 1)
+    for h in range(x_ref.shape[2] // head_dim):
+        xf = x_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(F32)
+        y_ref[0, h] = _forward_rows(xf, gain, c, s, eps, scale, roll).astype(y_ref.dtype)
+
+
+def _bwd_kernel(*refs, head_dim, eps, scale, has_gain, has_turn):
+    gain, c, s, (x_ref, dy_ref, dx_ref, *d_gain_ref) = _unpack(refs, has_gain, has_turn)
+    roll = lambda a: pltpu.roll(a, head_dim // 2, 1)
+    acc = 0.0
+    for h in range(x_ref.shape[2] // head_dim):
+        lanes = slice(h * head_dim, (h + 1) * head_dim)
+        dx, du = _backward_rows(x_ref[0, :, lanes].astype(F32), dy_ref[0, h].astype(F32),
+                                gain, c, s, eps, scale, roll)
+        dx_ref[0, :, lanes] = dx.astype(dx_ref.dtype)
+        if has_gain:
+            acc = acc + du
+    if has_gain:
+        d_gain_ref[0][0, 0, 0] = jnp.sum(acc, axis=0, keepdims=True)      # this tile's d gain
+
+
+def _call(kernel, name, x, dy, gain, tables, head_dim, eps, scale, interpret):
+    """One pass in tiles of some rows by a few heads: the forward kernel
+    (``dy`` None) reads the projection ``x`` [B, T, width] and writes y
+    [B, H, T, Dh]; the backward kernel reads x and ``dy`` and writes dx
+    and, under a norm, each tile's ``d gain`` (one ``[1, Dh]`` a tile). The
+    grid walks the row tiles outermost, so a tile's tables stay in VMEM for
+    all its head groups and sequences."""
+    B, T, width = x.shape
+    rows, lanes = _tiling(T, width, head_dim, x.dtype.itemsize)
+    grid = (T // rows, width // lanes, B)
+    flat = (jax.ShapeDtypeStruct(x.shape, x.dtype),
+            pl.BlockSpec((1, rows, lanes), lambda j, g, b: (b, j, g)))
+    by_head = (jax.ShapeDtypeStruct((B, width // head_dim, T, head_dim), x.dtype),
+               pl.BlockSpec((1, lanes // head_dim, rows, head_dim), lambda j, g, b: (b, g, j, 0)))
+    operands, specs = [], []
+    if gain is not None:
+        operands.append(gain.astype(F32).reshape(1, head_dim))
+        specs.append(pl.BlockSpec((1, head_dim), lambda j, g, b: (0, 0)))
+    if tables is not None:
+        operands += list(tables)
+        specs += [pl.BlockSpec((rows, head_dim), lambda j, g, b: (j, 0))] * 2
+    if dy is None:
+        tiled, outs = [(x, flat[1])], [by_head]
+    else:
+        tiled, outs = [(x, flat[1]), (dy, by_head[1])], [flat]
+        if gain is not None:
+            outs.append((jax.ShapeDtypeStruct(grid + (1, head_dim), F32),
+                         pl.BlockSpec((1, 1, 1, 1, head_dim), lambda j, g, b: (j, g, b, 0, 0))))
+    return pl.pallas_call(
+        functools.partial(kernel, head_dim=head_dim, eps=eps, scale=scale,
+                          has_gain=gain is not None, has_turn=tables is not None),
+        name=name, grid=grid,
+        in_specs=specs + [spec for _, spec in tiled],
+        out_specs=[spec for _, spec in outs], out_shape=[shape for shape, _ in outs],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+    )(*operands, *(a for a, _ in tiled))
+
+
+# ------------------------------------------------------------ the function
+
+
+def _whole(x, gain, tables, head_dim):
+    """The XLA path's view of the operands: the rows' shape ``[B, T, H,
+    Dh]``, the gain float32, the tables ``[1, T, 1, Dh]``, and the roll
+    along the last axis."""
+    B, T, width = x.shape
+    c, s = (None, None) if tables is None else (t[None, :, None, :] for t in tables)
+    roll = lambda a: jnp.roll(a, head_dim // 2, axis=-1)
+    return ((B, T, width // head_dim, head_dim),
+            None if gain is None else gain.astype(F32), c, s, roll)
+
+
+def head_prologue(x: Array, gain: Optional[Array], tables, head_dim: int,
+                  eps: float, scale: float) -> Array:
+    """A projection ``x`` [B, T, H*Dh], as the product leaves it, with its
+    heads made ready for the scores (module docstring): ``[B, H, T, Dh]``,
+    as the flash kernels read them, in x's dtype. ``gain`` [Dh] or None (no
+    norm); ``tables`` from :func:`turn_tables` or None (no turn). The
+    kernels run where `device.pallas_mode` has a way to run them and
+    :func:`supported` admits the shape."""
+    from paddle_tpu.utils import device
+
+    mode = device.pallas_mode()
+    if not supported(x.shape[1], x.shape[2], head_dim, x.dtype.itemsize):
+        mode = None
+    return _prologue(x, gain, tables, head_dim, eps, scale, mode)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _prologue(x, gain, tables, head_dim, eps, scale, mode):
+    """``mode`` "compiled" / "interpret": the kernels; None: the same
+    mathematics over the whole array under XLA."""
+    if mode is not None:
+        return _call(_fwd_kernel, "head_prologue_fwd", x, None, gain, tables,
+                     head_dim, eps, scale, mode == "interpret")[0]
+    shape, g, c, s, roll = _whole(x, gain, tables, head_dim)
+    y = _forward_rows(x.astype(F32).reshape(shape), g, c, s, eps, scale, roll)
+    return y.astype(x.dtype).transpose(0, 2, 1, 3)
+
+
+def _vjp_fwd(x, gain, tables, head_dim, eps, scale, mode):
+    return _prologue(x, gain, tables, head_dim, eps, scale, mode), (x, gain, tables)
+
+
+def _vjp_bwd(head_dim, eps, scale, mode, kept, dy):
+    x, gain, tables = kept
+    if mode is not None:
+        dx, *du = _call(_bwd_kernel, "head_prologue_bwd", x, dy, gain, tables,
+                        head_dim, eps, scale, mode == "interpret")
+        du = du[0] if du else None
+    else:
+        shape, g, c, s, roll = _whole(x, gain, tables, head_dim)
+        dx, du = _backward_rows(x.astype(F32).reshape(shape),
+                                dy.astype(F32).transpose(0, 2, 1, 3),
+                                g, c, s, eps, scale, roll)
+        dx = dx.reshape(x.shape).astype(x.dtype)
+    d_gain = None if gain is None else (
+        jnp.sum(du.reshape(-1, head_dim), axis=0).astype(gain.dtype))
+    # the tables come from integer positions: nothing to hand back
+    d_tables = None if tables is None else tuple(jnp.zeros_like(t) for t in tables)
+    return dx, d_gain, d_tables
+
+
+_prologue.defvjp(_vjp_fwd, _vjp_bwd)

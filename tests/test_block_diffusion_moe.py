@@ -10,6 +10,7 @@ width 32 with 2 a token, 2 layers, L = 32, block 4, vocabulary 97.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -154,6 +155,99 @@ def test_old_multi_head_attention_unchanged():
                          lengths=jnp.full((2,), 24, jnp.int32), causal=True)
     want = jnp.einsum("bte,ed->btd", out.reshape(2, 24, 32), wo)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ------------------------------------------------------- the head prologue
+
+
+def _written_out_prologue(x, gain, positions, theta, scale, eps=1e-6):
+    """What the layer computed before `ops/pallas_head_prologue.py`, in
+    plain `jax.numpy` for autodiff: float32 RMS norm over each head,
+    rotate-half rotary turn by half-row slices and a concatenate, the
+    scale, one rounding to x's dtype. x [B, T, H, Dh]."""
+    xf = x.astype(jnp.float32)
+    if gain is not None:
+        xf = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps) * gain
+    if theta:
+        half = xf.shape[-1] // 2
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+        cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+        x1, x2 = xf[..., :half], xf[..., half:]
+        xf = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return (xf * scale).astype(x.dtype)
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("norm,turn", [(True, True), (True, False), (False, True), (False, False)],
+                         ids=["norm+turn", "norm", "turn", "scale"])
+def test_head_prologue_matches_the_written_out_rule(norm, turn, dtype, path, monkeypatch):
+    """`head_prologue` (one pass each way, a hand-written backward) against
+    the rule written out under `jax.grad`: the value, dx and d gain, with
+    and without the norm and the turn, through the XLA path and through the
+    kernels (interpreted), at positions that repeat as the block-diffusion
+    rule's do. float32 agrees to 1e-5 of the largest entry, bfloat16 to one
+    rounding of the result."""
+    from paddle_tpu.ops.pallas_head_prologue import head_prologue, turn_tables
+
+    if path == "kernel":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    B, T, H, Dh = 2, 64, 4, 128
+    rng = np.random.RandomState(31)
+    x = jnp.asarray(3.0 * rng.randn(B, T, H, Dh), dtype)
+    w = jnp.asarray(rng.randn(B, T, H, Dh), jnp.float32)
+    gain = jnp.asarray(1.0 + 0.1 * rng.randn(Dh), jnp.float32) if norm else None
+    positions = jnp.tile(jnp.arange(T // 2, dtype=jnp.int32), 2)
+    theta, scale = (1e6 if turn else 0.0), Dh ** -0.5
+
+    def new(x, gain):
+        tables = turn_tables(positions, theta, Dh) if theta else None
+        y = head_prologue(x.reshape(B, T, H * Dh), gain, tables, Dh, 1e-6, scale)
+        assert y.shape == (B, H, T, Dh) and y.dtype == x.dtype
+        return y.transpose(0, 2, 1, 3)
+
+    old = lambda x, gain: _written_out_prologue(x, gain, positions, theta, scale)
+    loss = lambda f: (lambda x, gain: jnp.sum(f(x, gain).astype(jnp.float32) * w))
+    wrt = (0, 1) if norm else (0,)
+    want = (old(x, gain), *jax.grad(loss(old), argnums=wrt)(x, gain))
+    got = (new(x, gain), *jax.grad(loss(new), argnums=wrt)(x, gain))
+    for name, a, b in zip(("y", "dx", "d gain"), got, want):
+        assert a.dtype == b.dtype, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype == "bfloat16" and name != "d gain":
+            # both round a float32 result once: a bfloat16 step apart at most
+            np.testing.assert_array_less(np.abs(a - b), 2.0 ** -7 * np.abs(b) + 1e-30, err_msg=name)
+        else:
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * np.abs(b).max(), err_msg=name)
+
+
+def test_the_prologue_runs_under_the_layers_qkv_scope(monkeypatch):
+    """Where the reader of `attention_ms.train` and the by-scope table
+    looks: in the optimized program of a gradient step (the demo's two
+    blocks at a head of 128, the kernels interpreted), every instruction of
+    the prologue's forward, its recomputation and its hand-written backward
+    carries an `op_name` under `multi_head_attention:<name>/qkv` (a
+    `custom_vjp`'s backward takes its scopes from the call site)."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    gm = _demo_machine("seq_len=16,heads=2,kv_heads=1,head_dim=128")
+    params = gm.init_params(seed=3)
+    rng = np.random.RandomState(12)
+    lens = lambda t: jnp.full((2,), t, jnp.int32)
+    batch = {
+        "tokens": Argument(ids=jnp.asarray(rng.randint(0, 97, (2, 32)), jnp.int32), seq_lengths=lens(32)),
+        "labels": Argument(ids=jnp.asarray(rng.randint(0, 96, (2, 16)), jnp.int32), seq_lengths=lens(16)),
+        "weights": Argument(value=jnp.asarray(rng.rand(2, 16, 1).astype(np.float32)), seq_lengths=lens(16)),
+    }
+    hlo = jax.jit(gm.grad_fn("block")).lower(params, batch, None).compile().as_text()
+    names = re.findall(r'op_name="([^"]*head_prologue_[a-z]+[^"]*)"', hlo)
+    under = re.compile(r"multi_head_attention:l[01]_attn\)*/qkv/head_prologue_(fwd|bwd)/")
+    assert names and all(under.search(n) for n in names), [n for n in names if not under.search(n)][:3]
+    seen = {(under.search(n).group(1), "rematted_computation" in n, "transpose(" in n) for n in names}
+    # the forward, its recomputation inside the backward pass, the backward
+    assert {("fwd", False, False), ("fwd", True, True), ("bwd", False, True)} <= seen, seen
 
 
 # ------------------------------------------------------- the expert layer

@@ -135,6 +135,18 @@ def _expert_ffn(N=4096, D=2048, F=768, held=16, k=8):
     return fn, shapes, True
 
 
+def _head_prologue(heads, B=4, T=8192, Dh=128):
+    """The grouped-query head prologue's two kernels
+    (`ops/pallas_head_prologue.py`) at the block-diffusion cell's shapes: 4
+    sequences of 8,192 positions, the 32 query heads or the 4 key heads of
+    128, norm and turn on, bfloat16."""
+    from paddle_tpu.ops import pallas_head_prologue as hp
+
+    shapes = [((B, T, heads * Dh), BF16), ((Dh,), F32), ((T, Dh), F32), ((T, Dh), F32)]
+    fn = lambda x, gain, c, s: hp._prologue(x, gain, (c, s), Dh, 1e-6, Dh ** -0.5, "compiled")
+    return fn, shapes, hp.supported(T, heads * Dh, Dh, 2)
+
+
 def _conv1x1(M, K, N, dtype):
     from paddle_tpu.ops import pallas_conv1x1_bn as pcb
 
@@ -166,6 +178,8 @@ CASES = {
     "rule-attention-block-diffusion-t8192": lambda: _rule_attention("block_diffusion"),
     "rule-attention-causal-t8192": lambda: _rule_attention("causal"),
     "expert-ffn-16x768": _expert_ffn,
+    "head-prologue-q-32x128": lambda: _head_prologue(32),
+    "head-prologue-k-4x128": lambda: _head_prologue(4),
     # a ResNet-50 1x1 at B=256: stage-1 expand, 56x56 pixels, 64 -> 256
     "conv1x1-bf16": lambda: _conv1x1(256 * 56 * 56, 64, 256, BF16),
 }
