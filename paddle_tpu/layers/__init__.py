@@ -16,5 +16,6 @@ import paddle_tpu.layers.misc  # noqa: F401
 import paddle_tpu.layers.structured  # noqa: F401
 import paddle_tpu.layers.attention  # noqa: F401
 import paddle_tpu.layers.moe  # noqa: F401
+import paddle_tpu.layers.gated_mlp  # noqa: F401
 
 __all__ = ["LayerContext", "layer_registry", "register_layer", "forward_layer"]

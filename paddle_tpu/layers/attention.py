@@ -17,14 +17,21 @@ query heads over ``num_kv_heads`` key/value heads of ``head_dim`` each
 ``.wk`` / ``.wv`` [D, Hkv·Dh] and ``.wo`` [H·Dh, size]; optionally an RMS
 norm over each head's q and k (``.q_norm`` / ``.k_norm`` [1, Dh]) and a
 rotary embedding (rotate-half, ``rope_theta``) at the positions the mask
-rule gives each index; and the mask is a rule over positions
-(``attention_mask``: full | causal | block_diffusion, `ops/attention_mask.py`)
-shared by the XLA path and the Pallas kernel. Device time splits into the
-scopes ``qkv`` (the three products, and the head prologue of q and k: norm,
-turn and the scores' scale in one pass each way under a hand-written
-backward, `ops/pallas_head_prologue.py`, the kernels ``head_prologue_fwd``
-/ ``head_prologue_bwd`` where a kernel can run), ``core`` (scores, softmax,
-values) and ``out``.
+rule gives each index, over the whole head or its first ``rotary_dim``
+lanes, with YaRN's frequencies (``rope_yarn``) and a factor on cos and sin
+(``rope_attention_factor``) where the config gives them; and the mask is a
+rule over positions (``attention_mask``: full | causal | sliding_window
+with ``mask_window`` | block_diffusion, `ops/attention_mask.py`) shared by
+the XLA path and the Pallas kernel. ``output_gate``: each head's result is
+multiplied, before ``wo``, by one number a head a position, the sigmoid (in
+float32) of a projection of the layer's input, ``_<name>.wg`` [D, H] (the
+head-wise gate of "Gated Attention for Large Language Models",
+arXiv:2505.06708). Device time splits into the scopes ``qkv`` (the three
+products, and the head prologue of q and k: norm, turn and the scores'
+scale in one pass each way under a hand-written backward,
+`ops/pallas_head_prologue.py`, the kernels ``head_prologue_fwd`` /
+``head_prologue_bwd`` where a kernel can run), ``core`` (scores, softmax,
+values), ``gate`` and ``out``.
 """
 
 from __future__ import annotations
@@ -108,7 +115,8 @@ def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext)
     H, Dh = cfg.num_heads, cfg.head_dim
     Hkv = cfg.num_kv_heads or H
     assert H % Hkv == 0, f"{cfg.name}: {H} query heads over {Hkv} key/value heads"
-    rule = rule_of(cfg.attention_mask, cfg.mask_block_length, cfg.causal_attention)
+    rule = rule_of(cfg.attention_mask, cfg.mask_block_length, cfg.causal_attention,
+                   cfg.mask_window)
     rule.check(T)
     with jax.named_scope("qkv"):
         q = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wq"))
@@ -117,15 +125,22 @@ def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext)
         gains = [ctx.param(f"_{cfg.name}.{n}", cast=False)[0] if cfg.qk_norm else None
                  for n in ("q_norm", "k_norm")]
         pos = rule.positions(jnp.arange(T, dtype=jnp.int32), T)
-        turn = turn_tables(pos, cfg.rope_theta, Dh) if cfg.rope_theta else None
+        rot = cfg.rotary_dim or Dh
+        turn = turn_tables(pos, cfg.rope_theta, Dh, rot, tuple(cfg.rope_yarn) or None,
+                           cfg.rope_attention_factor) if cfg.rope_theta else None
         # the scores' 1/sqrt(Dh) is folded into q: the kernel multiplies no
         # score. The prologue leaves [B, H, T, Dh], as the flash kernels read
         # it; relabelled to rule_attention's [B, T, H, Dh] here, its Pallas
         # path's own transpose undoes this one and neither moves anything
-        q = head_prologue(q, gains[0], turn, Dh, cfg.norm_epsilon, Dh ** -0.5).transpose(0, 2, 1, 3)
-        k = head_prologue(k, gains[1], turn, Dh, cfg.norm_epsilon, 1.0).transpose(0, 2, 1, 3)
+        q = head_prologue(q, gains[0], turn, Dh, cfg.norm_epsilon, Dh ** -0.5, rot).transpose(0, 2, 1, 3)
+        k = head_prologue(k, gains[1], turn, Dh, cfg.norm_epsilon, 1.0, rot).transpose(0, 2, 1, 3)
     with jax.named_scope("core"):
         out = rule_attention(q, k, v, arg.seq_lengths, rule, scale=1.0)
+    if cfg.output_gate:
+        with jax.named_scope("gate"):
+            g = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", x, ctx.param(f"_{cfg.name}.wg"),
+                                          preferred_element_type=jnp.float32))
+            out = (out.astype(jnp.float32) * g[..., None]).astype(out.dtype)
     with jax.named_scope("out"):
         value = jnp.einsum("bte,ed->btd", out.reshape(B, T, H * Dh),
                            ctx.param(f"_{cfg.name}.wo"))

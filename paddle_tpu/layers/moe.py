@@ -6,7 +6,11 @@ A TPU extension beyond the 2016 reference. Every token is routed to
     r   = softmax(x Wr) over all the experts, in float32
     S   = the experts_per_token largest of r
     w_e = r_e / sum_{e' in S} r_e'        (norm_topk_prob; else w_e = r_e)
-    y   = sum_{e in S, held here} w_e * (silu(x Wg_e) * (x Wu_e)) Wd_e
+    y   = f * sum_{e in S, held here} w_e * (silu(x Wg_e) * (x Wu_e)) Wd_e
+
+with ``f`` the ``routed_scaling_factor`` (1 by default). A shared expert,
+which every token passes through, is no part of this layer: it is a
+``gated_mlp`` layer beside it (`layers/gated_mlp.py`), joined in the config.
 
 The program HOLDS a contiguous range of the experts (`experts_held_first`,
 `experts_held_count`; all of them by default): it routes over all of them,
@@ -66,6 +70,8 @@ def moe_layer(cfg: LayerConfig, inputs: List[Argument], ctx: LayerContext) -> Ar
         probs = jax.nn.softmax(logits, axis=-1)
         top, chosen = jax.lax.top_k(probs, k)
         weight = top / jnp.sum(top, axis=-1, keepdims=True) if cfg.norm_topk_prob else top
+        if cfg.routed_scaling_factor != 1.0:
+            weight = weight * cfg.routed_scaling_factor
     with jax.named_scope("dispatch"):
         routing = route(chosen.astype(jnp.int32), first, count)
     y = expert_ffn(xf, weight, ctx.param(f"_{cfg.name}.gate"),

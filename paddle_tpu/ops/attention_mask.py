@@ -17,6 +17,8 @@ Kinds:
 
 - ``full``: every pair.
 - ``causal``: ``k <= q``.
+- ``sliding_window`` (with ``window``): ``k <= q and q - k < window``: a
+  query sees itself and the ``window - 1`` positions before it.
 - ``block_diffusion`` (Block Diffusion, arXiv:2503.09573, the training
   mask): the axis holds two copies of an L = T/2 long sequence, the
   noised copy at 0..L-1 and the clean copy at L..2L-1; index i stands
@@ -35,19 +37,22 @@ import functools
 
 import numpy as np
 
-KINDS = ("full", "causal", "block_diffusion")
+KINDS = ("full", "causal", "sliding_window", "block_diffusion")
 
 
 @dataclasses.dataclass(frozen=True)
 class MaskRule:
     kind: str = "full"
     block_length: int = 0
+    window: int = 0
 
     def __post_init__(self):
         if self.kind not in KINDS:
             raise ValueError(f"attention_mask must be one of {KINDS}, got {self.kind!r}")
         if self.kind == "block_diffusion" and self.block_length < 1:
             raise ValueError("block_diffusion needs block_length >= 1")
+        if self.kind == "sliding_window" and self.window < 1:
+            raise ValueError("sliding_window needs window >= 1")
 
     # ------------------------------------------------------------ the rule
 
@@ -68,6 +73,8 @@ class MaskRule:
             return (q[0] >= 0) & (k[0] >= 0)
         if self.kind == "causal":
             return k[0] <= q[0]
+        if self.kind == "sliding_window":
+            return (k[0] <= q[0]) & (q[0] - k[0] < self.window)
         q_clean, q_blk = q
         k_clean, k_blk = k
         noised_q = (q_clean == 0) & (
@@ -89,9 +96,10 @@ class MaskRule:
                 f"copies of whole blocks, got T={T}")
 
 
-def rule_of(kind: str = "", block_length: int = 0, causal: bool = False) -> MaskRule:
+def rule_of(kind: str = "", block_length: int = 0, causal: bool = False,
+            window: int = 0) -> MaskRule:
     """The rule a layer's config names (``causal`` is the old flag)."""
-    return MaskRule(kind or ("causal" if causal else "full"), int(block_length))
+    return MaskRule(kind or ("causal" if causal else "full"), int(block_length), int(window))
 
 
 # ------------------------------------------------------------- tile tables

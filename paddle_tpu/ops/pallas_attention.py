@@ -11,7 +11,8 @@ get the rule's per-index attributes as small int arrays and, from the
 same rule evaluated on the host, a table of the tiles it leaves
 non-empty. A grid step walks only its row's non-empty tiles (a causal
 mask skips the upper triangle, the block-diffusion mask three quarters
-of the 2L x 2L tiles) and applies the rule only inside tiles it cuts.
+of the 2L x 2L tiles, a sliding window all but a band) and applies the
+rule only inside tiles it cuts.
 Grouped-query heads: query head h reads K/V head ``h // (H / Hkv)``;
 dK/dV come back a query head and are summed over each group outside.
 

@@ -9,9 +9,17 @@ and arithmetic inside a pass, rounded ONCE to the input's dtype)::
 
 with ``C = [cos, cos]`` and ``S = [-sin, sin]`` at the row's position: the
 rotate-half turn as ONE roll of the whole head row and two multiplies, no
-half-row slices and no concatenate. The turn is orthogonal, so the
-backward turns the cotangent by the opposite angle, and the norm's
-backward is its closed form; autodiff sees neither::
+half-row slices and no concatenate. A PARTIAL turn (the first ``rot`` of
+the ``Dh`` lanes turned, the rest passed through) is the same with two
+rolls, each with its own table::
+
+    y = (n * C + roll(n, Dh - rot/2) * S_up + roll(n, rot/2) * S_down) * scale
+    C = [cos, cos, 1...], S_up = [-sin, 0, 0...], S_down = [0, sin, 0...]
+
+(`turn_tables` gives two tables for a whole-head turn and three for a
+partial one; the tables may carry a factor on cos and sin). The backward
+applies the turn's transpose, the same rolls with the sines negated, and
+the norm's backward is its closed form; autodiff sees neither::
 
     dn = dy * scale * C - roll(dy * scale, Dh/2) * S
     u = x * inv,  g = dn * gain
@@ -37,6 +45,7 @@ a described v5e (tests/test_chip_compile.py).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -58,14 +67,56 @@ _TILE_BYTES = 2 * 1024 * 1024
 _VMEM_LIMIT = 48 * 1024 * 1024
 
 
-def turn_tables(positions: Array, theta: float, head_dim: int) -> Tuple[Array, Array]:
-    """``(C, S)``, float32 ``[T, Dh]``: ``[cos, cos]`` and ``[-sin, sin]``
-    of the rotate-half angles at integer ``positions`` [T]."""
-    half = head_dim // 2
+def rotary_frequencies(theta: float, rot: int, yarn=None) -> Array:
+    """float32 ``[rot/2]``: the rotate-half frequencies ``theta^(-2i/rot)``
+    or, with ``yarn = (factor, original positions, beta_fast, beta_slow)``,
+    YaRN's (arXiv:2309.00071): a frequency that makes more than
+    ``beta_fast`` turns over the original positions is kept, one that makes
+    fewer than ``beta_slow`` is divided by ``factor``, and between the two
+    (by frequency index, the bounds rounded outwards) a linear ramp. Fixed,
+    not a function of the sequence's length."""
+    half = rot // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=F32) / half)
-    ang = positions.astype(F32)[:, None] * inv_freq[None, :]              # [T, half]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    return jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1)
+    if yarn is None:
+        return inv_freq
+    factor, original, beta_fast, beta_slow = yarn
+    index_of = lambda turns: rot * math.log(original / (2 * math.pi * turns)) / (2 * math.log(theta))
+    low = max(math.floor(index_of(beta_fast)), 0)
+    high = min(math.ceil(index_of(beta_slow)), rot - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=F32) - low) / ((high - low) or 1e-3), 0.0, 1.0)
+    return inv_freq * (1.0 - ramp) + inv_freq / factor * ramp
+
+
+def turn_tables(positions: Array, theta: float, head_dim: int, rot: int = 0,
+                yarn=None, factor: float = 1.0) -> Tuple[Array, ...]:
+    """The turn's tables at integer ``positions`` [T], float32 ``[T, Dh]``
+    each. The whole head turned (``rot`` 0 or ``head_dim``): ``(C, S)`` =
+    ``[cos, cos]`` and ``[-sin, sin]`` of the rotate-half angles. The first
+    ``rot`` lanes turned: ``(C, S_up, S_down)`` of the module docstring.
+    ``yarn``: :func:`rotary_frequencies`; ``factor`` multiplies cos and sin
+    (YaRN's attention factor), so the turned lanes only."""
+    rot = rot or head_dim
+    ang = positions.astype(F32)[:, None] * rotary_frequencies(theta, rot, yarn)[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)                                 # [T, rot/2]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    if rot == head_dim:
+        return jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1)
+    zero = jnp.zeros_like(sin)
+    rest = jnp.zeros((ang.shape[0], head_dim - rot), F32)
+    return (jnp.concatenate([cos, cos, rest + 1.0], -1),
+            jnp.concatenate([-sin, zero, rest], -1),
+            jnp.concatenate([zero, sin, rest], -1))
+
+
+def _shifts(head_dim: int, rot: int, tables) -> Tuple[int, ...]:
+    """What each sine table's roll turns a head row by (``rot`` 0: the
+    whole head)."""
+    if tables is None:
+        return ()
+    if rot in (0, head_dim):
+        return (head_dim // 2,)
+    return (head_dim - rot // 2, rot // 2)
 
 
 # ------------------------------------------------- the mathematics, once
@@ -75,23 +126,33 @@ def _inv_rms(xf, eps):
     return jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
 
 
-def _forward_rows(xf, gain, c, s, eps, scale, roll):
+def _turned(a, c, s, rolls, sign):
+    """``a * c`` plus (``sign`` 1) or minus each sine table times its roll
+    of ``a``: the turn, and with the sines negated its transpose."""
+    out = a * c
+    for roll, table in zip(rolls, s):
+        out = out + roll(a) * table if sign > 0 else out - roll(a) * table
+    return out
+
+
+def _forward_rows(xf, gain, c, s, eps, scale, rolls):
     """float32 head rows ``[..., Dh]`` -> float32 y. ``gain``, ``c`` and
-    ``s`` broadcast against the rows; ``roll`` turns a row by Dh/2."""
+    the sine tables ``s`` broadcast against the rows; ``rolls``: a roll
+    along the row for each sine table."""
     if gain is not None:
         xf = xf * _inv_rms(xf, eps) * gain
     if c is not None:
-        xf = xf * c + roll(xf) * s
+        xf = _turned(xf, c, s, rolls, 1)
     return xf if scale == 1.0 else xf * scale
 
 
-def _backward_rows(xf, d, gain, c, s, eps, scale, roll):
+def _backward_rows(xf, d, gain, c, s, eps, scale, rolls):
     """float32 head rows and their cotangent -> (dx, dn * u); the second
     is None without a norm, else what sums over rows to ``d gain``."""
     if scale != 1.0:
         d = d * scale
     if c is not None:
-        d = d * c - roll(d) * s
+        d = _turned(d, c, s, rolls, -1)
     if gain is None:
         return d, None
     inv = _inv_rms(xf, eps)
@@ -123,28 +184,28 @@ def supported(T: int, width: int, head_dim: int, itemsize: int) -> bool:
             and _tiling(T, width, head_dim, itemsize)[0] > 0)
 
 
-def _unpack(refs, has_gain, has_turn):
-    """(gain, C, S: values or None; the other refs). A head's whole
+def _unpack(refs, has_gain, shifts):
+    """(gain, C, the sine tables: values or None; a lane roll a sine table;
+    the other refs). A head's whole
     ``[rows, Dh]`` slab is one value: a loop over fewer rows at a time only
     adds its own latency (on a v5e, 32 rows a trip: 1.8 ms a pass of q
     against 0.9)."""
     refs = list(refs)
     gain = refs.pop(0)[...] if has_gain else None                         # [1, Dh]
-    c, s = (refs.pop(0)[...], refs.pop(0)[...]) if has_turn else (None, None)   # [rows, Dh]
-    return gain, c, s, refs
+    tables = [refs.pop(0)[...] for _ in range(len(shifts) + 1)] if shifts else [None]
+    rolls = [lambda a, n=n: pltpu.roll(a, n, 1) for n in shifts]
+    return gain, tables[0], tables[1:], rolls, refs                       # [rows, Dh] each
 
 
-def _fwd_kernel(*refs, head_dim, eps, scale, has_gain, has_turn):
-    gain, c, s, (x_ref, y_ref) = _unpack(refs, has_gain, has_turn)
-    roll = lambda a: pltpu.roll(a, head_dim // 2, 1)
+def _fwd_kernel(*refs, head_dim, eps, scale, has_gain, shifts):
+    gain, c, s, roll, (x_ref, y_ref) = _unpack(refs, has_gain, shifts)
     for h in range(x_ref.shape[2] // head_dim):
         xf = x_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(F32)
         y_ref[0, h] = _forward_rows(xf, gain, c, s, eps, scale, roll).astype(y_ref.dtype)
 
 
-def _bwd_kernel(*refs, head_dim, eps, scale, has_gain, has_turn):
-    gain, c, s, (x_ref, dy_ref, dx_ref, *d_gain_ref) = _unpack(refs, has_gain, has_turn)
-    roll = lambda a: pltpu.roll(a, head_dim // 2, 1)
+def _bwd_kernel(*refs, head_dim, eps, scale, has_gain, shifts):
+    gain, c, s, roll, (x_ref, dy_ref, dx_ref, *d_gain_ref) = _unpack(refs, has_gain, shifts)
     acc = 0.0
     for h in range(x_ref.shape[2] // head_dim):
         lanes = slice(h * head_dim, (h + 1) * head_dim)
@@ -157,7 +218,7 @@ def _bwd_kernel(*refs, head_dim, eps, scale, has_gain, has_turn):
         d_gain_ref[0][0, 0, 0] = jnp.sum(acc, axis=0, keepdims=True)      # this tile's d gain
 
 
-def _call(kernel, name, x, dy, gain, tables, head_dim, eps, scale, interpret):
+def _call(kernel, name, x, dy, gain, tables, head_dim, eps, scale, rot, interpret):
     """One pass in tiles of some rows by a few heads: the forward kernel
     (``dy`` None) reads the projection ``x`` [B, T, width] and writes y
     [B, H, T, Dh]; the backward kernel reads x and ``dy`` and writes dx
@@ -177,7 +238,7 @@ def _call(kernel, name, x, dy, gain, tables, head_dim, eps, scale, interpret):
         specs.append(pl.BlockSpec((1, head_dim), lambda j, g, b: (0, 0)))
     if tables is not None:
         operands += list(tables)
-        specs += [pl.BlockSpec((rows, head_dim), lambda j, g, b: (j, 0))] * 2
+        specs += [pl.BlockSpec((rows, head_dim), lambda j, g, b: (j, 0))] * len(tables)
     if dy is None:
         tiled, outs = [(x, flat[1])], [by_head]
     else:
@@ -187,7 +248,7 @@ def _call(kernel, name, x, dy, gain, tables, head_dim, eps, scale, interpret):
                          pl.BlockSpec((1, 1, 1, 1, head_dim), lambda j, g, b: (j, g, b, 0, 0))))
     return pl.pallas_call(
         functools.partial(kernel, head_dim=head_dim, eps=eps, scale=scale,
-                          has_gain=gain is not None, has_turn=tables is not None),
+                          has_gain=gain is not None, shifts=_shifts(head_dim, rot, tables)),
         name=name, grid=grid,
         in_specs=specs + [spec for _, spec in tiled],
         out_specs=[spec for _, spec in outs], out_shape=[shape for shape, _ in outs],
@@ -201,57 +262,58 @@ def _call(kernel, name, x, dy, gain, tables, head_dim, eps, scale, interpret):
 # ------------------------------------------------------------ the function
 
 
-def _whole(x, gain, tables, head_dim):
+def _whole(x, gain, tables, head_dim, rot):
     """The XLA path's view of the operands: the rows' shape ``[B, T, H,
-    Dh]``, the gain float32, the tables ``[1, T, 1, Dh]``, and the roll
+    Dh]``, the gain float32, the tables ``[1, T, 1, Dh]``, and the rolls
     along the last axis."""
     B, T, width = x.shape
-    c, s = (None, None) if tables is None else (t[None, :, None, :] for t in tables)
-    roll = lambda a: jnp.roll(a, head_dim // 2, axis=-1)
+    c, *s = [None] if tables is None else [t[None, :, None, :] for t in tables]
+    rolls = [lambda a, n=n: jnp.roll(a, n, axis=-1) for n in _shifts(head_dim, rot, tables)]
     return ((B, T, width // head_dim, head_dim),
-            None if gain is None else gain.astype(F32), c, s, roll)
+            None if gain is None else gain.astype(F32), c, s, rolls)
 
 
 def head_prologue(x: Array, gain: Optional[Array], tables, head_dim: int,
-                  eps: float, scale: float) -> Array:
+                  eps: float, scale: float, rot: int = 0) -> Array:
     """A projection ``x`` [B, T, H*Dh], as the product leaves it, with its
     heads made ready for the scores (module docstring): ``[B, H, T, Dh]``,
     as the flash kernels read them, in x's dtype. ``gain`` [Dh] or None (no
-    norm); ``tables`` from :func:`turn_tables` or None (no turn). The
-    kernels run where `device.pallas_mode` has a way to run them and
+    norm); ``tables`` from :func:`turn_tables` or None (no turn), ``rot``
+    the lanes they turn where not the whole head. The kernels run where
+    `device.pallas_mode` has a way to run them and
     :func:`supported` admits the shape."""
     from paddle_tpu.utils import device
 
     mode = device.pallas_mode()
     if not supported(x.shape[1], x.shape[2], head_dim, x.dtype.itemsize):
         mode = None
-    return _prologue(x, gain, tables, head_dim, eps, scale, mode)
+    return _prologue(x, gain, tables, head_dim, eps, scale, mode, rot)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _prologue(x, gain, tables, head_dim, eps, scale, mode):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _prologue(x, gain, tables, head_dim, eps, scale, mode, rot=0):
     """``mode`` "compiled" / "interpret": the kernels; None: the same
     mathematics over the whole array under XLA."""
     if mode is not None:
         return _call(_fwd_kernel, "head_prologue_fwd", x, None, gain, tables,
-                     head_dim, eps, scale, mode == "interpret")[0]
-    shape, g, c, s, roll = _whole(x, gain, tables, head_dim)
+                     head_dim, eps, scale, rot, mode == "interpret")[0]
+    shape, g, c, s, roll = _whole(x, gain, tables, head_dim, rot)
     y = _forward_rows(x.astype(F32).reshape(shape), g, c, s, eps, scale, roll)
     return y.astype(x.dtype).transpose(0, 2, 1, 3)
 
 
-def _vjp_fwd(x, gain, tables, head_dim, eps, scale, mode):
-    return _prologue(x, gain, tables, head_dim, eps, scale, mode), (x, gain, tables)
+def _vjp_fwd(x, gain, tables, head_dim, eps, scale, mode, rot):
+    return _prologue(x, gain, tables, head_dim, eps, scale, mode, rot), (x, gain, tables)
 
 
-def _vjp_bwd(head_dim, eps, scale, mode, kept, dy):
+def _vjp_bwd(head_dim, eps, scale, mode, rot, kept, dy):
     x, gain, tables = kept
     if mode is not None:
         dx, *du = _call(_bwd_kernel, "head_prologue_bwd", x, dy, gain, tables,
-                        head_dim, eps, scale, mode == "interpret")
+                        head_dim, eps, scale, rot, mode == "interpret")
         du = du[0] if du else None
     else:
-        shape, g, c, s, roll = _whole(x, gain, tables, head_dim)
+        shape, g, c, s, roll = _whole(x, gain, tables, head_dim, rot)
         dx, du = _backward_rows(x.astype(F32).reshape(shape),
                                 dy.astype(F32).transpose(0, 2, 1, 3),
                                 g, c, s, eps, scale, roll)

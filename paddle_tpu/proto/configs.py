@@ -229,26 +229,39 @@ class LayerConfig(Message):
     # num_heads query heads over num_kv_heads K/V heads of head_dim,
     # per-head q/k RMS norm, rotary positions, the mask as a rule
     # (ops/attention_mask.py: "" = causal_attention's flag | full | causal
-    # | block_diffusion with mask_block_length)
+    # | sliding_window with mask_window | block_diffusion with
+    # mask_block_length); rotary_dim: the head's first lanes the rotary
+    # turn takes (0 = the whole head); rope_yarn: YaRN's (factor, original
+    # positions, beta_fast, beta_slow), empty = plain frequencies;
+    # rope_attention_factor on cos and sin; output_gate: sigmoid of a
+    # [D, num_heads] projection of the input times each head's result
     num_kv_heads: int = 0
     head_dim: int = 0
     qk_norm: bool = False
     rope_theta: float = 0.0
+    rotary_dim: int = 0
+    rope_yarn: List[float] = field(default_factory=list)
+    rope_attention_factor: float = 1.0
     attention_mask: str = ""
     mask_block_length: int = 0
+    mask_window: int = 0
+    output_gate: bool = False
     # rms_norm (and attention's q/k norm): x / sqrt(mean(x^2) + epsilon)
     norm_epsilon: float = 1e-6
     # sparse-expert layer (layers/moe.py): `experts` router outputs,
     # `experts_per_token` chosen, SwiGLU experts `expert_width` wide, of
     # which this program holds `experts_held_count` from
     # `experts_held_first` on (0 = all); norm_topk_prob renormalises the
-    # chosen probabilities over all the chosen, held or not
+    # chosen probabilities over all the chosen, held or not;
+    # routed_scaling_factor multiplies the routed sum. expert_width is
+    # also the width of a gated_mlp layer
     experts: int = 0
     experts_per_token: int = 0
     expert_width: int = 0
     experts_held_first: int = 0
     experts_held_count: int = 0
     norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
     # seq_slice: the time axis cut into seq_parts equal parts, part seq_part kept
     seq_parts: int = 1
     seq_part: int = 0

@@ -116,6 +116,7 @@ __all__ = [
     "multi_head_attention_layer",
     "rms_norm_layer",
     "moe_layer",
+    "gated_mlp_layer",
     "seq_slice_layer",
     "remat_block",
     "mdlstm_layer",
@@ -1797,6 +1798,11 @@ def multi_head_attention_layer(
     attention_mask: str = "",
     block_length: int = 0,
     norm_epsilon: float = 1e-6,
+    window: int = 0,
+    rotary_dim: int = 0,
+    rope_yarn: Optional[Sequence[float]] = None,
+    rope_attention_factor: float = 1.0,
+    output_gate: bool = False,
 ) -> LayerOutput:
     """Transformer-style multi-head self-attention over a sequence (TPU
     extension; the reference's only attention is simple_attention inside
@@ -1809,11 +1815,18 @@ def multi_head_attention_layer(
     ``head_dim`` each, separate parameters ``_<name>.wq`` [in, H*Dh],
     ``.wk`` / ``.wv`` [in, Hkv*Dh], ``.wo`` [H*Dh, size]; ``qk_norm``: an
     RMS norm over each head's q and k (``.q_norm`` / ``.k_norm`` [1, Dh],
-    ``norm_epsilon``); ``rope_theta`` > 0: rotary positions, rotate-half;
-    ``attention_mask``: "full" | "causal" | "block_diffusion" (with
-    ``block_length``; the input then holds the noised and the clean copy
-    of an L-long sequence as one 2L-long one) — a rule over positions,
-    `paddle_tpu/ops/attention_mask.py`."""
+    ``norm_epsilon``); ``rope_theta`` > 0: rotary positions, rotate-half,
+    over the whole head or its first ``rotary_dim`` lanes (the rest pass
+    through), with ``rope_yarn`` = (factor, original positions, beta_fast,
+    beta_slow) YaRN's frequencies, and ``rope_attention_factor`` on cos and
+    sin; ``attention_mask``: "full" | "causal" | "sliding_window" (with
+    ``window``: a query sees itself and the ``window - 1`` positions
+    before it) | "block_diffusion" (with ``block_length``; the input then
+    holds the noised and the clean copy of an L-long sequence as one
+    2L-long one) — a rule over positions,
+    `paddle_tpu/ops/attention_mask.py`; ``output_gate``: each head's result
+    times the sigmoid of a projection of the input, one number a head a
+    position (``_<name>.wg`` [in, H])."""
     assert seq_parallel in ("", "ring", "alltoall"), (
         f"seq_parallel must be '', 'ring' or 'alltoall', got {seq_parallel!r}"
     )
@@ -1831,11 +1844,19 @@ def multi_head_attention_layer(
     if head_dim:
         from paddle_tpu.ops.attention_mask import rule_of
 
-        rule_of(attention_mask, block_length, causal)      # refuses a bad rule here
+        rule_of(attention_mask, block_length, causal, window)      # refuses a bad rule here
+        assert 0 <= rotary_dim <= head_dim and rotary_dim % 2 == 0, (
+            f"rotary_dim {rotary_dim} is no even part of a head of {head_dim}")
+        assert rope_yarn is None or len(rope_yarn) == 4, (
+            "rope_yarn is (factor, original positions, beta_fast, beta_slow)")
         kv = num_kv_heads or num_heads
         cfg.num_kv_heads, cfg.head_dim, cfg.qk_norm = kv, head_dim, qk_norm
         cfg.rope_theta, cfg.norm_epsilon = float(rope_theta), float(norm_epsilon)
+        cfg.rotary_dim = 0 if rotary_dim == head_dim else rotary_dim
+        cfg.rope_yarn = [float(v) for v in rope_yarn or ()]
+        cfg.rope_attention_factor = float(rope_attention_factor)
         cfg.attention_mask, cfg.mask_block_length = attention_mask, block_length
+        cfg.mask_window, cfg.output_gate = window, bool(output_gate)
         wq = _create_parameter(f"_{name}.wq", input.size * num_heads * head_dim,
                                [input.size, num_heads * head_dim], param_attr)
         for leaf in ("wk", "wv"):
@@ -1846,11 +1867,15 @@ def multi_head_attention_layer(
         if qk_norm:
             for leaf in ("q_norm", "k_norm"):
                 _create_parameter(f"_{name}.{leaf}", head_dim, [1, head_dim], _ones_attr())
+        if output_gate:
+            _create_parameter(f"_{name}.wg", input.size * num_heads,
+                              [input.size, num_heads], param_attr)
         cfg.inputs.append(_input(input, wq))
     else:
-        assert not (num_kv_heads or qk_norm or rope_theta or attention_mask), (
-            "grouped-query heads, q/k norm, rotary positions and mask rules "
-            "need head_dim")
+        assert not (num_kv_heads or qk_norm or rope_theta or attention_mask or window
+                    or rotary_dim or rope_yarn or output_gate), (
+            "grouped-query heads, q/k norm, rotary positions, mask rules and "
+            "the output gate need head_dim")
         wqkv = _create_parameter(
             f"_{name}.wqkv", input.size * 3 * size, [input.size, 3 * size], param_attr
         )
@@ -1896,11 +1921,14 @@ def moe_layer(
     name: Optional[str] = None,
     param_attr: Optional[ParameterAttribute] = None,
     layer_attr=None,
+    routed_scaling_factor: float = 1.0,
 ) -> LayerOutput:
     """Sparse-expert feed-forward (TPU extension, `paddle_tpu/layers/
     moe.py`): a float32 softmax router over ``experts``, the
     ``experts_per_token`` largest chosen (renormalised over the chosen
-    with ``norm_topk_prob``), SwiGLU experts ``expert_width`` wide.
+    with ``norm_topk_prob``), SwiGLU experts ``expert_width`` wide, their
+    weighted sum times ``routed_scaling_factor``. A shared expert is a
+    :func:`gated_mlp_layer` beside this layer, joined by ``addto_layer``.
     ``experts_held``: ``(first, count)``, the experts this program holds
     and computes (all by default); the router always has ``experts``
     outputs. Parameters: ``_<name>.router`` [size, experts], ``.gate`` /
@@ -1915,6 +1943,7 @@ def moe_layer(
     cfg.experts, cfg.experts_per_token, cfg.expert_width = experts, experts_per_token, expert_width
     cfg.experts_held_first, cfg.experts_held_count = first, count
     cfg.norm_topk_prob = bool(norm_topk_prob)
+    cfg.routed_scaling_factor = float(routed_scaling_factor)
     router = _create_parameter(f"_{name}.router", d * experts, [d, experts], param_attr)
     for leaf, dims in (("gate", [count, d, expert_width]), ("up", [count, d, expert_width]),
                        ("down", [count, expert_width, d])):
@@ -1925,6 +1954,25 @@ def moe_layer(
     cfg.inputs.append(_input(input, router))
     _add_layer(cfg, layer_attr)
     return LayerOutput(name, "moe", [input], d)
+
+
+def gated_mlp_layer(input: LayerOutput, width: int, name: Optional[str] = None,
+                    param_attr: Optional[ParameterAttribute] = None,
+                    layer_attr=None) -> LayerOutput:
+    """Dense gated feed-forward (TPU extension, `paddle_tpu/layers/
+    gated_mlp.py`): ``(silu(x Wg) * (x Wu)) Wd``, ``width`` wide, no bias:
+    a transformer block's dense feed-forward, or the shared expert beside
+    a :func:`moe_layer`. Parameters: ``_<name>.gate`` / ``.up`` [size,
+    width], ``.down`` [width, size]."""
+    name = _name(name, "gated_mlp")
+    d = input.size
+    cfg = LayerConfig(name=name, type="gated_mlp", size=d)
+    cfg.expert_width = width
+    names = [_create_parameter(f"_{name}.{leaf}", d * width, dims, param_attr)
+             for leaf, dims in (("gate", [d, width]), ("up", [d, width]), ("down", [width, d]))]
+    cfg.inputs.append(_input(input, names[0]))
+    _add_layer(cfg, layer_attr)
+    return LayerOutput(name, "gated_mlp", [input], d)
 
 
 def seq_slice_layer(input: LayerOutput, parts: int, part: int = 0,

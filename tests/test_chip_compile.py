@@ -109,12 +109,15 @@ def _flash(T, D, dtype, B=2, H=4):
 def _rule_attention(kind, T=8192, H=32, Hkv=4, D=128, B=1):
     """The flash kernel of `ops/pallas_attention.py` under a mask rule at
     the block-diffusion cell's shapes (perfbench `sdar.train`: 32 query / 4
-    key-value heads of 128, 8192 positions), as `rule_attention` runs it
-    inside the train step: forward and both backward kernels."""
+    key-value heads of 128, 8192 positions) or at `laguna.train`'s (8
+    key-value heads under 64 query heads and a 512-wide window, or under 48
+    and the causal rule), as `rule_attention` runs it inside the train
+    step: forward and both backward kernels."""
     from paddle_tpu.ops import pallas_attention as pa
     from paddle_tpu.ops.attention_mask import MaskRule
 
-    rule = MaskRule(kind, 4 if kind == "block_diffusion" else 0)
+    rule = MaskRule(kind, 4 if kind == "block_diffusion" else 0,
+                    512 if kind == "sliding_window" else 0)
     shapes = [((B, T, H, D), BF16), ((B, T, Hkv, D), BF16), ((B, T, Hkv, D), BF16)]
     fn = lambda q, k, v: pa.flash_attention(q, k, v, rule=rule)
     return fn, shapes, pa.supported(T, D, 2)
@@ -144,6 +147,18 @@ def _head_prologue(heads, B=4, T=8192, Dh=128):
 
     shapes = [((B, T, heads * Dh), BF16), ((Dh,), F32), ((T, Dh), F32), ((T, Dh), F32)]
     fn = lambda x, gain, c, s: hp._prologue(x, gain, (c, s), Dh, 1e-6, Dh ** -0.5, "compiled")
+    return fn, shapes, hp.supported(T, heads * Dh, Dh, 2)
+
+
+def _head_prologue_partial(heads, B=4, T=8192, Dh=128, rot=64):
+    """The same kernels with a PARTIAL turn and no norm, as `laguna.train`'s
+    full-attention layers run them: the first 64 of a head's 128 lanes
+    turned (two rolls, three tables), the 48 query heads or the 8 key heads."""
+    from paddle_tpu.ops import pallas_head_prologue as hp
+
+    shapes = [((B, T, heads * Dh), BF16)] + [((T, Dh), F32)] * 3
+    fn = lambda x, c, up, down: hp._prologue(x, None, (c, up, down), Dh, 1e-6, Dh ** -0.5,
+                                             "compiled", rot)
     return fn, shapes, hp.supported(T, heads * Dh, Dh, 2)
 
 
@@ -180,6 +195,12 @@ CASES = {
     "expert-ffn-16x768": _expert_ffn,
     "head-prologue-q-32x128": lambda: _head_prologue(32),
     "head-prologue-k-4x128": lambda: _head_prologue(4),
+    # perfbench laguna.train: the window and the causal rule over 8 key/value
+    # heads, and the partial turn of the full-attention layers
+    "rule-attention-window-64-heads-t8192": lambda: _rule_attention("sliding_window", H=64, Hkv=8),
+    "rule-attention-causal-48-heads-t8192": lambda: _rule_attention("causal", H=48, Hkv=8),
+    "head-prologue-partial-q-48x128": lambda: _head_prologue_partial(48),
+    "head-prologue-partial-k-8x128": lambda: _head_prologue_partial(8),
     # a ResNet-50 1x1 at B=256: stage-1 expand, 56x56 pixels, 64 -> 256
     "conv1x1-bf16": lambda: _conv1x1(256 * 56 * 56, 64, 256, BF16),
 }
