@@ -4,8 +4,9 @@ A rule says which (query, key) index pairs of a T-long axis may attend,
 and which position each index stands for (what a rotary embedding
 turns by). The XLA path (`parallel/sequence_parallel.py`), the Pallas
 kernel (`ops/pallas_attention.py`) and the tile tables the kernel skips
-by all read the same two functions, so a new mask is one more `kind`
-here and nothing else:
+by (`tile_occupancy`) and pairs partial tiles by (`tile_walk`) all read
+the same two functions, so a new mask is one more `kind` here and
+nothing else:
 
 - ``attrs(idx, T)``: a tuple of int arrays shaped like ``idx``, what the
   rule needs to know of an index (computed outside the kernels, where
@@ -34,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import NamedTuple
 
 import numpy as np
 
@@ -122,18 +124,80 @@ def tile_occupancy(rule: MaskRule, T: int, bq: int, bk: int) -> np.ndarray:
     return out
 
 
-def tile_lists(occ: np.ndarray):
-    """Row r's non-empty tiles, as the kernels read them: a flat int32
-    table [rows * width] of ``2 * tile + partial`` (padded with the row's
-    last entry) and the int32 count a row."""
-    rows = occ.shape[0]
-    counts = (occ > 0).sum(axis=1).astype(np.int32)
-    width = max(int(counts.max()), 1)
-    table = np.zeros((rows, width), np.int32)
-    for r in range(rows):
-        ids = np.flatnonzero(occ[r])
-        codes = 2 * ids + (occ[r, ids] == 2)
-        if len(codes):
-            table[r, : len(codes)] = codes
-            table[r, len(codes):] = codes[-1]
-    return table.reshape(-1), counts, width
+class TileWalk(NamedTuple):
+    """How the kernels walk a row of tiles (a query tile's key tiles, or,
+    transposed, a key tile's query tiles). Every non-empty tile of
+    `tile_occupancy` is in it exactly once: as a single (the row's whole
+    tiles first, whose step needs no mask, then its partial ones), or as
+    one of a PAIR of partial tiles whose allowed sets, in tile-local
+    coordinates, are disjoint, so that the kernels merge the two score
+    tiles element by element and pay one pass of softmax vector work for
+    both."""
+
+    table: np.ndarray        # int32 [rows * width]: a row's single tiles, whole then partial
+    pairs: np.ndarray        # int32 [rows * pair_width * 2]: tile A, tile B
+    counts: np.ndarray       # int32 [rows * 3]: whole singles, all singles, pairs
+    width: int
+    pair_width: int          # 0 where no row has a pair
+    union_whole: bool        # every pair's two sets together are the whole tile
+    census: str              # whole / partial / paired tiles, for the log
+
+
+def _flat(rows, per):
+    """Lists of ints a row -> (flat int32 table, entries of the longest
+    row); an entry is ``per`` ints, a short row is padded with zeros."""
+    width = max(len(row) for row in rows) // per
+    table = np.zeros((len(rows), max(width, 1) * per), np.int32)
+    for r, row in enumerate(rows):
+        table[r, : len(row)] = row
+    table.setflags(write=False)         # cached: every caller gets this array
+    return table.reshape(-1), width
+
+
+@functools.lru_cache(maxsize=64)
+def tile_walk(rule: MaskRule, T: int, bq: int, bk: int, transpose: bool = False) -> TileWalk:
+    """The walk of `tile_occupancy(rule, T, bq, bk)` (of its transpose: the
+    dK/dV kernel's), found from the rule's own masks and from nothing
+    else: in a row, a partial tile takes the first later partial tile it
+    shares no allowed local (row, column) with; a tile is in at most one
+    pair. A causal row has one partial tile and no pair; a window's far
+    and near edge tiles pair (two triangles where the window is a multiple
+    of the tile: `union_whole`); a noised block-diffusion query tile's own
+    noised key tile pairs with its last clean one, and by key tile nothing
+    does (the clean key tile's two partial query tiles overlap). Tiles
+    ascend within a row's whole and within its partial singles."""
+    occ = tile_occupancy(rule, T, bq, bk)
+    occ = occ.T if transpose else occ
+
+    def local_mask(r, c):
+        i, j = (c, r) if transpose else (r, c)
+        m = rule.allowed(np.arange(i * bq, (i + 1) * bq), np.arange(j * bk, (j + 1) * bk), T)
+        return np.broadcast_to(m, (bq, bk))
+
+    singles, pairs, counts, union_whole = [], [], [], True
+    for r, row in enumerate(occ):
+        free = [int(c) for c in np.flatnonzero(row == 2)]
+        masks = {c: local_mask(r, c) for c in free} if len(free) > 1 else {}
+        paired = []
+        while len(free) > 1:
+            a = free.pop(0)
+            b = next((c for c in free if not (masks[a] & masks[c]).any()), None)
+            if b is not None:
+                free.remove(b)
+                paired += [a, b]
+                union_whole &= bool((masks[a] | masks[b]).all())
+        whole = [int(c) for c in np.flatnonzero(row == 1)]
+        partial = [int(c) for c in np.flatnonzero(row == 2) if c not in paired]
+        singles.append(whole + partial)
+        pairs.append(paired)
+        counts += [len(whole), len(whole) + len(partial), len(paired) // 2]
+
+    table, width = _flat(singles, 1)
+    pair_table, pair_width = _flat(pairs, 2)
+    counts = np.asarray(counts, np.int32)
+    counts.setflags(write=False)
+    n_paired = 2 * int(counts[2::3].sum())
+    census = (f"{int((occ == 1).sum())} whole + {int((occ == 2).sum()) - n_paired} partial + "
+              f"{n_paired} paired tiles in {len(occ)} rows")
+    return TileWalk(table, pair_table, counts, max(width, 1), pair_width,
+                    union_whole and pair_width > 0, census)

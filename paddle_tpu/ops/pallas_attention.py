@@ -8,11 +8,26 @@ kernels (dQ; dK/dV).
 
 The mask is a rule over positions (`ops/attention_mask.py`): the kernels
 get the rule's per-index attributes as small int arrays and, from the
-same rule evaluated on the host, a table of the tiles it leaves
-non-empty. A grid step walks only its row's non-empty tiles (a causal
-mask skips the upper triangle, the block-diffusion mask three quarters
-of the 2L x 2L tiles, a sliding window all but a band) and applies the
-rule only inside tiles it cuts.
+same rule evaluated on the host, the walk of the tiles it leaves
+non-empty (`attention_mask.tile_walk`). A grid step walks only its
+row's non-empty tiles (a causal mask skips the upper triangle, the
+block-diffusion mask three quarters of the 2L x 2L tiles, a sliding
+window all but a band) in three loops, one a kind of step and no branch
+inside a step (a `lax.cond` round the mask cost every tile about a
+third of its time): the row's whole tiles short of the sequence's
+length, with no mask at all; its partial tiles (and a whole tile the
+padding cuts) under the rule and the length; its pairs.
+
+Two partial tiles of a row whose allowed sets are disjoint in tile-local
+coordinates (a window's far and near triangle; a noised block-diffusion
+query tile's own noised keys and its clean keys of earlier blocks) are
+walked as ONE pair step: both score products, the two tiles merged
+element by element under their masks, then max, exp, sum and the
+rescaling once for the pair, the probabilities split again for the two
+value products. The vector unit sets these kernels' pace, so a pair costs
+about one tile's pass and not two; every allowed score is still computed
+once, in float32. Which tiles pair is read from the rule's masks on the
+host; a rule with no pair (causal, full) compiles no pair step.
 Grouped-query heads: query head h reads K/V head ``h // (H / Hkv)``;
 dK/dV come back a query head and are summed over each group outside.
 
@@ -38,7 +53,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.attention_mask import MaskRule, rule_of, tile_lists, tile_occupancy
+from paddle_tpu.ops.attention_mask import MaskRule, rule_of, tile_walk
 
 Array = jax.Array
 
@@ -83,56 +98,140 @@ def _scaled(x, scale):
     return x if scale == 1.0 else x * scale
 
 
-def _masked(s, rule, q_attrs, k_attrs, k_idx, length):
-    """Scores with the pairs the rule (or the padding) forbids at -inf."""
-    ok = rule.allowed_from(q_attrs, k_attrs) & (k_idx < length)
-    return jnp.where(ok, s, -jnp.inf)
+def _pair_merged(rule, side_a, side_b, a, b, union_whole):
+    """ONE tile out of the two of a pair (`attention_mask.tile_walk`): a
+    where the rule allows the pair in tile A, b where it does in tile B
+    (the two sets are disjoint), -inf elsewhere; with A's mask, which
+    splits the tile again. A side is ``(q_attrs, k_attrs)``. Where the
+    host found the two sets to be the whole tile together, B's is A's
+    complement and the rule is evaluated once."""
+    ok_a = rule.allowed_from(*side_a)
+    if union_whole:
+        return ok_a, jnp.where(ok_a, a, b)
+    return ok_a, jnp.where(ok_a, a, jnp.where(rule.allowed_from(*side_b), b, -jnp.inf))
 
 
-def _fwd_kernel(len_ref, tab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
-                o_ref, lse_ref, *, rule, n_attr, block_k, width, scale):
+def _split(ok_a, x, dtype):
+    """A merged tile's two parts again, as MXU operands (x is 0 wherever
+    neither side allows)."""
+    x_a = jnp.where(ok_a, x, 0.0)
+    return x_a.astype(dtype), (x - x_a).astype(dtype)
+
+
+def _past(inside):
+    """The padding as an addend: 0 on a key short of the sequence's length,
+    -inf past it. A pair's step adds it to each tile (a row or a column of
+    it) and has no branch."""
+    return jnp.where(inside, 0.0, -jnp.inf)
+
+
+def _walk_row(tab_ref, ptab_ref, cnt_ref, row, width, pair_width):
+    """A row of the host's walk: ``single(j)`` and ``pair(j)``, its j-th
+    single tile and its j-th pair of tiles; how many whole tiles lead its
+    singles; and ``run(n_plain, single_step, pair_step, carry)``, its three
+    loops, one a kind of step: the first ``n_plain`` singles with no mask
+    (``single_step(False)``), the other singles masked, the pairs."""
+    single = lambda j: tab_ref[row * width + j]
+    pair = lambda j: (ptab_ref[2 * (row * pair_width + j)], ptab_ref[2 * (row * pair_width + j) + 1])
+    n_whole, n_single, n_pair = (cnt_ref[3 * row + c] for c in range(3))
+
+    def run(n_plain, single_step, pair_step, carry):
+        carry = jax.lax.fori_loop(0, n_plain, single_step(False), carry)
+        carry = jax.lax.fori_loop(n_plain, n_single, single_step(True), carry)
+        if pair_width:
+            carry = jax.lax.fori_loop(0, n_pair, pair_step, carry)
+        return carry
+
+    return single, pair, n_whole, run
+
+
+def _whole_inside(single, n_whole, block_k, length):
+    """How many of a query tile's whole key tiles end short of the
+    sequence's length: they lead the row (tiles ascend), and their step has
+    no mask at all; the others go with the partial tiles."""
+    return jax.lax.fori_loop(
+        0, n_whole, lambda j, n: n + ((single(j) + 1) * block_k <= length).astype(jnp.int32), 0)
+
+
+def _key_masks(rule, q_attrs, ka_ref, block_k, length, union_whole):
+    """What the forward and the dQ kernel share of a query tile's walk over
+    key tiles: ``cut(kt, start, s)``, a single tile's scores with what the
+    rule or the padding forbids at -inf, and ``pair_scores``, the same for
+    a pair of tiles merged into one (with A's mask, which splits it again)."""
+
+    def side(kt):
+        return q_attrs, tuple(ka_ref[a, pl.ds(kt, 1), :] for a in range(len(q_attrs)))
+
+    def inside(start):                                        # [1, bk]
+        return start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1) < length
+
+    def cut(kt, start, s):
+        return jnp.where(rule.allowed_from(*side(kt)) & inside(start), s, -jnp.inf)
+
+    def pair_scores(ta, tb, start_a, start_b, s_a, s_b):
+        return _pair_merged(rule, side(ta), side(tb), s_a + _past(inside(start_a)),
+                            s_b + _past(inside(start_b)), union_whole)
+
+    return cut, pair_scores
+
+
+def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
+                o_ref, lse_ref, *, rule, n_attr, block_k, width, pair_width, union_whole, scale):
     b = pl.program_id(0)
     iq = pl.program_id(2)
     bq, D = q_ref.shape[2], q_ref.shape[3]
     length = len_ref[b]
     q = q_ref[0, 0]                                           # [bq, D]
     q_attrs = tuple(qa_ref[a] for a in range(n_attr))         # each [bq, 1]
+    single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, iq, width, pair_width)
+    cut, pair_scores = _key_masks(rule, q_attrs, ka_ref, block_k, length, union_whole)
 
-    def body(j, carry):
-        o, m, l = carry
-        code = tab_ref[iq * width + j]
-        kt = jax.lax.shift_right_logical(code, 1)
+    def tile(kt):
         start = pl.multiple_of(kt * block_k, block_k)
         k_blk = k_ref[0, 0, pl.ds(start, block_k), :]
         v_blk = v_ref[0, 0, pl.ds(start, block_k), :]
-        s = _scaled(_dot(q, k_blk, _NT), scale)               # [bq, bk]
+        return start, v_blk, _scaled(_dot(q, k_blk, _NT), scale)           # s [bq, bk]
 
-        def cut(s):
-            k_attrs = tuple(ka_ref[a, pl.ds(kt, 1), :] for a in range(n_attr))
-            k_idx = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-            return _masked(s, rule, q_attrs, k_attrs, k_idx, length)
-
-        partial = ((code & 1) == 1) | (start + block_k > length)
-        s = jax.lax.cond(partial, cut, lambda s: s, s)
+    def softmax_step(carry, s, pv):
+        o, m, l = carry
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)                                # -inf -> 0
-        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        o = o * alpha + _dot(p.astype(v_blk.dtype), v_blk, _NN)
-        return o, m_new, l
+        return o * alpha + pv(p), m_new, l * alpha + jnp.sum(p, axis=1, keepdims=True)
+
+    def single_step(masked):
+        def step(j, carry):
+            kt = single(j)
+            start, v_blk, s = tile(kt)
+            s = cut(kt, start, s) if masked else s
+            return softmax_step(carry, s, lambda p: _dot(p.astype(v_blk.dtype), v_blk, _NN))
+        return step
+
+    def pair_step(j, carry):
+        ta, tb = pair(j)
+        start_a, v_a, s_a = tile(ta)
+        start_b, v_b, s_b = tile(tb)
+        ok_a, s = pair_scores(ta, tb, start_a, start_b, s_a, s_b)
+
+        def pv(p):
+            p_a, p_b = _split(ok_a, p, v_a.dtype)
+            return _dot(p_a, v_a, _NN) + _dot(p_b, v_b, _NN)
+
+        return softmax_step(carry, s, pv)
 
     o0 = jnp.zeros((bq, D), jnp.float32)
     m0 = jnp.full((bq, 1), _NEG, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, cnt_ref[iq], body, (o0, m0, l0))
+    o, m, l = run(_whole_inside(single, n_whole, block_k, length), single_step, pair_step,
+                  (o0, m0, l0))
     l_safe = jnp.maximum(l, 1e-20)
     o_ref[0, 0] = (o / l_safe).astype(o_ref.dtype)
     lse_ref[0, 0, 0] = _row(jnp.where(l > 0, m + jnp.log(l_safe), _NEG))
 
 
-def _dq_kernel(len_ref, tab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
+def _dq_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
                do_ref, lse_ref, delta_ref, dq_ref,
-               *, rule, n_attr, block_k, width, scale):
+               *, rule, n_attr, block_k, width, pair_width, union_whole, scale):
     b = pl.program_id(0)
     iq = pl.program_id(2)
     bq, D = q_ref.shape[2], q_ref.shape[3]
@@ -142,37 +241,46 @@ def _dq_kernel(len_ref, tab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
     lse = _col(lse_ref[0, 0, 0])                              # [bq, 1]
     delta = _col(delta_ref[0, 0, 0])
     q_attrs = tuple(qa_ref[a] for a in range(n_attr))
+    single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, iq, width, pair_width)
+    cut, pair_scores = _key_masks(rule, q_attrs, ka_ref, block_k, length, union_whole)
 
-    def body(j, dq):
-        code = tab_ref[iq * width + j]
-        kt = jax.lax.shift_right_logical(code, 1)
+    def tile(kt):
         start = pl.multiple_of(kt * block_k, block_k)
         k_blk = k_ref[0, 0, pl.ds(start, block_k), :]
         v_blk = v_ref[0, 0, pl.ds(start, block_k), :]
-        s = _scaled(_dot(q, k_blk, _NT), scale)
+        return start, k_blk, _scaled(_dot(q, k_blk, _NT), scale), _dot(do, v_blk, _NT)
 
-        def cut(s):
-            k_attrs = tuple(ka_ref[a, pl.ds(kt, 1), :] for a in range(n_attr))
-            k_idx = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-            return _masked(s, rule, q_attrs, k_attrs, k_idx, length)
+    def single_step(masked):
+        def step(j, dq):
+            kt = single(j)
+            start, k_blk, s, dp = tile(kt)
+            s = cut(kt, start, s) if masked else s
+            ds = jnp.exp(s - lse) * (dp - delta)
+            return dq + _dot(ds.astype(k_blk.dtype), k_blk, _NN)
+        return step
 
-        partial = ((code & 1) == 1) | (start + block_k > length)
-        s = jax.lax.cond(partial, cut, lambda s: s, s)
+    def pair_step(j, dq):
+        ta, tb = pair(j)
+        start_a, k_a, s_a, dp_a = tile(ta)
+        start_b, k_b, s_b, dp_b = tile(tb)
+        ok_a, s = pair_scores(ta, tb, start_a, start_b, s_a, s_b)
         p = jnp.exp(s - lse)
-        dp = _dot(do, v_blk, _NT)
-        ds = p * (dp - delta)
-        return dq + _dot(ds.astype(k_blk.dtype), k_blk, _NN)
+        ds_a, ds_b = _split(ok_a, p * (jnp.where(ok_a, dp_a, dp_b) - delta), k_a.dtype)
+        return dq + _dot(ds_a, k_a, _NN) + _dot(ds_b, k_b, _NN)
 
-    dq = jax.lax.fori_loop(0, cnt_ref[iq], body, jnp.zeros((bq, D), jnp.float32))
+    dq = run(_whole_inside(single, n_whole, block_k, length), single_step, pair_step,
+             jnp.zeros((bq, D), jnp.float32))
     # the score's scale, once a tile of rows and not once a score
     dq_ref[0, 0] = _scaled(dq, scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(len_ref, tab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
+def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
                 do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                *, rule, n_attr, block_q, width, scale):
+                *, rule, n_attr, block_q, width, pair_width, union_whole, scale):
     """One K/V tile of one QUERY head; scores are held transposed,
-    [bk, bq], so that the per-query statistics broadcast along sublanes."""
+    [bk, bq], so that the per-query statistics broadcast along sublanes.
+    A pair's two query tiles have their own statistics: `s - lse` and
+    `dp - delta` are merged, then one exp and one product for both."""
     b = pl.program_id(0)
     ik = pl.program_id(2)
     bk, D = k_ref.shape[2], k_ref.shape[3]
@@ -180,37 +288,51 @@ def _dkv_kernel(len_ref, tab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
     k = k_ref[0, 0]
     v = v_ref[0, 0]
     k_attrs = tuple(ka_ref[a] for a in range(n_attr))         # each [bk, 1]
-    k_idx = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-    past_end = (ik + 1) * bk > length
+    inside = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0) < length
+    single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, ik, width, pair_width)
 
-    def body(j, carry):
-        dk, dv = carry
-        code = tab_ref[ik * width + j]
-        qt = jax.lax.shift_right_logical(code, 1)
+    def tile(qt):
         start = pl.multiple_of(qt * block_q, block_q)
         q_blk = q_ref[0, 0, pl.ds(start, block_q), :]
         do_blk = do_ref[0, 0, pl.ds(start, block_q), :]
         lse = lse_ref[0, 0, pl.ds(qt, 1), :]                  # [1, bq]
         delta = delta_ref[0, 0, pl.ds(qt, 1), :]
-        s = _scaled(_dot(k, q_blk, _NT), scale)               # [bk, bq]
+        return (q_blk, do_blk, _scaled(_dot(k, q_blk, _NT), scale) - lse,  # s - lse [bk, bq]
+                _dot(v, do_blk, _NT) - delta)
 
-        def cut(s):
-            q_attrs = tuple(qa_ref[a, pl.ds(qt, 1), :] for a in range(n_attr))
-            return _masked(s, rule, q_attrs, k_attrs, k_idx, length)
+    def side(qt):
+        return tuple(qa_ref[a, pl.ds(qt, 1), :] for a in range(n_attr)), k_attrs
 
-        partial = ((code & 1) == 1) | past_end
-        s = jax.lax.cond(partial, cut, lambda s: s, s)
-        p = jnp.exp(s - lse)
-        dv = dv + _dot(p.astype(do_blk.dtype), do_blk, _NN)
-        dp = _dot(v, do_blk, _NT)
-        ds = p * (dp - delta)
-        dk = dk + _dot(ds.astype(q_blk.dtype), q_blk, _NN)
+    def single_step(masked):
+        def step(j, carry):
+            dk, dv = carry
+            qt = single(j)
+            q_blk, do_blk, x, dpd = tile(qt)
+            if masked:
+                x = jnp.where(rule.allowed_from(*side(qt)) & inside, x, -jnp.inf)
+            p = jnp.exp(x)
+            dv = dv + _dot(p.astype(do_blk.dtype), do_blk, _NN)
+            dk = dk + _dot((p * dpd).astype(q_blk.dtype), q_blk, _NN)
+            return dk, dv
+        return step
+
+    def pair_step(j, carry):
+        dk, dv = carry
+        ta, tb = pair(j)
+        q_a, do_a, x_a, dpd_a = tile(ta)
+        q_b, do_b, x_b, dpd_b = tile(tb)
+        ok_a, x = _pair_merged(rule, side(ta), side(tb), x_a, x_b, union_whole)
+        p = jnp.exp(x + _past(inside))
+        p_a, p_b = _split(ok_a, p, do_a.dtype)
+        dv = dv + _dot(p_a, do_a, _NN) + _dot(p_b, do_b, _NN)
+        ds_a, ds_b = _split(ok_a, p * jnp.where(ok_a, dpd_a, dpd_b), q_a.dtype)
+        dk = dk + _dot(ds_a, q_a, _NN) + _dot(ds_b, q_b, _NN)
         return dk, dv
 
-    dk, dv = jax.lax.fori_loop(
-        0, cnt_ref[ik], body,
-        (jnp.zeros((bk, D), jnp.float32), jnp.zeros((bk, D), jnp.float32)),
-    )
+    # a key tile the sequence's end cuts masks every one of its query tiles
+    n_plain = jnp.where((ik + 1) * bk > length, 0, n_whole)
+    zeros = jnp.zeros((bk, D), jnp.float32)
+    dk, dv = run(n_plain, single_step, pair_step, (zeros, zeros))
     dk_ref[0, 0] = _scaled(dk, scale).astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
@@ -234,16 +356,18 @@ def _attr_arrays(rule: MaskRule, T: int, block: int):
     return attrs[:, :, None], attrs.reshape(len(attrs), T // block, block)
 
 
-def _tables(rule: MaskRule, T: int, bq: int, bk: int, transpose: bool):
-    occ = tile_occupancy(rule, T, bq, bk)
-    table, counts, width = tile_lists(occ.T if transpose else occ)
-    return jnp.asarray(table), jnp.asarray(counts), width
+def _walk(rule: MaskRule, T: int, bq: int, bk: int, transpose: bool):
+    """The host's walk as the kernels take it: the three int tables (scalar
+    memory) and the static facts of it."""
+    w = tile_walk(rule, T, bq, bk, transpose)
+    tables = tuple(map(jnp.asarray, (w.table, w.pairs, w.counts)))
+    return tables, dict(width=w.width, pair_width=w.pair_width, union_whole=w.union_whole)
 
 
 def _run_fwd(q, k, v, lengths, rule, bq, bk, scale, interpret):
     B, H, T, D = q.shape
     group = H // k.shape[1]
-    table, counts, width = _tables(rule, T, bq, bk, False)
+    tables, walk = _walk(rule, T, bq, bk, False)
     qa, _ = _attr_arrays(rule, T, bq)
     _, ka = _attr_arrays(rule, T, bk)
     n_attr = qa.shape[0]
@@ -251,11 +375,11 @@ def _run_fwd(q, k, v, lengths, rule, bq, bk, scale, interpret):
     kvspec = pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h // group, 0, 0))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, rule=rule, n_attr=n_attr, block_k=bk,
-                          width=width, scale=scale),
+                          scale=scale, **walk),
         name="attention_fwd",
         grid=(B, H, T // bq),
         in_specs=[
-            _SMEM, _SMEM, _SMEM,
+            *[_SMEM] * 4,
             pl.BlockSpec((n_attr, bq, 1), lambda b, h, i: (0, i, 0)),
             pl.BlockSpec(ka.shape, lambda b, h, i: (0, 0, 0)),
             qspec, kvspec, kvspec,
@@ -270,7 +394,7 @@ def _run_fwd(q, k, v, lengths, rule, bq, bk, scale, interpret):
         ],
         interpret=interpret,
         compiler_params=_params(),
-    )(lengths, table, counts, qa, ka, q, k, v)
+    )(lengths, *tables, qa, ka, q, k, v)
     return out, lse
 
 
@@ -285,17 +409,17 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
     ka_col, ka_row = _attr_arrays(rule, T, bk)
     n_attr = qa_col.shape[0]
 
-    table, counts, width = _tables(rule, T, bq, bk, False)
+    tables, walk = _walk(rule, T, bq, bk, False)
     qspec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0))
     kv_full = pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h // group, 0, 0))
     stat_q = pl.BlockSpec((1, 1, 1, 1, bq), lambda b, h, i: (b, h, i, 0, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, rule=rule, n_attr=n_attr, block_k=bk,
-                          width=width, scale=scale),
+                          scale=scale, **walk),
         name="attention_dq",
         grid=(B, H, nq),
         in_specs=[
-            _SMEM, _SMEM, _SMEM,
+            *[_SMEM] * 4,
             pl.BlockSpec((n_attr, bq, 1), lambda b, h, i: (0, i, 0)),
             pl.BlockSpec(ka_row.shape, lambda b, h, i: (0, 0, 0)),
             qspec, kv_full, kv_full, qspec, stat_q, stat_q,
@@ -304,20 +428,20 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         interpret=interpret,
         compiler_params=_params(),
-    )(lengths, table, counts, qa_col, ka_row, q, k, v, do, lse, delta)
+    )(lengths, *tables, qa_col, ka_row, q, k, v, do, lse, delta)
 
-    table_t, counts_t, width_t = _tables(rule, T, bq, bk, True)
+    tables, walk = _walk(rule, T, bq, bk, True)
     q_full = pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h, 0, 0))
     k_blk = pl.BlockSpec((1, 1, bk, D), lambda b, h, i: (b, h // group, i, 0))
     d_blk = pl.BlockSpec((1, 1, bk, D), lambda b, h, i: (b, h, i, 0))
     stat_full = pl.BlockSpec((1, 1, nq, bq), lambda b, h, i: (b, h, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, rule=rule, n_attr=n_attr, block_q=bq,
-                          width=width_t, scale=scale),
+                          scale=scale, **walk),
         name="attention_dkv",
         grid=(B, H, T // bk),
         in_specs=[
-            _SMEM, _SMEM, _SMEM,
+            *[_SMEM] * 4,
             pl.BlockSpec(qa_row.shape, lambda b, h, i: (0, 0, 0)),
             pl.BlockSpec((n_attr, bk, 1), lambda b, h, i: (0, i, 0)),
             q_full, k_blk, k_blk, q_full, stat_full, stat_full,
@@ -329,7 +453,7 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
         ],
         interpret=interpret,
         compiler_params=_params(),
-    )(lengths, table_t, counts_t, qa_row, ka_col, q, k, v, do,
+    )(lengths, *tables, qa_row, ka_col, q, k, v, do,
       lse.reshape(B, H, nq, bq), delta.reshape(B, H, nq, bq))
     if group > 1:
         # the query heads that share a K/V head: summed in float32
@@ -373,13 +497,23 @@ def supported(T: int, D: int, itemsize: int = 2) -> bool:
     """Shapes the kernels handle: T a multiple of a tile edge, a head the
     MXU takes whole, and a K/V head (backward: a query head and its
     cotangent) that fits the planned VMEM twice over beside the tiles'
-    float32 temporaries."""
+    float32 temporaries: 12 score-sized ones, for a pair's step holds two
+    score tiles and their two cotangents where a single's holds one of
+    each (8 were planned before tiles were paired)."""
     block = default_block(T)
     if not block or D > 256 or D % 8:
         return False
     resident = 2 * 2 * T * D * itemsize
-    tiles = 8 * block * block * 4 + 8 * block * D * 4
+    tiles = 12 * block * block * 4 + 8 * block * D * 4
     return resident + tiles <= _VMEM_PLAN
+
+
+def walk_census(rule: MaskRule, T: int) -> str:
+    """What the kernels walk under ``rule`` at `default_block(T)`, for the
+    log: a constant of the compiled program, no measurement."""
+    block = default_block(T)
+    return "; ".join(f"{name} {tile_walk(rule, T, block, block, transpose).census}"
+                     for name, transpose in (("fwd/dq", False), ("dkv", True)))
 
 
 def tpu_flash_attention(

@@ -130,6 +130,8 @@ def rule_attention(q: Array, k: Array, v: Array, lengths: Optional[Array], rule,
         why = "kernel gate refuses the shape"
     else:
         device.log_selection("rule_attention", site, f"Pallas kernel, {mode}")
+        device.log_selection("rule_attention", site,
+                             f"tile walk: {pallas_attention.walk_census(rule, T)}")
         return pallas_attention.flash_attention(
             q, k, v, lengths=lengths, rule=rule, interpret=mode == "interpret",
             scale=scale)

@@ -106,18 +106,24 @@ def _flash(T, D, dtype, B=2, H=4):
     return fn, shapes, pa.supported(T, D)
 
 
-def _rule_attention(kind, T=8192, H=32, Hkv=4, D=128, B=1):
+def _rule_attention(kind, T=8192, H=32, Hkv=4, D=128, B=1, pairs=None):
     """The flash kernel of `ops/pallas_attention.py` under a mask rule at
     the block-diffusion cell's shapes (perfbench `sdar.train`: 32 query / 4
     key-value heads of 128, 8192 positions) or at `laguna.train`'s (8
     key-value heads under 64 query heads and a 512-wide window, or under 48
     and the causal rule), as `rule_attention` runs it inside the train
-    step: forward and both backward kernels."""
+    step: forward and both backward kernels. ``pairs``: how many PAIRS
+    of partial tiles the host must have found by query tile (forward, dQ)
+    and by key tile (dK/dV), so that the case compiles the pair steps."""
     from paddle_tpu.ops import pallas_attention as pa
-    from paddle_tpu.ops.attention_mask import MaskRule
+    from paddle_tpu.ops.attention_mask import MaskRule, tile_walk
 
     rule = MaskRule(kind, 4 if kind == "block_diffusion" else 0,
                     512 if kind == "sliding_window" else 0)
+    if pairs is not None:
+        edge = pa.default_block(T)
+        assert pairs == tuple(int(tile_walk(rule, T, edge, edge, t).counts[2::3].sum())
+                              for t in (False, True))
     shapes = [((B, T, H, D), BF16), ((B, T, Hkv, D), BF16), ((B, T, Hkv, D), BF16)]
     fn = lambda q, k, v: pa.flash_attention(q, k, v, rule=rule)
     return fn, shapes, pa.supported(T, D, 2)
@@ -199,6 +205,16 @@ CASES = {
     # heads, and the partial turn of the full-attention layers
     "rule-attention-window-64-heads-t8192": lambda: _rule_attention("sliding_window", H=64, Hkv=8),
     "rule-attention-causal-48-heads-t8192": lambda: _rule_attention("causal", H=48, Hkv=8),
+    # the cells' whole shapes with the pair steps in: laguna.train's window
+    # layers (a pair a row of tiles both ways), sdar.train's (a pair a noised
+    # query tile, none by key tile); and the longest axis the gate admits at
+    # this head, whose plan counts a pair's second score tile
+    "rule-attention-pairs-window-4x64-heads-t8192": lambda: _rule_attention(
+        "sliding_window", H=64, Hkv=8, B=4, pairs=(15, 15)),
+    "rule-attention-pairs-block-diffusion-4x32-heads-t8192": lambda: _rule_attention(
+        "block_diffusion", B=4, pairs=(8, 0)),
+    "rule-attention-pairs-window-t26624": lambda: _rule_attention(
+        "sliding_window", T=26624, H=8, Hkv=8, pairs=(51, 51)),
     "head-prologue-partial-q-48x128": lambda: _head_prologue_partial(48),
     "head-prologue-partial-k-8x128": lambda: _head_prologue_partial(8),
     # a ResNet-50 1x1 at B=256: stage-1 expand, 56x56 pixels, 64 -> 256
@@ -218,6 +234,18 @@ def test_kernel_compiles_where_its_gate_says_yes(case, grad, chip):
             f"{type(e).__name__}: {str(e)[:400]}")
         return
     assert "tpu_custom_call" in hlo, f"{case}: no Mosaic kernel in the HLO"
+
+
+def test_the_flash_gate_plans_for_a_pairs_second_score_tile():
+    """`supported()` plans 12 score-sized float32 temporaries beside the
+    resident heads, not the 8 of the unpaired kernels: at a head of 128 in
+    bfloat16 the longest axis it admits is 52 tiles of 512 (compiled above
+    with its pairs in), and 53, which 8 would admit, it refuses."""
+    from paddle_tpu.ops import pallas_attention as pa
+
+    plan = lambda T, tiles: 4 * T * 128 * 2 + (tiles * 512 * 512 + 8 * 512 * 128) * 4
+    assert pa.supported(26624, 128) and plan(26624, 12) <= pa._VMEM_PLAN
+    assert not pa.supported(27136, 128) and plan(27136, 8) <= pa._VMEM_PLAN < plan(27136, 12)
 
 
 def test_a_recomputation_block_runs_the_flash_forward_once(chip):

@@ -28,7 +28,8 @@ import pytest
 from paddle_tpu.graph.argument import Argument
 from paddle_tpu.layers.base import LayerContext
 from paddle_tpu.ops import grouped_matmul
-from paddle_tpu.ops.attention_mask import MaskRule, tile_occupancy
+from paddle_tpu.ops import pallas_attention
+from paddle_tpu.ops.attention_mask import MaskRule, TileWalk, tile_occupancy, tile_walk
 from paddle_tpu.ops.pallas_attention import flash_attention
 from paddle_tpu.parallel.sequence_parallel import rule_attention
 from paddle_tpu.proto import LayerConfig, ModelConfig
@@ -133,6 +134,141 @@ def test_window_tiles_at_the_cell_size(edge, tiles, whole):
     idx = np.arange(8192)
     assert sum(int(rule.allowed(idx[i:i + edge], idx, 8192).sum())
                for i in range(0, 8192, edge)) == allowed
+
+
+# ------------------------------------------------------- paired partial tiles
+
+RULES = {
+    "full": MaskRule("full"),
+    "causal": MaskRule("causal"),
+    "window": MaskRule("sliding_window", window=512),
+    "block_diffusion": MaskRule("block_diffusion", 4),
+}
+
+
+def _walked(walk):
+    """A walk's tiles a row: its whole singles, its partial singles, [(A, B)] of its pairs."""
+    counts = walk.counts.reshape(-1, 3)
+    table = walk.table.reshape(len(counts), -1)
+    pairs = walk.pairs.reshape(len(counts), -1, 2)
+    return [(list(table[r, :w]), list(table[r, w:n]), [tuple(ab) for ab in pairs[r, :p]])
+            for r, (w, n, p) in enumerate(counts)]
+
+
+@pytest.mark.parametrize("transpose", [False, True], ids=["by_query_tile", "by_key_tile"])
+@pytest.mark.parametrize("kind", sorted(RULES))
+def test_the_walk_holds_every_tile_once_and_pairs_only_disjoint_ones(kind, transpose):
+    """The host's table at the cells' size (8,192 positions, 512-wide
+    tiles), by query tile (forward, dQ) and by key tile (dK/dV): every
+    non-empty tile of `tile_occupancy` is walked exactly once, as a single
+    (a row's whole tiles before its partial ones) or in a pair; a pair is two PARTIAL tiles whose
+    allowed sets, in tile-local coordinates, share nothing; `union_whole`
+    says whether together they are the whole tile. The window gives 15
+    pairs and 1 single a direction, block diffusion a pair a noised query
+    tile and none by key tile, causal and full none."""
+    rule, T, edge = RULES[kind], 8192, 512
+    occ = tile_occupancy(rule, T, edge, edge)
+    occ = occ.T if transpose else occ
+    walk = tile_walk(rule, T, edge, edge, transpose)
+    idx = lambda t: np.arange(t * edge, (t + 1) * edge)
+    local = lambda r, c: rule.allowed(*((idx(c), idx(r)) if transpose else (idx(r), idx(c))), T)
+    whole_unions = []
+    for r, (whole, partial, pairs) in enumerate(_walked(walk)):
+        assert whole == list(np.flatnonzero(occ[r] == 1))
+        assert sorted(partial + [t for ab in pairs for t in ab]) == list(np.flatnonzero(occ[r] == 2))
+        assert partial == sorted(partial)
+        for a, b in pairs:
+            assert a != b and not (local(r, a) & local(r, b)).any()
+            whole_unions.append(bool((local(r, a) | local(r, b)).all()))
+    n_pairs = len(whole_unions)
+    want = {"window": 15, "block_diffusion": 0 if transpose else 8, "causal": 0, "full": 0}
+    assert n_pairs == want[kind] and (walk.pair_width > 0) == (n_pairs > 0)
+    assert walk.union_whole == (n_pairs > 0 and all(whole_unions)) == (kind == "window")
+    if kind == "window":                        # the first row (the last, by key tile)
+        assert walk.counts.reshape(-1, 3)[:, 1].sum() == 1
+    assert f"{2 * n_pairs} paired" in walk.census
+
+
+def _unpaired(rule, T, bq, bk, transpose=False):
+    """The walk with no tile paired: every partial tile a single."""
+    occ = tile_occupancy(rule, T, bq, bk)
+    occ = occ.T if transpose else occ
+    rows = [list(np.flatnonzero(row == 1)) + list(np.flatnonzero(row == 2)) for row in occ]
+    table = np.zeros((len(occ), max(map(len, rows))), np.int32)
+    for r, row in enumerate(rows):
+        table[r, : len(row)] = row
+    counts = np.asarray([[(row == 1).sum(), (row > 0).sum(), 0] for row in occ], np.int32)
+    return TileWalk(table.reshape(-1), np.zeros(2 * len(occ), np.int32), counts.reshape(-1),
+                    table.shape[1], 0, False, "unpaired")
+
+
+PAIRED = {
+    # id: (rule, positions, query heads a key/value head, lengths, pairs by query tile)
+    "window_is_the_tile": (MaskRule("sliding_window", window=16), 64, 6, None, 3),
+    "window_two_tiles": (MaskRule("sliding_window", window=32), 64, 8, None, 2),
+    "window_half_a_tile": (MaskRule("sliding_window", window=8), 64, 6, None, 3),
+    "window_no_multiple": (MaskRule("sliding_window", window=40), 96, 8, None, 3),
+    "block_diffusion": (MaskRule("block_diffusion", 4), 128, 8, None, 4),
+    "causal": (MaskRule("causal"), 64, 6, None, 0),
+    # the first sequence ends inside the second tile of its last row's pair,
+    # the second inside the second tile of row 1's, with whole rows past it
+    "short_in_the_second_tile": (MaskRule("sliding_window", window=16), 64, 8, (59, 23), 3),
+    "short_window_half_a_tile": (MaskRule("sliding_window", window=8), 64, 6, (64, 37), 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAIRED))
+def test_paired_tiles_match_the_xla_path(case, monkeypatch):
+    """The three kernels (interpreted, 16-wide tiles) with the pairs the
+    host finds against the XLA path of `rule_attention`: the output and
+    the gradients of q, k and v. Against the same kernels walking every
+    tile as a single: close where tiles are paired, and bit for bit where
+    the rule has no pair (causal), whose kernels are the unpaired ones."""
+    rule, T, group, lengths, n_pairs = PAIRED[case]
+    assert int(tile_walk(rule, T, 16, 16).counts[2::3].sum()) == n_pairs
+    q, k, v = _qkv(T, 2 * group, 2, 16, len(case))
+    w = jnp.asarray(np.random.RandomState(5).randn(*q.shape).astype(np.float32))
+    if lengths is not None:
+        lengths = jnp.asarray(lengths, jnp.int32)
+        # padded query rows are unspecified on both paths: out of the comparison
+        w = w * (jnp.arange(T)[None, :] < lengths[:, None])[:, :, None, None]
+    flash = lambda q, k, v: flash_attention(q, k, v, lengths=lengths, rule=rule,
+                                            interpret=True, block=16)
+    xla = lambda q, k, v: rule_attention(q, k, v, lengths, rule)
+    both = lambda f: (f(q, k, v) * (w != 0),) + jax.grad(
+        lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    paired = both(flash)
+    for got, want, tol in zip(paired, both(xla), (2e-5, 1e-4, 1e-4, 1e-4)):
+        np.testing.assert_allclose(got, want, atol=tol)
+    monkeypatch.setattr(pallas_attention, "tile_walk", _unpaired)
+    for got, want in zip(paired, both(flash)):
+        if n_pairs:
+            np.testing.assert_allclose(got, want, atol=1e-5)
+        else:
+            np.testing.assert_array_equal(got, want)
+
+
+def test_the_walks_census_goes_out_once_beside_the_kernels_selection(monkeypatch, caplog):
+    """`rule_attention` says once a site what its kernels walk (whole,
+    partial and paired tiles, by query tile and by key tile): how one sees
+    that pairing engaged; a constant of the program and no metric."""
+    from paddle_tpu.utils import device
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(device, "_said", set())
+    rule = MaskRule("sliding_window", window=512)
+    q, k, v = _qkv(1024, 2, 1, 8, 3)
+    device.logger.addHandler(caplog.handler)        # the package's logger does not propagate
+    try:
+        with caplog.at_level("DEBUG", logger=device.logger.name):
+            for _ in range(2):
+                rule_attention(q, k, v, None, rule)
+    finally:
+        device.logger.removeHandler(caplog.handler)
+    said = [r.getMessage() for r in caplog.records if "tile walk" in r.getMessage()]
+    assert said == ["rule_attention T=1024 D=8 sliding_window: tile walk: "
+                    "fwd/dq 0 whole + 1 partial + 2 paired tiles in 2 rows; "
+                    "dkv 0 whole + 1 partial + 2 paired tiles in 2 rows"]
 
 
 # --------------------------------------------- the head prologue, a partial turn
