@@ -32,6 +32,31 @@ scale in one pass each way under a hand-written backward,
 `ops/pallas_head_prologue.py`, the kernels ``head_prologue_fwd`` /
 ``head_prologue_bwd`` where a kernel can run), ``core`` (scores, softmax,
 values), ``gate`` and ``out``.
+
+With ``kv_latent_dim`` set the layer is the LATENT form (multi-head latent
+attention): keys and values come, a head, from one ``kv_latent_dim``-wide
+latent a position, and a head's scores are over ``head_dim`` lanes of its
+own plus ``rope_head_dim`` rotary lanes that ALL the query heads read from
+one shared key head; its values are ``value_head_dim`` wide. x [T, D] the
+layer's input, H = ``num_heads``, dn / dr / dv the three widths::
+
+    q = x Wq                         Wq [D, H (dn + dr)]; a head: [q_nope | q_rope]
+    a = x Wkv_a                      Wkv_a [D, latent + dr]
+    c = RMSNorm(a[:, :latent]; g)    g [latent];  k_rope = a[:, latent:]
+    u = c Wkv_b                      Wkv_b [latent, H (dn + dv)]; a head: [k_nope | v]
+    s_ij = (q_nope_i . k_nope_j + turn(q_rope)_i . turn(k_rope)_j) / sqrt(dn + dr)
+    y = (softmax_j(s) v) Wo          Wo [H dv, size]
+
+Parameters ``_<name>.wq``, ``.wkv_a``, ``.kv_norm`` [1, latent] (float32),
+``.wkv_b``, ``.wo``. The two score parts go to the flash kernels as they
+are (`ops/pallas_attention.py`: the shared rotary key head is never
+broadcast in memory, the values keep their own width); the rotary lanes
+are turned by the same prologue, with ``rope_interleave`` as a static
+order of the weights' rotary columns (`pallas_head_prologue.
+interleaved_order`). Scopes: ``qkv`` (q's product, the turn of q_rope, the
+scores' scale), ``latent_down`` (x Wkv_a, the latent's norm, k_rope's
+turn), ``latent_up`` (c Wkv_b), ``core``, ``out``. A recomputation block
+keeps the kernel's ``out`` and ``lse``; c, k_nope and v are recomputed.
 """
 
 from __future__ import annotations
@@ -61,6 +86,8 @@ def multi_head_attention(cfg: LayerConfig, inputs: List[Argument], ctx: LayerCon
     assert arg.is_seq and arg.value is not None, (
         f"{cfg.name}: multi_head_attention needs a dense sequence input"
     )
+    if cfg.kv_latent_dim:
+        return _latent_attention(cfg, arg, ctx)
     if cfg.head_dim:
         return _grouped_query_attention(cfg, arg, ctx)
     x = arg.value                                   # [B, T, D]
@@ -101,30 +128,49 @@ def rms_normalize(x: Array, gain: Array, eps: float) -> Array:
     return (xf * inv * hp(gain)).astype(x.dtype)
 
 
-def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext) -> Argument:
+def _rule_and_positions(cfg: LayerConfig, ctx: LayerContext, T: int):
+    """What the forms with a mask rule share before their products: the
+    rule, checked against T, and the position the rule gives each index."""
     from paddle_tpu.ops.attention_mask import rule_of
-    from paddle_tpu.ops.pallas_head_prologue import head_prologue, turn_tables
-    from paddle_tpu.parallel.sequence_parallel import rule_attention
 
     if ctx.mesh is not None and cfg.seq_parallel_mode:
         raise NotImplementedError(
-            f"{cfg.name}: grouped-query attention with a mask rule does not "
+            f"{cfg.name}: attention with a mask rule does not "
             "run sequence-parallel yet; drop seq_parallel or the mesh's seq axis")
+    rule = rule_of(cfg.attention_mask, cfg.mask_block_length, cfg.causal_attention,
+                   cfg.mask_window)
+    rule.check(T)
+    return rule, rule.positions(jnp.arange(T, dtype=jnp.int32), T)
+
+
+def _project_out(cfg: LayerConfig, arg: Argument, ctx: LayerContext, out: Array) -> Argument:
+    """The tail those forms share: heads' results [B, T, H, Dv] -> ``wo``,
+    the layer's bias and activation, padded positions zeroed."""
+    B, T = out.shape[:2]
+    with jax.named_scope("out"):
+        value = jnp.einsum("bte,ed->btd", out.reshape(B, T, -1),
+                           ctx.param(f"_{cfg.name}.wo"))
+    value = finalize_output(cfg, value, ctx, mask=arg.seq_mask())
+    value = value * arg.seq_mask(dtype=value.dtype)[..., None]
+    return with_seq_meta(arg, value)
+
+
+def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext) -> Argument:
+    from paddle_tpu.ops.pallas_head_prologue import head_prologue, turn_tables
+    from paddle_tpu.parallel.sequence_parallel import rule_attention
+
     x = arg.value                                   # [B, T, D]
     B, T, _ = x.shape
     H, Dh = cfg.num_heads, cfg.head_dim
     Hkv = cfg.num_kv_heads or H
     assert H % Hkv == 0, f"{cfg.name}: {H} query heads over {Hkv} key/value heads"
-    rule = rule_of(cfg.attention_mask, cfg.mask_block_length, cfg.causal_attention,
-                   cfg.mask_window)
-    rule.check(T)
+    rule, pos = _rule_and_positions(cfg, ctx, T)
     with jax.named_scope("qkv"):
         q = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wq"))
         k = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wk"))
         v = jnp.einsum("btd,de->bte", x, ctx.param(f"_{cfg.name}.wv")).reshape(B, T, Hkv, Dh)
         gains = [ctx.param(f"_{cfg.name}.{n}", cast=False)[0] if cfg.qk_norm else None
                  for n in ("q_norm", "k_norm")]
-        pos = rule.positions(jnp.arange(T, dtype=jnp.int32), T)
         rot = cfg.rotary_dim or Dh
         turn = turn_tables(pos, cfg.rope_theta, Dh, rot, tuple(cfg.rope_yarn) or None,
                            cfg.rope_attention_factor) if cfg.rope_theta else None
@@ -141,9 +187,51 @@ def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext)
             g = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", x, ctx.param(f"_{cfg.name}.wg"),
                                           preferred_element_type=jnp.float32))
             out = (out.astype(jnp.float32) * g[..., None]).astype(out.dtype)
-    with jax.named_scope("out"):
-        value = jnp.einsum("bte,ed->btd", out.reshape(B, T, H * Dh),
-                           ctx.param(f"_{cfg.name}.wo"))
-    value = finalize_output(cfg, value, ctx, mask=arg.seq_mask())
-    value = value * arg.seq_mask(dtype=value.dtype)[..., None]
-    return with_seq_meta(arg, value)
+    return _project_out(cfg, arg, ctx, out)
+
+
+def _latent_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext) -> Argument:
+    """The latent form (module docstring)."""
+    from paddle_tpu.ops.pallas_head_prologue import (
+        head_prologue, interleaved_order, turn_tables)
+    from paddle_tpu.parallel.sequence_parallel import rule_attention
+
+    x = arg.value                                   # [B, T, D]
+    B, T, D = x.shape
+    H, dn, dr, latent = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim, cfg.kv_latent_dim
+    dv = cfg.value_head_dim or dn
+    rule, pos = _rule_and_positions(cfg, ctx, T)
+    turn = turn_tables(pos, cfg.rope_theta, dr, dr, tuple(cfg.rope_yarn) or None,
+                       cfg.rope_attention_factor)
+    # the rotary lanes' order, on the weights' columns (a score sums over
+    # lanes: q_rope and k_rope reordered alike give the same scores)
+    order = interleaved_order(dr) if cfg.rope_interleave else slice(None)
+    # a part with rotary lanes goes through the head prologue, which turns it
+    # and leaves [B, H, T, dr] as the flash kernels read it: relabelled to
+    # rule_attention's [B, T, H, dr] here, its own transpose undoes this one.
+    # The other parts need no prologue (no per-head norm, no turn): they stay
+    # as the products leave them, and nothing of them is kept for a backward
+    turned = lambda y, scale: head_prologue(
+        y, None, turn, dr, cfg.norm_epsilon, scale).transpose(0, 2, 1, 3)
+    with jax.named_scope("qkv"):
+        wq = ctx.param(f"_{cfg.name}.wq").reshape(D, H, dn + dr)
+        # the scores' 1/sqrt(dn + dr) is folded into both parts of q
+        scale = (dn + dr) ** -0.5
+        q_nope = jnp.einsum("btd,de->bte", x, wq[:, :, :dn].reshape(D, H * dn))
+        q_nope = (q_nope.astype(jnp.float32) * scale).astype(x.dtype).reshape(B, T, H, dn)
+        q_rope = turned(jnp.einsum("btd,de->bte", x, wq[:, :, dn:][:, :, order].reshape(D, H * dr)),
+                        scale)
+    with jax.named_scope("latent_down"):
+        wkv_a = ctx.param(f"_{cfg.name}.wkv_a")
+        c = rms_normalize(jnp.einsum("btd,de->bte", x, wkv_a[:, :latent]),
+                          ctx.param(f"_{cfg.name}.kv_norm", cast=False)[0], cfg.norm_epsilon)
+        # ONE key head of rotary lanes for all the query heads
+        k_rope = turned(jnp.einsum("btd,de->bte", x, wkv_a[:, latent:][:, order]), 1.0)
+    with jax.named_scope("latent_up"):
+        wkv_b = ctx.param(f"_{cfg.name}.wkv_b").reshape(latent, H, dn + dv)
+        k_nope = jnp.einsum("bte,ef->btf", c, wkv_b[:, :, :dn].reshape(latent, H * dn)).reshape(B, T, H, dn)
+        v = jnp.einsum("bte,ef->btf", c, wkv_b[:, :, dn:].reshape(latent, H * dv)).reshape(B, T, H, dv)
+    with jax.named_scope("core"):
+        out = rule_attention((q_nope, q_rope), (k_nope, k_rope), v, arg.seq_lengths, rule,
+                             scale=1.0)
+    return _project_out(cfg, arg, ctx, out)

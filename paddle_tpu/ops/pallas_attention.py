@@ -33,7 +33,17 @@ dK/dV come back a query head and are summed over each group outside.
 
 Layout: q [B, H, T, D], k/v [B, Hkv, T, D] inside the kernels (callers
 transpose from the [B, T, H, D] sequence_parallel layout). A K/V head
-stays whole in VMEM while its query heads' tiles pass. Correctness is
+stays whole in VMEM while its query heads' tiles pass.
+
+The scores may come in PARTS: q and k are then tuples of as many arrays,
+part i ``[B, H, T, D_i]`` against ``[B, Hk_i, T, D_i]`` with a head count of
+its own (latent attention: 128 lanes that differ by head, and 64 rotary
+lanes that every query head reads from ONE key head, which is never
+broadcast in memory); a tile's score products are summed before the mask
+and the softmax's vector work, dq and dk come back a part, and a part's dk
+is summed over the query heads that share its key head. The values have a
+width of their own (``v [B, Hkv, T, Dv]``, and so the output). One part of
+the values' width is the kernel as it always was. Correctness is
 tested in interpret mode on CPU against the XLA path
 (tests/test_pallas_attention.py, tests/test_block_diffusion_moe.py); what
 Mosaic accepts, by compiling for a described v5e
@@ -44,6 +54,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 from typing import Optional
 
 import jax
@@ -78,6 +89,16 @@ def _dot(a, b, dims):
 
 _NT = ((1,), (1,))      # a @ b.T
 _NN = ((1,), (0,))      # a @ b
+
+
+def _scores(a_parts, b_parts):
+    """sum over the score parts of a_i @ b_i.T."""
+    return functools.reduce(operator.add, (_dot(a, b, _NT) for a, b in zip(a_parts, b_parts)))
+
+
+def _rows(refs, start, size):
+    """A tile of rows of each part's [1, 1, T, D_i] block."""
+    return tuple(r[0, 0, pl.ds(start, size), :] for r in refs)
 
 
 def _col(row):
@@ -175,22 +196,23 @@ def _key_masks(rule, q_attrs, ka_ref, block_k, length, union_whole):
     return cut, pair_scores
 
 
-def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
-                o_ref, lse_ref, *, rule, n_attr, block_k, width, pair_width, union_whole, scale):
+def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
+                rule, n_attr, parts, block_k, width, pair_width, union_whole, scale):
+    q_refs, k_refs, (v_ref, o_ref, lse_ref) = refs[:parts], refs[parts:2 * parts], refs[2 * parts:]
     b = pl.program_id(0)
     iq = pl.program_id(2)
-    bq, D = q_ref.shape[2], q_ref.shape[3]
+    bq, D = q_refs[0].shape[2], v_ref.shape[3]
     length = len_ref[b]
-    q = q_ref[0, 0]                                           # [bq, D]
+    q = tuple(r[0, 0] for r in q_refs)                        # each [bq, D_i]
     q_attrs = tuple(qa_ref[a] for a in range(n_attr))         # each [bq, 1]
     single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, iq, width, pair_width)
     cut, pair_scores = _key_masks(rule, q_attrs, ka_ref, block_k, length, union_whole)
 
     def tile(kt):
         start = pl.multiple_of(kt * block_k, block_k)
-        k_blk = k_ref[0, 0, pl.ds(start, block_k), :]
+        k_blk = _rows(k_refs, start, block_k)
         v_blk = v_ref[0, 0, pl.ds(start, block_k), :]
-        return start, v_blk, _scaled(_dot(q, k_blk, _NT), scale)           # s [bq, bk]
+        return start, v_blk, _scaled(_scores(q, k_blk), scale)             # s [bq, bk]
 
     def softmax_step(carry, s, pv):
         o, m, l = carry
@@ -229,14 +251,16 @@ def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_re
     lse_ref[0, 0, 0] = _row(jnp.where(l > 0, m + jnp.log(l_safe), _NEG))
 
 
-def _dq_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
-               do_ref, lse_ref, delta_ref, dq_ref,
-               *, rule, n_attr, block_k, width, pair_width, union_whole, scale):
+def _dq_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
+               rule, n_attr, parts, block_k, width, pair_width, union_whole, scale):
+    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
+    v_ref, do_ref, lse_ref, delta_ref = refs[2 * parts:2 * parts + 4]
+    dq_refs = refs[2 * parts + 4:]
     b = pl.program_id(0)
     iq = pl.program_id(2)
-    bq, D = q_ref.shape[2], q_ref.shape[3]
+    bq = q_refs[0].shape[2]
     length = len_ref[b]
-    q = q_ref[0, 0]
+    q = tuple(r[0, 0] for r in q_refs)
     do = do_ref[0, 0]
     lse = _col(lse_ref[0, 0, 0])                              # [bq, 1]
     delta = _col(delta_ref[0, 0, 0])
@@ -246,17 +270,20 @@ def _dq_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref
 
     def tile(kt):
         start = pl.multiple_of(kt * block_k, block_k)
-        k_blk = k_ref[0, 0, pl.ds(start, block_k), :]
+        k_blk = _rows(k_refs, start, block_k)
         v_blk = v_ref[0, 0, pl.ds(start, block_k), :]
-        return start, k_blk, _scaled(_dot(q, k_blk, _NT), scale), _dot(do, v_blk, _NT)
+        return start, k_blk, _scaled(_scores(q, k_blk), scale), _dot(do, v_blk, _NT)
+
+    def add(dq, ds, k_blk):
+        ds = ds.astype(k_blk[0].dtype)
+        return tuple(d + _dot(ds, k, _NN) for d, k in zip(dq, k_blk))
 
     def single_step(masked):
         def step(j, dq):
             kt = single(j)
             start, k_blk, s, dp = tile(kt)
             s = cut(kt, start, s) if masked else s
-            ds = jnp.exp(s - lse) * (dp - delta)
-            return dq + _dot(ds.astype(k_blk.dtype), k_blk, _NN)
+            return add(dq, jnp.exp(s - lse) * (dp - delta), k_blk)
         return step
 
     def pair_step(j, dq):
@@ -265,27 +292,30 @@ def _dq_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref
         start_b, k_b, s_b, dp_b = tile(tb)
         ok_a, s = pair_scores(ta, tb, start_a, start_b, s_a, s_b)
         p = jnp.exp(s - lse)
-        ds_a, ds_b = _split(ok_a, p * (jnp.where(ok_a, dp_a, dp_b) - delta), k_a.dtype)
-        return dq + _dot(ds_a, k_a, _NN) + _dot(ds_b, k_b, _NN)
+        ds_a, ds_b = _split(ok_a, p * (jnp.where(ok_a, dp_a, dp_b) - delta), k_a[0].dtype)
+        return tuple(d + _dot(ds_a, a, _NN) + _dot(ds_b, b, _NN) for d, a, b in zip(dq, k_a, k_b))
 
     dq = run(_whole_inside(single, n_whole, block_k, length), single_step, pair_step,
-             jnp.zeros((bq, D), jnp.float32))
+             tuple(jnp.zeros((bq, r.shape[3]), jnp.float32) for r in q_refs))
     # the score's scale, once a tile of rows and not once a score
-    dq_ref[0, 0] = _scaled(dq, scale).astype(dq_ref.dtype)
+    for d, ref in zip(dq, dq_refs):
+        ref[0, 0] = _scaled(d, scale).astype(ref.dtype)
 
 
-def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_ref, v_ref,
-                do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                *, rule, n_attr, block_q, width, pair_width, union_whole, scale):
+def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
+                rule, n_attr, parts, block_q, width, pair_width, union_whole, scale):
     """One K/V tile of one QUERY head; scores are held transposed,
     [bk, bq], so that the per-query statistics broadcast along sublanes.
     A pair's two query tiles have their own statistics: `s - lse` and
     `dp - delta` are merged, then one exp and one product for both."""
+    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
+    v_ref, do_ref, lse_ref, delta_ref = refs[2 * parts:2 * parts + 4]
+    dk_refs, dv_ref = refs[2 * parts + 4:-1], refs[-1]
     b = pl.program_id(0)
     ik = pl.program_id(2)
-    bk, D = k_ref.shape[2], k_ref.shape[3]
+    bk = k_refs[0].shape[2]
     length = len_ref[b]
-    k = k_ref[0, 0]
+    k = tuple(r[0, 0] for r in k_refs)
     v = v_ref[0, 0]
     k_attrs = tuple(ka_ref[a] for a in range(n_attr))         # each [bk, 1]
     inside = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0) < length
@@ -293,11 +323,11 @@ def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_re
 
     def tile(qt):
         start = pl.multiple_of(qt * block_q, block_q)
-        q_blk = q_ref[0, 0, pl.ds(start, block_q), :]
+        q_blk = _rows(q_refs, start, block_q)
         do_blk = do_ref[0, 0, pl.ds(start, block_q), :]
         lse = lse_ref[0, 0, pl.ds(qt, 1), :]                  # [1, bq]
         delta = delta_ref[0, 0, pl.ds(qt, 1), :]
-        return (q_blk, do_blk, _scaled(_dot(k, q_blk, _NT), scale) - lse,  # s - lse [bk, bq]
+        return (q_blk, do_blk, _scaled(_scores(k, q_blk), scale) - lse,    # s - lse [bk, bq]
                 _dot(v, do_blk, _NT) - delta)
 
     def side(qt):
@@ -312,8 +342,8 @@ def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_re
                 x = jnp.where(rule.allowed_from(*side(qt)) & inside, x, -jnp.inf)
             p = jnp.exp(x)
             dv = dv + _dot(p.astype(do_blk.dtype), do_blk, _NN)
-            dk = dk + _dot((p * dpd).astype(q_blk.dtype), q_blk, _NN)
-            return dk, dv
+            ds = (p * dpd).astype(q_blk[0].dtype)
+            return tuple(d + _dot(ds, q, _NN) for d, q in zip(dk, q_blk)), dv
         return step
 
     def pair_step(j, carry):
@@ -325,15 +355,16 @@ def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, q_ref, k_re
         p = jnp.exp(x + _past(inside))
         p_a, p_b = _split(ok_a, p, do_a.dtype)
         dv = dv + _dot(p_a, do_a, _NN) + _dot(p_b, do_b, _NN)
-        ds_a, ds_b = _split(ok_a, p * jnp.where(ok_a, dpd_a, dpd_b), q_a.dtype)
-        dk = dk + _dot(ds_a, q_a, _NN) + _dot(ds_b, q_b, _NN)
-        return dk, dv
+        ds_a, ds_b = _split(ok_a, p * jnp.where(ok_a, dpd_a, dpd_b), q_a[0].dtype)
+        return tuple(d + _dot(ds_a, a, _NN) + _dot(ds_b, b, _NN)
+                     for d, a, b in zip(dk, q_a, q_b)), dv
 
     # a key tile the sequence's end cuts masks every one of its query tiles
     n_plain = jnp.where((ik + 1) * bk > length, 0, n_whole)
-    zeros = jnp.zeros((bk, D), jnp.float32)
-    dk, dv = run(n_plain, single_step, pair_step, (zeros, zeros))
-    dk_ref[0, 0] = _scaled(dk, scale).astype(dk_ref.dtype)
+    zeros = lambda ref: jnp.zeros((bk, ref.shape[3]), jnp.float32)
+    dk, dv = run(n_plain, single_step, pair_step, (tuple(map(zeros, k_refs)), zeros(v_ref)))
+    for d, ref in zip(dk, dk_refs):
+        ref[0, 0] = _scaled(d, scale).astype(ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
@@ -364,17 +395,30 @@ def _walk(rule: MaskRule, T: int, bq: int, bk: int, transpose: bool):
     return tables, dict(width=w.width, pair_width=w.pair_width, union_whole=w.union_whole)
 
 
+def _head_blocks(arrays, rows, tiled, H=0):
+    """A BlockSpec a [B, heads, T, D] array for a grid (batch, query head,
+    tile): ``rows`` rows of a head, the grid's tile of them where
+    ``tiled``, else all (rows = T). The head is the grid's query head, or
+    with ``H`` (the query heads: a key or value operand, whose heads may be
+    fewer) the one that query head h reads, ``h // (H / heads)``."""
+    def spec(x):
+        group = H // x.shape[1] if H else 0
+        head = (lambda h: h // group) if H else (lambda h: h)
+        tile = (lambda i: i) if tiled else (lambda i: 0)
+        return pl.BlockSpec((1, 1, rows, x.shape[3]), lambda b, h, i: (b, head(h), tile(i), 0))
+    return [spec(x) for x in arrays]
+
+
 def _run_fwd(q, k, v, lengths, rule, bq, bk, scale, interpret):
-    B, H, T, D = q.shape
-    group = H // k.shape[1]
+    """q, k: tuples of the score parts (module docstring)."""
+    B, H, T, _ = q[0].shape
     tables, walk = _walk(rule, T, bq, bk, False)
     qa, _ = _attr_arrays(rule, T, bq)
     _, ka = _attr_arrays(rule, T, bk)
     n_attr = qa.shape[0]
-    qspec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0))
-    kvspec = pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h // group, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((B, H, T, v.shape[3]), q[0].dtype)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, rule=rule, n_attr=n_attr, block_k=bk,
+        functools.partial(_fwd_kernel, rule=rule, n_attr=n_attr, parts=len(q), block_k=bk,
                           scale=scale, **walk),
         name="attention_fwd",
         grid=(B, H, T // bq),
@@ -382,26 +426,25 @@ def _run_fwd(q, k, v, lengths, rule, bq, bk, scale, interpret):
             *[_SMEM] * 4,
             pl.BlockSpec((n_attr, bq, 1), lambda b, h, i: (0, i, 0)),
             pl.BlockSpec(ka.shape, lambda b, h, i: (0, 0, 0)),
-            qspec, kvspec, kvspec,
+            *_head_blocks(q, bq, True), *_head_blocks(k + (v,), T, False, H),
         ],
         out_specs=[
-            qspec,
+            *_head_blocks([out_shape], bq, True),
             pl.BlockSpec((1, 1, 1, 1, bq), lambda b, h, i: (b, h, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
+            out_shape,
             jax.ShapeDtypeStruct((B, H, T // bq, 1, bq), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_params(),
-    )(lengths, *tables, qa, ka, q, k, v)
+    )(lengths, *tables, qa, ka, *q, *k, v)
     return out, lse
 
 
 def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
-    B, H, T, D = q.shape
-    Hkv = k.shape[1]
-    group = H // Hkv
+    B, H, T, _ = q[0].shape
+    parts = len(q)
     nq = T // bq
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     delta = delta.reshape(B, H, nq, 1, bq)
@@ -410,11 +453,9 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
     n_attr = qa_col.shape[0]
 
     tables, walk = _walk(rule, T, bq, bk, False)
-    qspec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0))
-    kv_full = pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h // group, 0, 0))
     stat_q = pl.BlockSpec((1, 1, 1, 1, bq), lambda b, h, i: (b, h, i, 0, 0))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, rule=rule, n_attr=n_attr, block_k=bk,
+        functools.partial(_dq_kernel, rule=rule, n_attr=n_attr, parts=parts, block_k=bk,
                           scale=scale, **walk),
         name="attention_dq",
         grid=(B, H, nq),
@@ -422,21 +463,21 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
             *[_SMEM] * 4,
             pl.BlockSpec((n_attr, bq, 1), lambda b, h, i: (0, i, 0)),
             pl.BlockSpec(ka_row.shape, lambda b, h, i: (0, 0, 0)),
-            qspec, kv_full, kv_full, qspec, stat_q, stat_q,
+            *_head_blocks(q, bq, True), *_head_blocks(k + (v,), T, False, H),
+            *_head_blocks([do], bq, True), stat_q, stat_q,
         ],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
+        out_specs=_head_blocks(q, bq, True),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q],
         interpret=interpret,
         compiler_params=_params(),
-    )(lengths, *tables, qa_col, ka_row, q, k, v, do, lse, delta)
+    )(lengths, *tables, qa_col, ka_row, *q, *k, v, do, lse, delta)
 
     tables, walk = _walk(rule, T, bq, bk, True)
-    q_full = pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h, 0, 0))
-    k_blk = pl.BlockSpec((1, 1, bk, D), lambda b, h, i: (b, h // group, i, 0))
-    d_blk = pl.BlockSpec((1, 1, bk, D), lambda b, h, i: (b, h, i, 0))
     stat_full = pl.BlockSpec((1, 1, nq, bq), lambda b, h, i: (b, h, 0, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, rule=rule, n_attr=n_attr, block_q=bq,
+    # a gradient a QUERY head: [B, H, T, D_i] for part i's keys, [B, H, T, Dv]
+    d_shapes = [jax.ShapeDtypeStruct((B, H, T, x.shape[3]), x.dtype) for x in k + (v,)]
+    *dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, rule=rule, n_attr=n_attr, parts=parts, block_q=bq,
                           scale=scale, **walk),
         name="attention_dkv",
         grid=(B, H, T // bk),
@@ -444,28 +485,29 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
             *[_SMEM] * 4,
             pl.BlockSpec(qa_row.shape, lambda b, h, i: (0, 0, 0)),
             pl.BlockSpec((n_attr, bk, 1), lambda b, h, i: (0, i, 0)),
-            q_full, k_blk, k_blk, q_full, stat_full, stat_full,
+            *_head_blocks(q, T, False), *_head_blocks(k + (v,), bk, True, H),
+            *_head_blocks([do], T, False), stat_full, stat_full,
         ],
-        out_specs=[d_blk, d_blk],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, T, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, T, D), v.dtype),
-        ],
+        out_specs=_head_blocks(d_shapes, bk, True),
+        out_shape=d_shapes,
         interpret=interpret,
         compiler_params=_params(),
-    )(lengths, *tables, qa_row, ka_col, q, k, v, do,
+    )(lengths, *tables, qa_row, ka_col, *q, *k, v, do,
       lse.reshape(B, H, nq, bq), delta.reshape(B, H, nq, bq))
-    if group > 1:
-        # the query heads that share a K/V head: summed in float32
-        fold = lambda x: jnp.sum(
-            x.reshape(B, Hkv, group, T, D).astype(jnp.float32), axis=2
-        ).astype(x.dtype)
-        dk, dv = fold(dk), fold(dv)
-    return dq, dk, dv
+
+    def fold(d, x):
+        """The query heads that share a K/V head: summed in float32."""
+        heads = x.shape[1]
+        if heads == H:
+            return d
+        return jnp.sum(d.reshape(B, heads, H // heads, T, d.shape[3]).astype(jnp.float32),
+                       axis=2).astype(d.dtype)
+
+    return tuple(dq), tuple(map(fold, dk, k)), fold(dv, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, lengths, rule, blocks, interpret):  # blocks: (bq, bk, scale)
+def _flash(q, k, v, lengths, rule, blocks, interpret):  # q, k: tuples of parts; blocks: (bq, bk, scale)
     out, _ = _run_fwd(q, k, v, lengths, rule, *blocks, interpret)
     return out
 
@@ -493,18 +535,30 @@ def _flash_bwd(rule, blocks, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def supported(T: int, D: int, itemsize: int = 2) -> bool:
-    """Shapes the kernels handle: T a multiple of a tile edge, a head the
+def as_parts(x) -> tuple:
+    """The score parts of an operand: a tuple as it is, one array as a 1-tuple."""
+    return tuple(x) if isinstance(x, (tuple, list)) else (x,)
+
+
+def supported(T: int, D, itemsize: int = 2, value_dim: int = 0) -> bool:
+    """Shapes the kernels handle: T a multiple of a tile edge, heads the
     MXU takes whole, and a K/V head (backward: a query head and its
     cotangent) that fits the planned VMEM twice over beside the tiles'
     float32 temporaries: 12 score-sized ones, for a pair's step holds two
     score tiles and their two cotangents where a single's holds one of
-    each (8 were planned before tiles were paired)."""
+    each (8 were planned before tiles were paired). ``D``: the scores'
+    width, or the widths of their parts; ``value_dim``: the values' (the
+    scores' by default). A part narrower than a lane tile sits in VMEM as
+    a whole one."""
+    widths = as_parts(D)
+    value_dim = value_dim or sum(widths)
     block = default_block(T)
-    if not block or D > 256 or D % 8:
+    if not block or any(d > 256 or d % 8 for d in widths + (value_dim,)):
         return False
-    resident = 2 * 2 * T * D * itemsize
-    tiles = 12 * block * block * 4 + 8 * block * D * 4
+    lanes = lambda d: -(-d // _LANES) * _LANES
+    held = sum(map(lanes, widths)) + lanes(value_dim)
+    resident = 2 * T * held * itemsize
+    tiles = 12 * block * block * 4 + 4 * block * held * 4
     return resident + tiles <= _VMEM_PLAN
 
 
@@ -557,20 +611,28 @@ def flash_attention(
 ) -> Array:
     """Flash attention over [B, T, H, D] queries and [B, T, Hkv, D] keys
     and values (the sequence_parallel layout), masked by ``rule``
-    (``causal`` is the old flag for the causal rule). ``block``: the tile
-    edge, `default_block(T)` unless a test wants small tiles. ``scale``:
-    what the scores are multiplied by, 1/sqrt(D) by default; a caller that
-    folded it into q passes 1."""
-    B, T, H, D = q.shape
+    (``causal`` is the old flag for the causal rule). ``q`` and ``k`` may
+    be tuples of score parts, part i [B, T, H, D_i] against [B, T, Hk_i,
+    D_i] (module docstring); the values' width is their own. ``block``: the
+    tile edge, `default_block(T)` unless a test wants small tiles.
+    ``scale``: what the scores are multiplied by, 1/sqrt(D) (D the parts'
+    sum) by default; a caller that folded it into q passes 1."""
+    q, k = as_parts(q), as_parts(k)
+    B, T, H, _ = q[0].shape
+    D = sum(x.shape[3] for x in q)
     rule = rule or rule_of(causal=causal)
     rule.check(T)
     block = block or default_block(T)
     assert block and T % block == 0, f"unsupported shape T={T}, D={D}"
-    assert H % k.shape[2] == 0, f"{H} query heads over {k.shape[2]} K/V heads"
+    assert len(q) == len(k) and all(a.shape[3] == b.shape[3] for a, b in zip(q, k)), (
+        "a score part's queries and keys have one width")
+    for x in (*k, v):
+        assert H % x.shape[2] == 0, f"{H} query heads over {x.shape[2]} K/V heads"
     if lengths is None:
         lengths = jnp.full((B,), T, jnp.int32)
-    qt, kt, vt = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
-    out = _flash(qt, kt, vt, jnp.asarray(lengths, jnp.int32), rule,
+    by_head = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+    out = _flash(tuple(map(by_head, q)), tuple(map(by_head, k)), by_head(v),
+                 jnp.asarray(lengths, jnp.int32), rule,
                  (block, block, 1.0 / math.sqrt(D) if scale is None else float(scale)),
                  interpret)
     return jnp.transpose(out, (0, 2, 1, 3))

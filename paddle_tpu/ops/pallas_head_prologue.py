@@ -50,6 +50,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -107,6 +108,16 @@ def turn_tables(positions: Array, theta: float, head_dim: int, rot: int = 0,
     return (jnp.concatenate([cos, cos, rest + 1.0], -1),
             jnp.concatenate([-sin, zero, rest], -1),
             jnp.concatenate([zero, sin, rest], -1))
+
+
+def interleaved_order(rot: int):
+    """The static lane order that makes an INTERLEAVED turn (the lanes (2i,
+    2i + 1) are a pair) a rotate-half one: [even lanes | odd lanes]. A
+    score is a sum over lanes, so q and k reordered alike give the scores
+    of the interleaved turn; a caller applies the order to the projection's
+    weight columns (a gather of a weight, not of an activation) and the
+    prologue then turns halves, as it does for every other head."""
+    return np.concatenate([np.arange(0, rot, 2), np.arange(1, rot, 2)])
 
 
 def _shifts(head_dim: int, rot: int, tables) -> Tuple[int, ...]:
@@ -287,6 +298,9 @@ def head_prologue(x: Array, gain: Optional[Array], tables, head_dim: int,
     mode = device.pallas_mode()
     if not supported(x.shape[1], x.shape[2], head_dim, x.dtype.itemsize):
         mode = None
+    device.log_selection(
+        "head_prologue", f"T={x.shape[1]} heads={x.shape[2] // head_dim} Dh={head_dim}",
+        f"Pallas kernel, {mode}" if mode else "XLA path")
     return _prologue(x, gain, tables, head_dim, eps, scale, mode, rot)
 
 

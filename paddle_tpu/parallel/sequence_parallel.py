@@ -104,11 +104,17 @@ def full_attention(
     return out.astype(q.dtype)
 
 
-def rule_attention(q: Array, k: Array, v: Array, lengths: Optional[Array], rule,
+def rule_attention(q, k, v: Array, lengths: Optional[Array], rule,
                    scale: Optional[float] = None) -> Array:
     """Single-device attention of [B, T, H, D] queries over [B, T, Hkv, D]
     keys and values (query head h reads K/V head ``h // (H / Hkv)``) under
     a mask RULE over positions (`ops/attention_mask.MaskRule`).
+
+    ``q`` and ``k`` may be tuples of score PARTS: part i is [B, T, H, D_i]
+    against [B, T, Hk_i, D_i], a key head count of its own, and the scores
+    are the parts' sum (latent attention: the lanes that differ by head,
+    and the rotary lanes all heads read from one key head); ``v`` [B, T,
+    Hkv, Dv] has a width of its own, which is the result's.
 
     Where a Pallas kernel can run (a TPU backend, or interpret mode off
     it) and its gate admits the shape, the flash kernel of
@@ -116,17 +122,20 @@ def rule_attention(q: Array, k: Array, v: Array, lengths: Optional[Array], rule,
     leaves; else the XLA path below, which materializes [B, H, T, T]
     scores and is for small T only. Padded query rows are unspecified, as
     in :func:`full_attention`. ``scale`` multiplies the scores (1/sqrt(D)
-    by default; 1 where the caller folded it into q)."""
+    by default, D the parts' sum; 1 where the caller folded it into q)."""
     from paddle_tpu.ops import pallas_attention
     from paddle_tpu.utils import device
 
-    B, T, H, D = q.shape
-    Hkv = k.shape[2]
-    site = f"T={T} D={D} {rule.kind}"
+    qs, ks = pallas_attention.as_parts(q), pallas_attention.as_parts(k)
+    B, T, H, _ = qs[0].shape
+    widths = tuple(x.shape[3] for x in qs)
+    D, Dv = sum(widths), v.shape[3]
+    # "128" for one part of the values' width, "128+64/128" for latent heads
+    site = f"T={T} D={'+'.join(map(str, widths))}{'' if Dv == D else f'/{Dv}'} {rule.kind}"
     mode = device.pallas_mode()
     if mode is None:
         why = device.why_no_pallas()
-    elif not pallas_attention.supported(T, D, q.dtype.itemsize):
+    elif not pallas_attention.supported(T, widths, qs[0].dtype.itemsize, Dv):
         why = "kernel gate refuses the shape"
     else:
         device.log_selection("rule_attention", site, f"Pallas kernel, {mode}")
@@ -136,17 +145,25 @@ def rule_attention(q: Array, k: Array, v: Array, lengths: Optional[Array], rule,
             q, k, v, lengths=lengths, rule=rule, interpret=mode == "interpret",
             scale=scale)
     device.log_selection("rule_attention", site, f"XLA path ({why})")
-    acc_t = jnp.promote_types(q.dtype, jnp.float32)
-    qg = q.reshape(B, T, Hkv, H // Hkv, D)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=acc_t)
+    acc_t = jnp.promote_types(qs[0].dtype, jnp.float32)
+
+    def part_scores(qp, kp):                                   # -> [B, H, T, T]
+        heads = kp.shape[2]
+        qg = qp.reshape(B, T, heads, H // heads, qp.shape[3])
+        return jnp.einsum("bqhgd,bkhd->bhgqk", qg, kp,
+                          preferred_element_type=acc_t).reshape(B, H, T, T)
+
+    s = sum(map(part_scores, qs[1:], ks[1:]), part_scores(qs[0], ks[0]))
     s = s * (1.0 / math.sqrt(D) if scale is None else scale)
     idx = jnp.arange(T)
-    mask = jnp.broadcast_to(rule.allowed(idx, idx, T), (T, T))[None, None, None]
+    mask = jnp.broadcast_to(rule.allowed(idx, idx, T), (T, T))[None, None]
     if lengths is not None:
-        mask = mask & (idx[None, None, None, None, :] < lengths[:, None, None, None, None])
+        mask = mask & (idx[None, None, None, :] < lengths[:, None, None, None])
     p = jax.nn.softmax(jnp.where(mask, s, _NEG), axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v, preferred_element_type=acc_t)
-    return out.reshape(B, T, H, D).astype(q.dtype)
+    Hkv = v.shape[2]
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.reshape(B, Hkv, H // Hkv, T, T), v,
+                     preferred_element_type=acc_t)
+    return out.reshape(B, T, H, Dv).astype(qs[0].dtype)
 
 
 def _ring_attention_local(q, k, v, lengths, causal, axis_name):

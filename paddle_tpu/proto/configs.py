@@ -246,6 +246,17 @@ class LayerConfig(Message):
     mask_block_length: int = 0
     mask_window: int = 0
     output_gate: bool = False
+    # latent form of multi_head_attention (kv_latent_dim > 0 selects it):
+    # keys and values are projected up, a head, from ONE kv_latent_dim-wide
+    # RMS-normed latent a position; a head's scores are over its head_dim
+    # lanes plus rope_head_dim rotary lanes, which every query head reads
+    # from one shared key head; its values are value_head_dim wide (0 =
+    # head_dim). rope_interleave: the rotary lanes (2i, 2i + 1) are a pair
+    # (rotate-half pairs lane i with lane i + half)
+    kv_latent_dim: int = 0
+    rope_head_dim: int = 0
+    value_head_dim: int = 0
+    rope_interleave: bool = False
     # rms_norm (and attention's q/k norm): x / sqrt(mean(x^2) + epsilon)
     norm_epsilon: float = 1e-6
     # sparse-expert layer (layers/moe.py): `experts` router outputs,
@@ -262,6 +273,10 @@ class LayerConfig(Message):
     experts_held_count: int = 0
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # the router's score function ("" = softmax | sigmoid), and whether a
+    # static per-expert bias is added to the scores for the CHOICE only
+    score_function: str = ""
+    selection_bias: bool = False
     # seq_slice: the time axis cut into seq_parts equal parts, part seq_part kept
     seq_parts: int = 1
     seq_part: int = 0

@@ -1803,6 +1803,10 @@ def multi_head_attention_layer(
     rope_yarn: Optional[Sequence[float]] = None,
     rope_attention_factor: float = 1.0,
     output_gate: bool = False,
+    kv_latent_dim: int = 0,
+    rope_head_dim: int = 0,
+    value_head_dim: int = 0,
+    rope_interleave: bool = False,
 ) -> LayerOutput:
     """Transformer-style multi-head self-attention over a sequence (TPU
     extension; the reference's only attention is simple_attention inside
@@ -1826,7 +1830,20 @@ def multi_head_attention_layer(
     2L-long one) — a rule over positions,
     `paddle_tpu/ops/attention_mask.py`; ``output_gate``: each head's result
     times the sigmoid of a projection of the input, one number a head a
-    position (``_<name>.wg`` [in, H])."""
+    position (``_<name>.wg`` [in, H]).
+
+    With ``kv_latent_dim`` (and ``head_dim``) the latent form
+    (`paddle_tpu/layers/attention.py`): ``num_heads`` heads whose keys and
+    values are projected up from one ``kv_latent_dim``-wide RMS-normed
+    latent a position; a head's scores are over ``head_dim`` lanes of its
+    own plus ``rope_head_dim`` rotary lanes (``rope_theta``; with
+    ``rope_interleave`` the lanes (2i, 2i + 1) are a pair) that every query
+    head reads from one shared key head; its values are ``value_head_dim``
+    wide (default ``head_dim``). Parameters ``_<name>.wq`` [in, H*(head_dim
+    + rope_head_dim)], ``.wkv_a`` [in, kv_latent_dim + rope_head_dim],
+    ``.kv_norm`` [1, kv_latent_dim], ``.wkv_b`` [kv_latent_dim,
+    H*(head_dim + value_head_dim)], ``.wo`` [H*value_head_dim, size]. No
+    grouped heads, per-head norm, partial turn or output gate there."""
     assert seq_parallel in ("", "ring", "alltoall"), (
         f"seq_parallel must be '', 'ring' or 'alltoall', got {seq_parallel!r}"
     )
@@ -1841,22 +1858,48 @@ def multi_head_attention_layer(
     cfg.num_heads = num_heads
     cfg.causal_attention = causal
     cfg.seq_parallel_mode = seq_parallel
+    assert kv_latent_dim or not (rope_head_dim or value_head_dim or rope_interleave), (
+        "rope_head_dim, value_head_dim and rope_interleave belong to the latent "
+        "form (kv_latent_dim)")
+    assert head_dim or not kv_latent_dim, "the latent form needs head_dim"
     if head_dim:
+        # the two forms under a mask rule: what they share of the config
         from paddle_tpu.ops.attention_mask import rule_of
 
         rule_of(attention_mask, block_length, causal, window)      # refuses a bad rule here
-        assert 0 <= rotary_dim <= head_dim and rotary_dim % 2 == 0, (
-            f"rotary_dim {rotary_dim} is no even part of a head of {head_dim}")
         assert rope_yarn is None or len(rope_yarn) == 4, (
             "rope_yarn is (factor, original positions, beta_fast, beta_slow)")
-        kv = num_kv_heads or num_heads
-        cfg.num_kv_heads, cfg.head_dim, cfg.qk_norm = kv, head_dim, qk_norm
+        cfg.head_dim = head_dim
         cfg.rope_theta, cfg.norm_epsilon = float(rope_theta), float(norm_epsilon)
-        cfg.rotary_dim = 0 if rotary_dim == head_dim else rotary_dim
         cfg.rope_yarn = [float(v) for v in rope_yarn or ()]
         cfg.rope_attention_factor = float(rope_attention_factor)
-        cfg.attention_mask, cfg.mask_block_length = attention_mask, block_length
-        cfg.mask_window, cfg.output_gate = window, bool(output_gate)
+        cfg.attention_mask, cfg.mask_block_length, cfg.mask_window = (
+            attention_mask, block_length, window)
+    if kv_latent_dim:
+        assert rope_head_dim and rope_head_dim % 2 == 0 and rope_theta, (
+            "the latent form needs an even rope_head_dim and rope_theta")
+        assert not (num_kv_heads or qk_norm or rotary_dim or output_gate), (
+            "the latent form has no grouped heads, per-head norm, partial turn "
+            "or output gate")
+        dv = value_head_dim or head_dim
+        cfg.kv_latent_dim, cfg.rope_head_dim = kv_latent_dim, rope_head_dim
+        cfg.value_head_dim = 0 if dv == head_dim else dv
+        cfg.rope_interleave = bool(rope_interleave)
+        names = [_create_parameter(f"_{name}.{leaf}", dims[0] * dims[1], dims, param_attr)
+                 for leaf, dims in (
+                     ("wq", [input.size, num_heads * (head_dim + rope_head_dim)]),
+                     ("wkv_a", [input.size, kv_latent_dim + rope_head_dim]),
+                     ("wkv_b", [kv_latent_dim, num_heads * (head_dim + dv)]),
+                     ("wo", [num_heads * dv, size]))]
+        _create_parameter(f"_{name}.kv_norm", kv_latent_dim, [1, kv_latent_dim], _ones_attr())
+        cfg.inputs.append(_input(input, names[0]))
+    elif head_dim:
+        assert 0 <= rotary_dim <= head_dim and rotary_dim % 2 == 0, (
+            f"rotary_dim {rotary_dim} is no even part of a head of {head_dim}")
+        kv = num_kv_heads or num_heads
+        cfg.num_kv_heads, cfg.qk_norm = kv, qk_norm
+        cfg.rotary_dim = 0 if rotary_dim == head_dim else rotary_dim
+        cfg.output_gate = bool(output_gate)
         wq = _create_parameter(f"_{name}.wq", input.size * num_heads * head_dim,
                                [input.size, num_heads * head_dim], param_attr)
         for leaf in ("wk", "wv"):
@@ -1922,12 +1965,23 @@ def moe_layer(
     param_attr: Optional[ParameterAttribute] = None,
     layer_attr=None,
     routed_scaling_factor: float = 1.0,
+    score_function: str = "softmax",
+    selection_bias: bool = False,
+    n_group: int = 1,
 ) -> LayerOutput:
     """Sparse-expert feed-forward (TPU extension, `paddle_tpu/layers/
-    moe.py`): a float32 softmax router over ``experts``, the
-    ``experts_per_token`` largest chosen (renormalised over the chosen
-    with ``norm_topk_prob``), SwiGLU experts ``expert_width`` wide, their
-    weighted sum times ``routed_scaling_factor``. A shared expert is a
+    moe.py`): a float32 router over ``experts`` whose scores are a softmax
+    over the experts or, ``score_function="sigmoid"``, each expert's own
+    sigmoid; the ``experts_per_token`` largest chosen (renormalised over
+    the chosen with ``norm_topk_prob``), SwiGLU experts ``expert_width``
+    wide, their weighted sum times ``routed_scaling_factor``.
+    ``selection_bias``: a per-expert bias ``_<name>.router_bias`` [1,
+    experts] is added to the scores for the CHOICE only (the weights come
+    from the unbiased scores): a STATIC parameter, which the gradient, the
+    clip and the optimizer never touch (the rule that moves it from the
+    experts' load outside the gradient is not built; it starts at 0).
+    ``n_group``: choosing within the best groups of experts is not built,
+    so more than one group is refused. A shared expert is a
     :func:`gated_mlp_layer` beside this layer, joined by ``addto_layer``.
     ``experts_held``: ``(first, count)``, the experts this program holds
     and computes (all by default); the router always has ``experts``
@@ -1937,6 +1991,12 @@ def moe_layer(
     assert 0 <= first and count >= 1 and first + count <= experts, (
         f"experts_held {(first, count)} is no range of {experts} experts")
     assert 1 <= experts_per_token <= experts
+    assert score_function in ("softmax", "sigmoid"), (
+        f"score_function must be 'softmax' or 'sigmoid', got {score_function!r}")
+    if n_group != 1:
+        raise NotImplementedError(
+            f"moe_layer: n_group={n_group}: a choice limited to the best groups of "
+            "experts is not built; only n_group=1 (no group limit) runs")
     name = _name(name, "moe")
     d = input.size
     cfg = LayerConfig(name=name, type="moe", size=d)
@@ -1944,6 +2004,11 @@ def moe_layer(
     cfg.experts_held_first, cfg.experts_held_count = first, count
     cfg.norm_topk_prob = bool(norm_topk_prob)
     cfg.routed_scaling_factor = float(routed_scaling_factor)
+    cfg.score_function = "" if score_function == "softmax" else score_function
+    cfg.selection_bias = bool(selection_bias)
+    if selection_bias:
+        _create_parameter(f"_{name}.router_bias", experts, [1, experts],
+                          ParameterAttribute(initial_mean=0.0, initial_std=0.0, is_static=True))
     router = _create_parameter(f"_{name}.router", d * experts, [d, experts], param_attr)
     for leaf, dims in (("gate", [count, d, expert_width]), ("up", [count, d, expert_width]),
                        ("down", [count, expert_width, d])):

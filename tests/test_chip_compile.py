@@ -9,6 +9,7 @@ compiles, and a shape the compiler refuses is one the gate refuses too.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 # libtpu lets one process at a time in (a lock file under /tmp), because a
@@ -129,6 +130,27 @@ def _rule_attention(kind, T=8192, H=32, Hkv=4, D=128, B=1, pairs=None):
     return fn, shapes, pa.supported(T, D, 2)
 
 
+def _latent_attention(split, B=1, T=8192, H=32, dn=128, dr=64, dv=128):
+    """The flash kernels at `kanana.train`'s shapes (perfbench: 32 heads,
+    scores over 128 lanes a head + 64 rotary lanes, values of 128, the
+    causal rule): ``split``, as the latent layer runs them, two score parts
+    with the rotary part's ONE key head shared by all the query heads; or
+    one 192-wide score operand a head (the single-operand form the split
+    one was measured against)."""
+    from paddle_tpu.ops import pallas_attention as pa
+    from paddle_tpu.ops.attention_mask import MaskRule
+
+    rule = MaskRule("causal")
+    if split:
+        shapes = [((B, T, H, dn), BF16), ((B, T, H, dr), BF16), ((B, T, H, dn), BF16),
+                  ((B, T, 1, dr), BF16), ((B, T, H, dv), BF16)]
+        fn = lambda qn, qr, kn, kr, v: pa.flash_attention((qn, qr), (kn, kr), v, rule=rule)
+        return fn, shapes, pa.supported(T, (dn, dr), 2, dv)
+    shapes = [((B, T, H, dn + dr), BF16), ((B, T, H, dn + dr), BF16), ((B, T, H, dv), BF16)]
+    fn = lambda q, k, v: pa.flash_attention(q, k, v, rule=rule)
+    return fn, shapes, pa.supported(T, dn + dr, 2, dv)
+
+
 def _expert_ffn(N=4096, D=2048, F=768, held=16, k=8):
     """The sparse-expert layer's dispatch and grouped products
     (`ops/grouped_matmul.py`) at the cell's shapes: chunks of 8,192 rows of
@@ -215,6 +237,10 @@ CASES = {
         "block_diffusion", B=4, pairs=(8, 0)),
     "rule-attention-pairs-window-t26624": lambda: _rule_attention(
         "sliding_window", T=26624, H=8, Hkv=8, pairs=(51, 51)),
+    # perfbench kanana.train: latent attention's score parts (128 a head + 64
+    # shared from one key head) over 128-wide values, and the one-operand form
+    "rule-attention-latent-split-32-heads-t8192": lambda: _latent_attention(True),
+    "rule-attention-latent-192-over-128-32-heads-t8192": lambda: _latent_attention(False),
     "head-prologue-partial-q-48x128": lambda: _head_prologue_partial(48),
     "head-prologue-partial-k-8x128": lambda: _head_prologue_partial(8),
     # a ResNet-50 1x1 at B=256: stage-1 expand, 56x56 pixels, 64 -> 256
@@ -268,3 +294,64 @@ def test_a_recomputation_block_runs_the_flash_forward_once(chip):
 
     assert calls(recompute_block(fn)) == 3
     assert calls(jax.checkpoint(fn)) == 4
+
+
+def _step_compiled(stem, chip, monkeypatch):
+    """A benchmark configuration's REAL train step (its DSL file at its own
+    sizes -> GradientMachine's gradient under its recomputation blocks ->
+    the updater, parameters and optimizer state donated as the trainer
+    donates them) compiled for the described chip from shapes alone:
+    nothing is allocated here. The kernels are steered on in the test (the
+    program asks `jax.default_backend()`, which is the CPU here)."""
+    from paddle_tpu.config import parse_config
+    from paddle_tpu.graph.argument import Argument
+    from paddle_tpu.graph.machine import GradientMachine, compute_dtype_of
+    from paddle_tpu.optimizer.updater import Updater
+    from paddle_tpu.utils import device
+
+    monkeypatch.setattr(device, "pallas_mode", lambda: "compiled")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    conf = parse_config(os.path.join(root, "perfbench", "configs", stem + ".py"),
+                        "feed=x,feed_list=y,batch=4")
+    gm = GradientMachine(conf.model_config, compute_dtype=compute_dtype_of(conf.opt_config))
+    updater = Updater(conf.opt_config, conf.model_config)
+    params = jax.eval_shape(lambda: gm.init_params(seed=1))
+    state = jax.eval_shape(updater.init_state, params)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree)
+    ids = jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=chip)
+    lens = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=chip)
+    batch = {"tokens": Argument(ids=ids, seq_lengths=lens),
+             "labels": Argument(ids=ids, seq_lengths=lens)}
+    grad_fn = gm.grad_fn(remat=conf.opt_config.remat, sparse=True)
+
+    def step(params, opt_state, in_args):
+        loss, grads, outputs, _ = grad_fn(params, in_args, None)
+        new_params, new_state = updater(params, grads, opt_state, jnp.float32(4))
+        return new_params, new_state, loss
+
+    return jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), batch).compile()
+
+
+def test_the_latent_cells_real_step_fits_the_chip(chip, monkeypatch):
+    """`kanana.train`'s step at its published widths (576 M parameters, 4 x
+    8,192 tokens) compiles for a v5e with its `mem_total_bytes` under the
+    15.5 GB ISSUE 34 set for it (the chip gives a program 16.9 GB), with
+    the flash kernels in (three a layer: a block keeps the forward's `out`
+    and `lse`) and the latent's scopes in its `op_name`s."""
+    from paddle_tpu.observability.compile_log import hlo_census
+    from paddle_tpu.observability.memory import memory_analysis_of
+
+    compiled = _step_compiled("kanana-2-30b-a3b-ep8", chip, monkeypatch)
+    text = compiled.as_text()
+    memory = memory_analysis_of(compiled)
+    assert memory["mem_arg_bytes"] == pytest.approx(12 * 575955968, rel=0.001)
+    assert memory["mem_total_bytes"] < 15.5e9, memory
+    calls = re.findall(r"%(attention_(?:fwd|dq|dkv))[.\d]* = ", text)
+    assert {k: calls.count(k) for k in set(calls)} == {
+        "attention_fwd": 5, "attention_dq": 5, "attention_dkv": 5}
+    assert hlo_census(compiled, text)["mosaic_calls"] > 15
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("latent_down", "latent_up"):
+        assert any(f"/{scope}/" in n for n in names), scope
