@@ -157,7 +157,7 @@ def _flat(rows, per):
 @functools.lru_cache(maxsize=64)
 def tile_walk(rule: MaskRule, T: int, bq: int, bk: int, transpose: bool = False) -> TileWalk:
     """The walk of `tile_occupancy(rule, T, bq, bk)` (of its transpose: the
-    dK/dV kernel's), found from the rule's own masks and from nothing
+    backward kernel's), found from the rule's own masks and from nothing
     else: in a row, a partial tile takes the first later partial tile it
     shares no allowed local (row, column) with; a tile is in at most one
     pair. A causal row has one partial tile and no pair; a window's far

@@ -3,8 +3,12 @@
 The XLA path materializes the [B, H, T, T] score matrix; this kernel
 streams K/V tiles through an online-softmax accumulator in VMEM so
 activation memory stays O(T·D). Forward saves only (out, logsumexp);
-backward recomputes scores tile by tile (flash-attention-2 style) in two
-kernels (dQ; dK/dV).
+backward recomputes scores tile by tile (flash-attention-2 style) in ONE
+kernel, `attention_bwd`: its grid step is a key tile of a query head, which
+leaves with the tile's dK and dV; the head's dQ is summed in float32 in
+VMEM while the head's key tiles pass and rounded once after the last (a
+head's [T, D] float32 fits there: no partial dQ in HBM, no atomic add), so
+a tile's scores, mask and exp are worked out once in the backward pass.
 
 The mask is a rule over positions (`ops/attention_mask.py`): the kernels
 get the rule's per-index attributes as small int arrays and, from the
@@ -29,11 +33,13 @@ about one tile's pass and not two; every allowed score is still computed
 once, in float32. Which tiles pair is read from the rule's masks on the
 host; a rule with no pair (causal, full) compiles no pair step.
 Grouped-query heads: query head h reads K/V head ``h // (H / Hkv)``;
-dK/dV come back a query head and are summed over each group outside.
+dK/dV come back a query head and are summed over each group outside; dQ
+is a query head's own.
 
 Layout: q [B, H, T, D], k/v [B, Hkv, T, D] inside the kernels (callers
-transpose from the [B, T, H, D] sequence_parallel layout). A K/V head
-stays whole in VMEM while its query heads' tiles pass.
+transpose from the [B, T, H, D] sequence_parallel layout). Forward: a K/V
+head stays whole in VMEM while its query heads' tiles pass. Backward: a
+query head, its cotangent and its dQ stay whole while its key tiles pass.
 
 The scores may come in PARTS: q and k are then tuples of as many arrays,
 part i ``[B, H, T, D_i]`` against ``[B, Hk_i, T, D_i]`` with a head count of
@@ -53,6 +59,7 @@ Mosaic accepts, by compiling for a described v5e
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 import operator
 from typing import Optional
@@ -71,10 +78,10 @@ Array = jax.Array
 _NEG = -1e30
 _LANES = 128
 # scoped VMEM the kernels may use, and what `supported` lets them plan for
-# (a K/V head, or a query head and its cotangent, double-buffered, plus the
-# tile temporaries); v5e has 128 MiB
+# (a K/V head; in the backward a query head, its cotangent and its dQ; plus
+# the tile temporaries); v5e has 128 MiB
 _VMEM_LIMIT = 64 * 1024 * 1024
-_VMEM_PLAN = 40 * 1024 * 1024
+_VMEM_PLAN = 48 * 1024 * 1024
 
 
 def default_block(T: int) -> int:
@@ -89,6 +96,7 @@ def _dot(a, b, dims):
 
 _NT = ((1,), (1,))      # a @ b.T
 _NN = ((1,), (0,))      # a @ b
+_TN = ((0,), (0,))      # a.T @ b
 
 
 def _scores(a_parts, b_parts):
@@ -96,15 +104,9 @@ def _scores(a_parts, b_parts):
     return functools.reduce(operator.add, (_dot(a, b, _NT) for a, b in zip(a_parts, b_parts)))
 
 
-def _rows(refs, start, size):
+def _rows(refs, rows):
     """A tile of rows of each part's [1, 1, T, D_i] block."""
-    return tuple(r[0, 0, pl.ds(start, size), :] for r in refs)
-
-
-def _col(row):
-    """f32 [1, n] -> [n, 1] through an aligned 2-D transpose."""
-    n = row.shape[1]
-    return jnp.broadcast_to(row, (_LANES, n)).T[:, 0:1]
+    return tuple(r[0, 0, rows, :] for r in refs)
 
 
 def _row(col):
@@ -174,28 +176,6 @@ def _whole_inside(single, n_whole, block_k, length):
         0, n_whole, lambda j, n: n + ((single(j) + 1) * block_k <= length).astype(jnp.int32), 0)
 
 
-def _key_masks(rule, q_attrs, ka_ref, block_k, length, union_whole):
-    """What the forward and the dQ kernel share of a query tile's walk over
-    key tiles: ``cut(kt, start, s)``, a single tile's scores with what the
-    rule or the padding forbids at -inf, and ``pair_scores``, the same for
-    a pair of tiles merged into one (with A's mask, which splits it again)."""
-
-    def side(kt):
-        return q_attrs, tuple(ka_ref[a, pl.ds(kt, 1), :] for a in range(len(q_attrs)))
-
-    def inside(start):                                        # [1, bk]
-        return start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1) < length
-
-    def cut(kt, start, s):
-        return jnp.where(rule.allowed_from(*side(kt)) & inside(start), s, -jnp.inf)
-
-    def pair_scores(ta, tb, start_a, start_b, s_a, s_b):
-        return _pair_merged(rule, side(ta), side(tb), s_a + _past(inside(start_a)),
-                            s_b + _past(inside(start_b)), union_whole)
-
-    return cut, pair_scores
-
-
 def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
                 rule, n_attr, parts, block_k, width, pair_width, union_whole, scale):
     q_refs, k_refs, (v_ref, o_ref, lse_ref) = refs[:parts], refs[parts:2 * parts], refs[2 * parts:]
@@ -206,13 +186,18 @@ def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
     q = tuple(r[0, 0] for r in q_refs)                        # each [bq, D_i]
     q_attrs = tuple(qa_ref[a] for a in range(n_attr))         # each [bq, 1]
     single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, iq, width, pair_width)
-    cut, pair_scores = _key_masks(rule, q_attrs, ka_ref, block_k, length, union_whole)
 
     def tile(kt):
         start = pl.multiple_of(kt * block_k, block_k)
-        k_blk = _rows(k_refs, start, block_k)
+        k_blk = _rows(k_refs, pl.ds(start, block_k))
         v_blk = v_ref[0, 0, pl.ds(start, block_k), :]
         return start, v_blk, _scaled(_scores(q, k_blk), scale)             # s [bq, bk]
+
+    def side(kt):
+        return q_attrs, tuple(ka_ref[a, pl.ds(kt, 1), :] for a in range(n_attr))
+
+    def inside(start):                                        # [1, bk]
+        return start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1) < length
 
     def softmax_step(carry, s, pv):
         o, m, l = carry
@@ -225,7 +210,8 @@ def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
         def step(j, carry):
             kt = single(j)
             start, v_blk, s = tile(kt)
-            s = cut(kt, start, s) if masked else s
+            if masked:
+                s = jnp.where(rule.allowed_from(*side(kt)) & inside(start), s, -jnp.inf)
             return softmax_step(carry, s, lambda p: _dot(p.astype(v_blk.dtype), v_blk, _NN))
         return step
 
@@ -233,7 +219,8 @@ def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
         ta, tb = pair(j)
         start_a, v_a, s_a = tile(ta)
         start_b, v_b, s_b = tile(tb)
-        ok_a, s = pair_scores(ta, tb, start_a, start_b, s_a, s_b)
+        ok_a, s = _pair_merged(rule, side(ta), side(tb), s_a + _past(inside(start_a)),
+                               s_b + _past(inside(start_b)), union_whole)
 
         def pv(p):
             p_a, p_b = _split(ok_a, p, v_a.dtype)
@@ -251,69 +238,25 @@ def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
     lse_ref[0, 0, 0] = _row(jnp.where(l > 0, m + jnp.log(l_safe), _NEG))
 
 
-def _dq_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
-               rule, n_attr, parts, block_k, width, pair_width, union_whole, scale):
-    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
-    v_ref, do_ref, lse_ref, delta_ref = refs[2 * parts:2 * parts + 4]
-    dq_refs = refs[2 * parts + 4:]
-    b = pl.program_id(0)
-    iq = pl.program_id(2)
-    bq = q_refs[0].shape[2]
-    length = len_ref[b]
-    q = tuple(r[0, 0] for r in q_refs)
-    do = do_ref[0, 0]
-    lse = _col(lse_ref[0, 0, 0])                              # [bq, 1]
-    delta = _col(delta_ref[0, 0, 0])
-    q_attrs = tuple(qa_ref[a] for a in range(n_attr))
-    single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, iq, width, pair_width)
-    cut, pair_scores = _key_masks(rule, q_attrs, ka_ref, block_k, length, union_whole)
-
-    def tile(kt):
-        start = pl.multiple_of(kt * block_k, block_k)
-        k_blk = _rows(k_refs, start, block_k)
-        v_blk = v_ref[0, 0, pl.ds(start, block_k), :]
-        return start, k_blk, _scaled(_scores(q, k_blk), scale), _dot(do, v_blk, _NT)
-
-    def add(dq, ds, k_blk):
-        ds = ds.astype(k_blk[0].dtype)
-        return tuple(d + _dot(ds, k, _NN) for d, k in zip(dq, k_blk))
-
-    def single_step(masked):
-        def step(j, dq):
-            kt = single(j)
-            start, k_blk, s, dp = tile(kt)
-            s = cut(kt, start, s) if masked else s
-            return add(dq, jnp.exp(s - lse) * (dp - delta), k_blk)
-        return step
-
-    def pair_step(j, dq):
-        ta, tb = pair(j)
-        start_a, k_a, s_a, dp_a = tile(ta)
-        start_b, k_b, s_b, dp_b = tile(tb)
-        ok_a, s = pair_scores(ta, tb, start_a, start_b, s_a, s_b)
-        p = jnp.exp(s - lse)
-        ds_a, ds_b = _split(ok_a, p * (jnp.where(ok_a, dp_a, dp_b) - delta), k_a[0].dtype)
-        return tuple(d + _dot(ds_a, a, _NN) + _dot(ds_b, b, _NN) for d, a, b in zip(dq, k_a, k_b))
-
-    dq = run(_whole_inside(single, n_whole, block_k, length), single_step, pair_step,
-             tuple(jnp.zeros((bq, r.shape[3]), jnp.float32) for r in q_refs))
-    # the score's scale, once a tile of rows and not once a score
-    for d, ref in zip(dq, dq_refs):
-        ref[0, 0] = _scaled(d, scale).astype(ref.dtype)
-
-
-def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
+def _bwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
                 rule, n_attr, parts, block_q, width, pair_width, union_whole, scale):
-    """One K/V tile of one QUERY head; scores are held transposed,
-    [bk, bq], so that the per-query statistics broadcast along sublanes.
-    A pair's two query tiles have their own statistics: `s - lse` and
-    `dp - delta` are merged, then one exp and one product for both."""
-    q_refs, k_refs = refs[:parts], refs[parts:2 * parts]
-    v_ref, do_ref, lse_ref, delta_ref = refs[2 * parts:2 * parts + 4]
-    dk_refs, dv_ref = refs[2 * parts + 4:-1], refs[-1]
+    """One K/V tile of one QUERY head: the tile's dK and dV, and what it
+    adds to the head's dQ. Scores are held transposed, [bk, bq], so that
+    the per-query statistics broadcast along sublanes. A pair's two query
+    tiles have their own statistics: `s - lse` and `dp - delta` are merged,
+    then one exp and one product for both. dQ of the whole head is summed
+    in float32 in VMEM (``acc_refs``) while the head's key tiles pass, the
+    grid's last axis: zeroed at the first, scaled and rounded once into
+    ``dq_refs`` at the last."""
+    # operands, results, scratch: q and k a part, v, do, lse, delta; dq and dk a part, dv; dq's sums
+    sizes = (parts, parts, 4, parts, parts, 1, parts)
+    ends = list(itertools.accumulate(sizes))
+    q_refs, k_refs, (v_ref, do_ref, lse_ref, delta_ref), dq_refs, dk_refs, (dv_ref,), acc_refs = (
+        refs[end - n:end] for n, end in zip(sizes, ends))
     b = pl.program_id(0)
     ik = pl.program_id(2)
     bk = k_refs[0].shape[2]
+    n_q = q_refs[0].shape[2] // block_q
     length = len_ref[b]
     k = tuple(r[0, 0] for r in k_refs)
     v = v_ref[0, 0]
@@ -321,14 +264,36 @@ def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
     inside = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0) < length
     single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, ik, width, pair_width)
 
+    def rows_of(qt):
+        return pl.ds(pl.multiple_of(qt * block_q, block_q), block_q)
+
+    def query_tiles(step):
+        """``step(rows)`` for the rows of each of the head's query tiles."""
+        def body(qt, carry):
+            step(rows_of(qt))
+            return carry
+        jax.lax.fori_loop(0, n_q, body, 0)
+
+    @pl.when(ik == 0)
+    def _():
+        def zero(rows):
+            for acc in acc_refs:
+                acc[rows, :] = jnp.zeros((block_q, acc.shape[1]), jnp.float32)
+        query_tiles(zero)
+
     def tile(qt):
-        start = pl.multiple_of(qt * block_q, block_q)
-        q_blk = _rows(q_refs, start, block_q)
-        do_blk = do_ref[0, 0, pl.ds(start, block_q), :]
+        rows = rows_of(qt)
+        q_blk = _rows(q_refs, rows)
+        do_blk = do_ref[0, 0, rows, :]
         lse = lse_ref[0, 0, pl.ds(qt, 1), :]                  # [1, bq]
         delta = delta_ref[0, 0, pl.ds(qt, 1), :]
-        return (q_blk, do_blk, _scaled(_scores(k, q_blk), scale) - lse,    # s - lse [bk, bq]
+        return (rows, q_blk, do_blk, _scaled(_scores(k, q_blk), scale) - lse,  # s - lse [bk, bq]
                 _dot(v, do_blk, _NT) - delta)
+
+    def add_dq(rows, ds):
+        """ds^T @ k into the query tile's rows of the head's dQ."""
+        for acc, k_part in zip(acc_refs, k):
+            acc[rows, :] += _dot(ds, k_part, _TN)
 
     def side(qt):
         return tuple(qa_ref[a, pl.ds(qt, 1), :] for a in range(n_attr)), k_attrs
@@ -337,25 +302,28 @@ def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
         def step(j, carry):
             dk, dv = carry
             qt = single(j)
-            q_blk, do_blk, x, dpd = tile(qt)
+            rows, q_blk, do_blk, x, dpd = tile(qt)
             if masked:
                 x = jnp.where(rule.allowed_from(*side(qt)) & inside, x, -jnp.inf)
             p = jnp.exp(x)
             dv = dv + _dot(p.astype(do_blk.dtype), do_blk, _NN)
             ds = (p * dpd).astype(q_blk[0].dtype)
+            add_dq(rows, ds)
             return tuple(d + _dot(ds, q, _NN) for d, q in zip(dk, q_blk)), dv
         return step
 
     def pair_step(j, carry):
         dk, dv = carry
         ta, tb = pair(j)
-        q_a, do_a, x_a, dpd_a = tile(ta)
-        q_b, do_b, x_b, dpd_b = tile(tb)
+        rows_a, q_a, do_a, x_a, dpd_a = tile(ta)
+        rows_b, q_b, do_b, x_b, dpd_b = tile(tb)
         ok_a, x = _pair_merged(rule, side(ta), side(tb), x_a, x_b, union_whole)
         p = jnp.exp(x + _past(inside))
         p_a, p_b = _split(ok_a, p, do_a.dtype)
         dv = dv + _dot(p_a, do_a, _NN) + _dot(p_b, do_b, _NN)
         ds_a, ds_b = _split(ok_a, p * jnp.where(ok_a, dpd_a, dpd_b), q_a[0].dtype)
+        add_dq(rows_a, ds_a)
+        add_dq(rows_b, ds_b)
         return tuple(d + _dot(ds_a, a, _NN) + _dot(ds_b, b, _NN)
                      for d, a, b in zip(dk, q_a, q_b)), dv
 
@@ -363,9 +331,17 @@ def _dkv_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
     n_plain = jnp.where((ik + 1) * bk > length, 0, n_whole)
     zeros = lambda ref: jnp.zeros((bk, ref.shape[3]), jnp.float32)
     dk, dv = run(n_plain, single_step, pair_step, (tuple(map(zeros, k_refs)), zeros(v_ref)))
+    # the score's scale, once a tile of rows and not once a score
     for d, ref in zip(dk, dk_refs):
         ref[0, 0] = _scaled(d, scale).astype(ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ik == pl.num_programs(2) - 1)
+    def _():
+        def finish(rows):
+            for acc, ref in zip(acc_refs, dq_refs):
+                ref[0, 0, rows, :] = _scaled(acc[rows, :], scale).astype(ref.dtype)
+        query_tiles(finish)
 
 
 # small int tables visible to every program: scalar memory
@@ -447,39 +423,19 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
     parts = len(q)
     nq = T // bq
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = delta.reshape(B, H, nq, 1, bq)
-    qa_col, qa_row = _attr_arrays(rule, T, bq)
-    ka_col, ka_row = _attr_arrays(rule, T, bk)
-    n_attr = qa_col.shape[0]
-
-    tables, walk = _walk(rule, T, bq, bk, False)
-    stat_q = pl.BlockSpec((1, 1, 1, 1, bq), lambda b, h, i: (b, h, i, 0, 0))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, rule=rule, n_attr=n_attr, parts=parts, block_k=bk,
-                          scale=scale, **walk),
-        name="attention_dq",
-        grid=(B, H, nq),
-        in_specs=[
-            *[_SMEM] * 4,
-            pl.BlockSpec((n_attr, bq, 1), lambda b, h, i: (0, i, 0)),
-            pl.BlockSpec(ka_row.shape, lambda b, h, i: (0, 0, 0)),
-            *_head_blocks(q, bq, True), *_head_blocks(k + (v,), T, False, H),
-            *_head_blocks([do], bq, True), stat_q, stat_q,
-        ],
-        out_specs=_head_blocks(q, bq, True),
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q],
-        interpret=interpret,
-        compiler_params=_params(),
-    )(lengths, *tables, qa_col, ka_row, *q, *k, v, do, lse, delta)
-
+    _, qa_row = _attr_arrays(rule, T, bq)
+    ka_col, _ = _attr_arrays(rule, T, bk)
+    n_attr = qa_row.shape[0]
     tables, walk = _walk(rule, T, bq, bk, True)
     stat_full = pl.BlockSpec((1, 1, nq, bq), lambda b, h, i: (b, h, 0, 0))
-    # a gradient a QUERY head: [B, H, T, D_i] for part i's keys, [B, H, T, Dv]
+    # dQ a query head whole, its block the same for all the head's key tiles;
+    # dK and dV a QUERY head too: [B, H, T, D_i] for part i's keys, [B, H, T, Dv]
+    dq_shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q]
     d_shapes = [jax.ShapeDtypeStruct((B, H, T, x.shape[3]), x.dtype) for x in k + (v,)]
-    *dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, rule=rule, n_attr=n_attr, parts=parts, block_q=bq,
+    *grads, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, rule=rule, n_attr=n_attr, parts=parts, block_q=bq,
                           scale=scale, **walk),
-        name="attention_dkv",
+        name="attention_bwd",
         grid=(B, H, T // bk),
         in_specs=[
             *[_SMEM] * 4,
@@ -488,12 +444,14 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
             *_head_blocks(q, T, False), *_head_blocks(k + (v,), bk, True, H),
             *_head_blocks([do], T, False), stat_full, stat_full,
         ],
-        out_specs=_head_blocks(d_shapes, bk, True),
-        out_shape=d_shapes,
+        out_specs=[*_head_blocks(dq_shapes, T, False), *_head_blocks(d_shapes, bk, True)],
+        out_shape=[*dq_shapes, *d_shapes],
+        scratch_shapes=[pltpu.VMEM((T, x.shape[3]), jnp.float32) for x in q],
         interpret=interpret,
         compiler_params=_params(),
     )(lengths, *tables, qa_row, ka_col, *q, *k, v, do,
       lse.reshape(B, H, nq, bq), delta.reshape(B, H, nq, bq))
+    dq, dk = grads[:parts], grads[parts:]
 
     def fold(d, x):
         """The query heads that share a K/V head: summed in float32."""
@@ -542,22 +500,25 @@ def as_parts(x) -> tuple:
 
 def supported(T: int, D, itemsize: int = 2, value_dim: int = 0) -> bool:
     """Shapes the kernels handle: T a multiple of a tile edge, heads the
-    MXU takes whole, and a K/V head (backward: a query head and its
-    cotangent) that fits the planned VMEM twice over beside the tiles'
-    float32 temporaries: 12 score-sized ones, for a pair's step holds two
-    score tiles and their two cotangents where a single's holds one of
-    each (8 were planned before tiles were paired). ``D``: the scores'
-    width, or the widths of their parts; ``value_dim``: the values' (the
-    scores' by default). A part narrower than a lane tile sits in VMEM as
-    a whole one."""
+    MXU takes whole, and what the backward kernel keeps of a QUERY head in
+    VMEM while the head's key tiles pass, planned beside the tiles' float32
+    temporaries: the head's q and its cotangent twice over (the forward's
+    K/V head twice over is no larger), and the head's dQ, a score part its
+    output block twice over and its float32 accumulator. The temporaries
+    are 12 score-sized ones, for a pair's step holds two score tiles and
+    their two cotangents where a single's holds one of each. ``D``: the
+    scores' width, or the widths of their parts; ``value_dim``: the values'
+    (the scores' by default). A part narrower than a lane tile sits in VMEM
+    as a whole one."""
     widths = as_parts(D)
     value_dim = value_dim or sum(widths)
     block = default_block(T)
     if not block or any(d > 256 or d % 8 for d in widths + (value_dim,)):
         return False
     lanes = lambda d: -(-d // _LANES) * _LANES
-    held = sum(map(lanes, widths)) + lanes(value_dim)
-    resident = 2 * T * held * itemsize
+    scores = sum(map(lanes, widths))
+    held = scores + lanes(value_dim)
+    resident = 2 * T * held * itemsize + T * scores * (2 * itemsize + 4)
     tiles = 12 * block * block * 4 + 4 * block * held * 4
     return resident + tiles <= _VMEM_PLAN
 
@@ -567,7 +528,7 @@ def walk_census(rule: MaskRule, T: int) -> str:
     log: a constant of the compiled program, no measurement."""
     block = default_block(T)
     return "; ".join(f"{name} {tile_walk(rule, T, block, block, transpose).census}"
-                     for name, transpose in (("fwd/dq", False), ("dkv", True)))
+                     for name, transpose in (("fwd", False), ("bwd", True)))
 
 
 def tpu_flash_attention(

@@ -159,7 +159,7 @@ def _walked(walk):
 @pytest.mark.parametrize("kind", sorted(RULES))
 def test_the_walk_holds_every_tile_once_and_pairs_only_disjoint_ones(kind, transpose):
     """The host's table at the cells' size (8,192 positions, 512-wide
-    tiles), by query tile (forward, dQ) and by key tile (dK/dV): every
+    tiles), by query tile (the forward kernel) and by key tile (the backward one): every
     non-empty tile of `tile_occupancy` is walked exactly once, as a single
     (a row's whole tiles before its partial ones) or in a pair; a pair is two PARTIAL tiles whose
     allowed sets, in tile-local coordinates, share nothing; `union_whole`
@@ -214,12 +214,18 @@ PAIRED = {
     # the second inside the second tile of row 1's, with whole rows past it
     "short_in_the_second_tile": (MaskRule("sliding_window", window=16), 64, 8, (59, 23), 3),
     "short_window_half_a_tile": (MaskRule("sliding_window", window=8), 64, 6, (64, 37), 3),
+    # the one backward kernel under the rules with no pair by key tile: a head's dq
+    # summed over 4 or 8 key tiles, and a sequence's end inside a tile
+    "full": (MaskRule("full"), 64, 6, None, 0),
+    "short_full": (MaskRule("full"), 64, 8, (64, 41), 0),
+    "short_causal": (MaskRule("causal"), 64, 6, (50, 64), 0),
+    "short_block_diffusion": (MaskRule("block_diffusion", 4), 128, 8, (128, 77), 4),
 }
 
 
 @pytest.mark.parametrize("case", sorted(PAIRED))
 def test_paired_tiles_match_the_xla_path(case, monkeypatch):
-    """The three kernels (interpreted, 16-wide tiles) with the pairs the
+    """The two kernels (interpreted, 16-wide tiles) with the pairs the
     host finds against the XLA path of `rule_attention`: the output and
     the gradients of q, k and v. Against the same kernels walking every
     tile as a single: close where tiles are paired, and bit for bit where
@@ -267,8 +273,8 @@ def test_the_walks_census_goes_out_once_beside_the_kernels_selection(monkeypatch
         device.logger.removeHandler(caplog.handler)
     said = [r.getMessage() for r in caplog.records if "tile walk" in r.getMessage()]
     assert said == ["rule_attention T=1024 D=8 sliding_window: tile walk: "
-                    "fwd/dq 0 whole + 1 partial + 2 paired tiles in 2 rows; "
-                    "dkv 0 whole + 1 partial + 2 paired tiles in 2 rows"]
+                    "fwd 0 whole + 1 partial + 2 paired tiles in 2 rows; "
+                    "bwd 0 whole + 1 partial + 2 paired tiles in 2 rows"]
 
 
 # --------------------------------------------- the head prologue, a partial turn

@@ -82,6 +82,10 @@ KERNEL_CASES = {
     "causal-128+64-shared-over-128": (MaskRule("causal"), dict()),
     # one operand of 192 against values of 128: the single-operand form
     "causal-192-over-128": (MaskRule("causal"), dict(widths=(192,), key_heads=(4,))),
+    # two parts under the rules the other cells run: a window's pairs add to two query
+    # tiles' dq a part, and a full rule walks every key tile
+    "sliding_window-128+64-shared-over-128": (MaskRule("sliding_window", 0, 96), dict()),
+    "full-128+64-shared-over-128": (MaskRule("full"), dict()),
     # the shapes of the other two cells: ONE part, grouped heads, under their rules
     "sliding_window-one-part": (MaskRule("sliding_window", 0, 96),
                                 dict(widths=(128,), key_heads=(2,), Hv=2)),
@@ -92,7 +96,7 @@ KERNEL_CASES = {
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
 def test_kernels_with_score_parts_match_the_xla_path(case):
-    """The three flash kernels in interpret mode against `rule_attention`'s
+    """The two flash kernels in interpret mode against `rule_attention`'s
     XLA path: the result and all the gradients (five with two score parts),
     a padded sequence among them; a shared key head's gradient is the sum
     over its query heads."""
@@ -129,11 +133,11 @@ def test_the_gate_takes_the_widths():
     its answers for one width: a part narrower than a lane tile is planned
     as a whole one."""
     assert supported(8192, (128, 64), 2, 128) and supported(8192, 192, 2, 128)
-    assert supported(8192, 128) and supported(26624, 128) and not supported(27136, 128)
+    assert supported(8192, 128) and supported(17408, 128) and not supported(17920, 128)
     assert not supported(8192, (128, 60), 2, 128)          # no multiple of 8
     assert not supported(8192, (128, 64), 2, 384)          # a value wider than 256
     # three lane tiles of keys and one of values against two and one: a shorter axis
-    assert supported(26624, (128, 128), 2, 128) != supported(26624, 128, 2, 128)
+    assert supported(17408, (128, 128), 2, 128) != supported(17408, 128, 2, 128)
 
 
 # ---------------------------------------------------------- the interleaved turn

@@ -28,6 +28,39 @@ def test_supported_predicate():
     assert not supported(256, 512)     # head dim too large
 
 
+def _gate_before_the_fused_backward(T, widths, itemsize, value_dim, block):
+    """The gate as it was while dQ had a kernel of its own: a head and its
+    cotangent twice over beside the tiles' temporaries, under 40 MiB."""
+    held = sum(-(-d // 128) * 128 for d in widths + (value_dim,))
+    return (2 * T * held * itemsize + 12 * block * block * 4 + 4 * block * held * 4
+            <= 40 * 1024 * 1024)
+
+
+# a cell's heads: scores' widths, values' width (bfloat16 in all three)
+CELL_HEADS = {"sdar-and-laguna-128": ((128,), 128), "kanana-128+64-over-128": ((128, 64), 128)}
+
+
+@pytest.mark.parametrize("heads", sorted(CELL_HEADS))
+@pytest.mark.parametrize("T", [128, 256, 512, 1024, 2048, 4096, 8192])
+def test_the_gate_keeps_its_answers_up_to_the_cells_length(T, heads):
+    """The plan now counts the query head's dQ (its output block twice over
+    and its float32 accumulator): at every power of two up to the cells'
+    8,192 positions the old gate's answer, True, stands."""
+    widths, value_dim = CELL_HEADS[heads]
+    assert _gate_before_the_fused_backward(T, widths, 2, value_dim, min(T, 512))
+    assert supported(T, widths, 2, value_dim)
+
+
+@pytest.mark.parametrize("T,widths,itemsize", [
+    (26624, (128,), 2),          # the old gate's longest axis at a head of 128
+    (16384, (128, 64), 2),       # twice the latent cell's length
+    (8192, (128, 64), 4),        # the latent cell's heads in float32
+])
+def test_the_gate_refuses_where_the_heads_dq_no_longer_fits(T, widths, itemsize):
+    assert _gate_before_the_fused_backward(T, widths, itemsize, 128, 512)
+    assert not supported(T, widths, itemsize, 128)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_forward_matches_xla(causal):
     q, k, v = _qkv()
@@ -37,8 +70,13 @@ def test_forward_matches_xla(causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("block", [None, 64], ids=["one-tile", "4x4-tiles"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_xla(causal):
+def test_gradients_match_xla(causal, block):
+    """dq, dk and dv of the one backward kernel against the XLA path: a
+    head of one tile, and of 4 x 4 tiles of 64, where a head's dq is summed
+    over its key tiles in VMEM and the second sequence's end (126) cuts the
+    second tile, so that whole key tiles past it add nothing."""
     q, k, v = _qkv(1)
     lengths = jnp.asarray([T, T - 130], jnp.int32)
 
@@ -51,7 +89,8 @@ def test_gradients_match_xla(causal):
         return jnp.sum((o * m[..., None, None]) ** 2)
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, lengths=lengths, causal=causal, interpret=True)
+        o = flash_attention(q, k, v, lengths=lengths, causal=causal, interpret=True,
+                            block=block)
         m = (jnp.arange(T)[None, :] < lengths[:, None]).astype(o.dtype)
         return jnp.sum((o * m[..., None, None]) ** 2)
 
