@@ -202,7 +202,15 @@ def _setup(rest, device_job=True):
     if not os.path.exists(FLAGS.config):
         print(f"error: config file {FLAGS.config!r} not found", file=sys.stderr)
         raise SystemExit(2)
-    config = parse_config(FLAGS.config, FLAGS.config_args)
+    if not device_job:
+        return FLAGS, parse_config(FLAGS.config, FLAGS.config_args)
+    # set-up by phase: the DSL file run to the proto is a span. On the
+    # device-job path only — a span's first scope imports jax, which
+    # dump_config and merge_model never do
+    from paddle_tpu.utils.stats import stat_timer
+
+    with stat_timer("config/parse"):
+        config = parse_config(FLAGS.config, FLAGS.config_args)
     return FLAGS, config
 
 

@@ -48,6 +48,19 @@ _COUNTER_COLS = (
     ("ckpt.async_dropped", "ckpt_dropped"),
 )
 
+# the restart table's columns of time-to-first-step by phase: (heading,
+# span of the `restart` record's `spans_total`), in the order they run
+RESTART_PHASES = (
+    ("init s", "trainer/init"),
+    ("provid s", "data/provider_start"),
+    ("wait s", "trainer/data_wait"),
+    ("flops s", "trainer/flops_count"),
+    ("trace s", "compile/trace_lower"),
+    ("compil s", "compile/backend"),
+    ("report s", "compile/report"),
+    ("sync s", "trainer/loss_sync"),
+)
+
 
 def load_run(run_dir: str) -> Dict[Any, List[Dict[str, Any]]]:
     """{stream key: [records in stream order]} for one run dir.
@@ -606,16 +619,25 @@ def _fmt_table(doc: Dict[str, Any]) -> str:
         # startup work a checkpoint cannot shrink. `resumed` separates
         # cold starts from checkpoint restores.
         lines.append("")
+        # ttfs by phase, where the record carries the spans closed by the
+        # first completed launch (`spans_total`; "-" in an older stream)
+        phases = RESTART_PHASES if any(
+            r.get("spans_total") for r in doc["restarts"]) else ()
         lines.append(
             f"{'restart':<8} {'host':>4} {'pass':>5} {'restore s':>9} "
             f"{'ttfs s':>8} {'resumed':>7}"
+            + "".join(f" {h:>9}" for h, _ in phases)
         )
         for i, r in enumerate(doc["restarts"]):
+            spans = r.get("spans_total") or {}
             lines.append(
                 f"{i:<8} {r.get('host', 0):>4} {r.get('pass', -1):>5} "
                 f"{r.get('restore_s', 0.0):>9.3f} "
                 f"{r.get('time_to_first_step_s', 0.0):>8.3f} "
                 f"{'yes' if r.get('resumed') else 'no':>7}"
+                + "".join(
+                    f" {spans[n][1]:>9.3f}" if n in spans else f" {'-':>9}"
+                    for _, n in phases)
             )
         lat = doc.get("restart_latency") or {}
         if lat:

@@ -27,6 +27,10 @@ record and makes the cache persistent:
   (:meth:`CompileRegistry.note_exec`) and emits ``kind=roofline``
   records at pass end — the raw material of ``paddle roofline``.
 
+- One listener on jax's own compile events (:func:`_on_jax_event`) keeps
+  the process-wide ``jax.*`` counters: the registry's spans see its
+  launch groups, the counters see EVERY jit of the process.
+
 Cache-hit detection is host-side and observational: a compile that
 consults the persistent cache writes a new ``*-cache`` entry on a miss
 and writes nothing on a hit, so counting entries around the compile
@@ -41,6 +45,7 @@ import collections
 import hashlib
 import os
 import re
+import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -49,6 +54,7 @@ import jax
 from paddle_tpu.observability import metrics as obs
 from paddle_tpu.utils.device import device_stamp
 from paddle_tpu.utils.logging import logger
+from paddle_tpu.utils.stats import stat_timer
 
 # the enabled persistent-cache dir ("" = off) — module state, one per
 # process, matching jax's own process-global cache config
@@ -137,6 +143,61 @@ def cache_probe() -> Callable[[], Optional[bool]]:
         return after == before
 
     return hit
+
+
+# jax's own events -> the registry's cumulative counters (seconds). They
+# fire for EVERY jit of the process: the parameter initialisers, a
+# caller's own jitted helpers, the flops count's `make_jaxpr`, the second
+# compile of an AOT fallback — none of which passes `_first_call`
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower_s",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load_s",
+}
+
+
+class _ClosedEvents(threading.local):
+    """This thread's closed events that no later event has enclosed yet,
+    oldest first, as (start, seconds)."""
+
+    KEPT = 4096
+
+    def __init__(self):
+        self.events: list = []
+
+
+_closed = _ClosedEvents()
+
+
+def _on_jax_event(event: str, duration: float, **_kwargs) -> None:
+    """THE listener of jax's compile events. jax's events nest — a jit
+    traced inside another's trace fires inside it, an eager op on a
+    constant compiles inside a trace, a load from the persistent cache is
+    inside ``backend_compile_duration`` — and an event arrives when it
+    closes, with its seconds only. Each counter gets its events' SELF
+    time (the seconds less those of the events that closed inside it, on
+    this thread), so the four are disjoint and their sum is the process's
+    seconds under any of them, none twice. ``jax.compiles`` counts the
+    executables built or loaded."""
+    name = _JAX_EVENTS.get(event)
+    if name is None:
+        return
+    start = time.perf_counter() - duration
+    closed = _closed.events
+    inside = 0.0
+    while closed and closed[-1][0] >= start:
+        inside += closed.pop()[1]
+    closed.append((start, duration))
+    if len(closed) > _closed.KEPT:
+        del closed[:_closed.KEPT // 2]
+    r = obs.registry()
+    r.counter(name).inc(max(duration - inside, 0.0))
+    if name == "jax.backend_compile_s":
+        r.counter("jax.compiles").inc()
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
 
 
 _MOSAIC_RE = re.compile(
@@ -295,6 +356,7 @@ class CompileRegistry:
                 # failures (OOM etc.) propagate — after dispatch the
                 # donated args are gone and a retry would only mask the
                 # real error with "Array has been deleted".
+                obs.registry().counter("compile.aot_fallbacks").inc()
                 if group not in self._warned_degraded:
                     self._warned_degraded.add(group)
                     logger.warning(
@@ -328,27 +390,30 @@ class CompileRegistry:
         lower = getattr(fn, "lower", None)
         if lower is not None:
             try:
-                t0 = time.perf_counter()
-                lowered = lower(*args)
-                t1 = time.perf_counter()
-                compiled = lowered.compile()
-                t2 = time.perf_counter()
-                rec["trace_s"] = round(t1 - t0, 6)
-                rec["compile_s"] = round(t2 - t1, 6)
+                # the compile's halves are spans like the step's (trace_s
+                # and compile_s are read off them), and what the telemetry
+                # itself costs in set-up is a third
+                with stat_timer("compile/trace_lower") as traced:
+                    lowered = lower(*args)
+                with stat_timer("compile/backend") as built:
+                    compiled = lowered.compile()
+                rec["trace_s"] = round(traced.elapsed_s, 6)
+                rec["compile_s"] = round(built.elapsed_s, 6)
                 from paddle_tpu.observability.costs import cost_analysis_of
                 from paddle_tpu.observability.memory import memory_analysis_of
 
-                cost = cost_analysis_of(compiled)
-                # static HBM plan (argument/output/temp/generated
-                # bytes): joined onto the SAME compile record, so every
-                # launch group's planned footprint is on disk before
-                # the first step runs — the raw material of
-                # `paddle memory` and the OOM pre-mortem
-                mem = memory_analysis_of(compiled)
-                text = _hlo_text(compiled)
-                rec.update(hlo_census(compiled, text))
-                if text and self._hlo_dir:
-                    _keep_hlo(self._hlo_dir, rec, text)
+                with stat_timer("compile/report"):
+                    cost = cost_analysis_of(compiled)
+                    # static HBM plan (argument/output/temp/generated
+                    # bytes): joined onto the SAME compile record, so every
+                    # launch group's planned footprint is on disk before
+                    # the first step runs — the raw material of
+                    # `paddle memory` and the OOM pre-mortem
+                    mem = memory_analysis_of(compiled)
+                    text = _hlo_text(compiled)
+                    rec.update(hlo_census(compiled, text))
+                    if text and self._hlo_dir:
+                        _keep_hlo(self._hlo_dir, rec, text)
                 callable_ = compiled
             except Exception as e:
                 logger.debug(
@@ -360,9 +425,9 @@ class CompileRegistry:
             # no .lower (mesh-sharded closures, plain python) or AOT
             # refused: the first dispatch pays trace+compile together —
             # still measured, just not separable
-            t0 = time.perf_counter()
-            out = fn(*args)
-            rec["compile_s"] = round(time.perf_counter() - t0, 6)
+            with stat_timer("compile/backend") as built:
+                out = fn(*args)
+            rec["compile_s"] = round(built.elapsed_s, 6)
             rec["mode"] = "inline"
         hit = hit_probe()
         if hit is not None:
@@ -376,9 +441,6 @@ class CompileRegistry:
         self._cross_check(group, rec)
         r = obs.registry()
         r.counter("compile.count").inc()
-        r.counter("compile.total_s").inc(
-            rec.get("compile_s", 0.0) + rec.get("trace_s", 0.0)
-        )
         if hit is True:
             r.counter("compile.cache_hits").inc()
         elif hit is False:

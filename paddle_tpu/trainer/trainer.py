@@ -118,19 +118,27 @@ class Trainer:
         # launch — the number ROADMAP item 5 tightens heartbeat-grace
         # and crash-loop windows from
         self._t_construct = time.perf_counter()
+        # set-up by phase (doc/observability.md "Spans"): the root of the
+        # construction, its children opened where the work happens
+        with stat_timer("trainer/init"):
+            self._init(config, flags)
+
+    def _init(self, config: TrainerConfig, flags) -> None:
         self.config = config
         self.flags = flags
         from paddle_tpu.utils.device import describe_devices
 
-        # fails here, naming the reason, when the platform the run asked
-        # for (--use_tpu) is not there — never carries on elsewhere
-        device = describe_devices("trainer")
-        from paddle_tpu.native import get_lib
+        with stat_timer("trainer/init_devices"):
+            # fails here, naming the reason, when the platform the run asked
+            # for (--use_tpu) is not there — never carries on elsewhere
+            device = describe_devices("trainer")
+            from paddle_tpu.native import get_lib
 
-        # builds datapath.cc on first use; a missing toolchain degrades to
-        # NumPy packing — say which this run got, not only when it failed
-        logger.info("native datapath: %s",
-                    "loaded" if get_lib() is not None else "NumPy fallback")
+            # builds datapath.cc on first use; a missing toolchain degrades
+            # to NumPy packing — say which this run got, not only when it
+            # failed
+            logger.info("native datapath: %s",
+                        "loaded" if get_lib() is not None else "NumPy fallback")
         dtype = jnp.float32
         if flags.use_double:
             # the reference's WITH_DOUBLE build; mostly for gradient checks
@@ -141,21 +149,24 @@ class Trainer:
         # OptimizationConfig.dtype="bfloat16" → bf16 activations/matmuls
         # with f32 master weights + optimizer state (x64 builds stay full)
         compute_dtype = None if flags.use_double else compute_dtype_of(config.opt_config)
-        self.gm = GradientMachine(
-            config.model_config, dtype=dtype, compute_dtype=compute_dtype,
-            scan_unroll=config.opt_config.scan_unroll,
-            pallas_rnn=config.opt_config.pallas_rnn,
-            pallas_flat=config.opt_config.pallas_flat,
-            conv_s2d=config.opt_config.conv_s2d,
-            conv_stats_mode=config.opt_config.conv_stats_mode,
-            pallas_decoder=config.opt_config.pallas_decoder,
-        )
-        self.updater = Updater(
-            config.opt_config, config.model_config,
-            init_model_path=flags.init_model_path or config.init_model_path,
-        )
-        self.params = self.gm.init_params(seed=flags.seed)
-        self.opt_state = self.updater.init_state(self.params)
+        with stat_timer("trainer/init_graph"):
+            self.gm = GradientMachine(
+                config.model_config, dtype=dtype, compute_dtype=compute_dtype,
+                scan_unroll=config.opt_config.scan_unroll,
+                pallas_rnn=config.opt_config.pallas_rnn,
+                pallas_flat=config.opt_config.pallas_flat,
+                conv_s2d=config.opt_config.conv_s2d,
+                conv_stats_mode=config.opt_config.conv_stats_mode,
+                pallas_decoder=config.opt_config.pallas_decoder,
+            )
+            self.updater = Updater(
+                config.opt_config, config.model_config,
+                init_model_path=flags.init_model_path or config.init_model_path,
+            )
+        with stat_timer("trainer/init_params"):
+            self.params = self.gm.init_params(seed=flags.seed)
+        with stat_timer("trainer/init_opt_state"):
+            self.opt_state = self.updater.init_state(self.params)
         self.start_pass = flags.start_pass or config.start_pass
         self.save_dir = flags.save_dir or config.save_dir
         self._train_step_fn = None
@@ -957,8 +968,15 @@ class Trainer:
         return guard()
 
     def train(self, num_passes: Optional[int] = None) -> None:
+        # the root of one call: the provider, the liveness plumbing, the
+        # passes, the closing save
+        with stat_timer("trainer/train"):
+            self._train(num_passes)
+
+    def _train(self, num_passes: Optional[int]) -> None:
         num_passes = num_passes or self.flags.num_passes
-        train_provider = self._provider(for_test=False)
+        with stat_timer("data/provider_start"):
+            train_provider = self._provider(for_test=False)
         assert train_provider is not None, "no train data configured"
         if self._batch_method is not None:
             if self._hangwatch is not None:
@@ -1559,6 +1577,11 @@ class Trainer:
                             time.perf_counter() - self._t_construct, 6
                         ),
                         resumed=self._restored_pass is not None,
+                        # that time by phase: every span closed since
+                        # process start (`trainer/init` and its children,
+                        # `data/provider_start`, this launch's wait, flops
+                        # count, `compile/*` and loss sync)
+                        spans_total=global_stats.totals(),
                     )
                 batch_id_start = batch_id
                 for loss_f, outputs, states, n in results:
@@ -1731,8 +1754,13 @@ class Trainer:
                 self._hangwatch.take_max_age(), 3
             )
         # the step's phases without a profiler: {span: [count, total_s]}
-        # over this pass, the same scopes the trace shows
-        record["spans"] = global_stats.growth_since(spans_before)
+        # over this pass, the same scopes the trace shows; and the same
+        # since process start, by the convention of `counters`, so that
+        # what ran between passes or before the first (set-up) is in a
+        # record too
+        spans_now = global_stats.snapshot()
+        record["spans"] = global_stats.growth_since(spans_before, spans_now)
+        record["spans_total"] = global_stats.totals(spans_now)
         if obs.enabled():
             record["counters"] = obs.registry().snapshot()
         obs.emit("pass_end", pass_id=pass_id, step=batch_id, **record)

@@ -59,15 +59,24 @@ class StatSet:
             stats = list(self._stats.values())
         return {s.name: (s.count, s.total_s) for s in stats}
 
-    def growth_since(self, before: Dict[str, Tuple[int, float]]) -> Dict[str, list]:
+    def growth_since(self, before: Dict[str, Tuple[int, float]],
+                     now: Optional[Dict[str, Tuple[int, float]]] = None) -> Dict[str, list]:
         """name -> [count, total_s] added since the ``before`` snapshot
-        (scopes that did not run are left out)."""
+        (scopes that did not run are left out), up to the ``now`` snapshot
+        where the caller took one already."""
         out = {}
-        for name, (count, total_s) in self.snapshot().items():
+        for name, (count, total_s) in (now or self.snapshot()).items():
             c0, t0 = before.get(name, (0, 0.0))
             if count > c0:
                 out[name] = [count - c0, round(total_s - t0, 6)]
         return out
+
+    def totals(self, now: Optional[Dict[str, Tuple[int, float]]] = None) -> Dict[str, list]:
+        """name -> [count, total_s] since process start, in a record's
+        form: what ``pass_end`` and ``restart`` carry as ``spans_total``.
+        A scope still open (the pass's own, the ``train()`` call's) is not
+        in it yet."""
+        return self.growth_since({}, now)
 
     def reset(self) -> None:
         with self._lock:
@@ -126,9 +135,13 @@ class stat_timer:
 
     A scope left by an exception, or ``drop()``ped, reaches the profiler
     trace only: it is no completed unit of the work it names.
+
+    ``elapsed_s``: the scope's seconds once it has closed, for a caller
+    that writes them into a record of its own (the compile record's
+    ``trace_s`` / ``compile_s``): one clock, one primitive.
     """
 
-    __slots__ = ("name", "step_num", "_t0", "_ann", "_dropped")
+    __slots__ = ("name", "step_num", "elapsed_s", "_t0", "_ann", "_dropped")
 
     def __init__(self, name: str, step_num: Optional[int] = None):
         self.name = name
@@ -150,8 +163,8 @@ class stat_timer:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self._ann.__exit__(exc_type, exc, tb)
+        self.elapsed_s = dt = time.perf_counter() - self._t0
         if exc_type is None and not self._dropped:
-            dt = time.perf_counter() - self._t0
             global_stats.get(self.name).add(dt)
             _hooks[2](self.name, self._t0, dt)
         return False

@@ -196,11 +196,17 @@ def test_roofline_bucket_classification():
 # ------------------------------------------------ persistent cache e2e
 
 
-def test_compile_cache_two_runs_hit_and_faster_ttfs(tmp_path):
-    """Acceptance: two `paddle train` runs sharing --compile_cache_dir —
-    the second run's compile records show cache hits and a measured
-    drop in time_to_first_step_s."""
-    cfg = _lr_config(tmp_path, hidden=256)  # big enough that compile dominates
+@pytest.fixture(scope="module")
+def cache_runs(tmp_path_factory):
+    """Two `paddle train` processes sharing --compile_cache_dir, the first
+    on an empty directory: ((compile records, restart record, last
+    pass_end record) of the cold run, the same of the warm one)."""
+    tmp_path = tmp_path_factory.mktemp("cache_runs")
+    sys.path.insert(0, PROVIDER_DIR)
+    try:
+        cfg = _lr_config(tmp_path, hidden=256)  # big enough that compile dominates
+    finally:
+        sys.path.remove(PROVIDER_DIR)
     cache = str(tmp_path / "cache")
 
     def run(name):
@@ -217,10 +223,16 @@ def test_compile_cache_two_runs_hit_and_faster_ttfs(tmp_path):
         compiles = [x for x in recs if x["kind"] == "compile"]
         restart = [x for x in recs if x["kind"] == "restart"]
         assert compiles and len(restart) == 1
-        return compiles, restart[0]
+        return compiles, restart[0], [x for x in recs if x["kind"] == "pass_end"][-1]
 
-    c_cold, r_cold = run("runA")
-    c_warm, r_warm = run("runB")
+    return run("runA"), run("runB")
+
+
+def test_compile_cache_two_runs_hit_and_faster_ttfs(cache_runs):
+    """Acceptance: two `paddle train` runs sharing --compile_cache_dir —
+    the second run's compile records show cache hits and a measured
+    drop in time_to_first_step_s."""
+    (c_cold, r_cold, _), (c_warm, r_warm, _) = cache_runs
     # cold run: all misses (cache dir was empty); warm run: all hits
     assert all(c.get("cache_hit") is False for c in c_cold), c_cold
     assert all(c.get("cache_hit") is True for c in c_warm), c_warm
@@ -231,6 +243,72 @@ def test_compile_cache_two_runs_hit_and_faster_ttfs(tmp_path):
     # ...and time_to_first_step_s drops measurably (restore + trace
     # still run; the XLA half is what the cache absorbs)
     assert r_warm["time_to_first_step_s"] < r_cold["time_to_first_step_s"]
+
+
+def test_jax_counters_tell_a_cold_process_from_a_warm_one(cache_runs):
+    """The listener's counters see every jit of the process, the
+    registry's launch groups and the rest: both processes trace, lower
+    and build executables; the warm one loads from the cache what the cold
+    one compiled, and the seconds move from the one counter to the other."""
+    (c_cold, _, cold), (_, _, warm) = cache_runs
+    # the pass's record is written before the pass-end test compiles
+    steps = [r for r in c_cold if r["group"] == "train_step"]
+    for end in (cold, warm):
+        c = end["counters"]
+        assert c["jax.trace_s"] > 0 and c["jax.lower_s"] > 0
+        assert c["jax.backend_compile_s"] > 0
+        assert c["jax.compiles"] >= c["compile.count"] == len(steps)
+        assert c.get("compile.aot_fallbacks", 0) == 0
+        assert "compile.total_s" not in c
+        # through `cli._setup`: the DSL file's parse is a span of the run
+        assert end["spans_total"]["config/parse"][0] == 1
+        assert end["spans_total"]["trainer/init"][0] == 1
+    # (a cold process hits too, where two of its jits lower to one module)
+    cold, warm = cold["counters"], warm["counters"]
+    assert warm["jax.cache_load_s"] > cold.get("jax.cache_load_s", 0)
+    assert warm["jax.backend_compile_s"] < cold["jax.backend_compile_s"]
+    assert warm["jax.compiles"] == cold["jax.compiles"]
+
+
+def test_jax_counters_grow_on_a_first_compile_only():
+    import jax
+    import jax.numpy as jnp
+
+    names = ("jax.trace_s", "jax.lower_s", "jax.backend_compile_s",
+             "jax.compiles")
+    x = jnp.ones((3, 5))                     # its own fill compiles here
+    fn = jax.jit(lambda a: jnp.tanh(a) @ a.T + 36.0)
+    before = obs.registry().snapshot()
+    jax.block_until_ready(fn(x))
+    first = obs.registry().snapshot()
+    for n in names:
+        assert first[n] > before.get(n, 0), n
+    assert first["jax.compiles"] == before.get("jax.compiles", 0) + 1
+    jax.block_until_ready(fn(x))             # the same shape: nothing new
+    assert obs.registry().snapshot() == first
+
+
+def test_jax_event_seconds_are_self_time():
+    """An event that closed inside another's seconds (a jit traced inside
+    a trace, a cache load inside a backend compile) is taken out of the
+    outer one's: the counters are disjoint."""
+    import time
+
+    on = compile_log._on_jax_event
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    build = "/jax/core/compile/backend_compile_duration"
+    load = "/jax/compilation_cache/cache_retrieval_time_sec"
+    time.sleep(0.05)
+    on(trace, 0.01)       # closed 0.01 s ago at the earliest ...
+    on(trace, 0.04)       # ... so inside this one
+    on(load, 0.5e-3)
+    on(build, 2e-3, fun_name="f")            # holds the load, not the traces
+    on("/jax/some/other_event", 7.0)
+    c = obs.registry().snapshot()
+    assert c["jax.trace_s"] == pytest.approx(0.04)
+    assert c["jax.cache_load_s"] == pytest.approx(0.5e-3)
+    assert c["jax.backend_compile_s"] == pytest.approx(1.5e-3)
+    assert c["jax.compiles"] == 1 and "jax.lower_s" not in c
 
 
 # ------------------------------------------------------ fallback paths
@@ -285,6 +363,42 @@ def test_registry_inline_fallback_without_lower(tmp_path):
     assert rec["compile_s"] > 0 and "trace_s" not in rec
     assert "flops" not in rec  # no executable to cost-analyze
     assert calls == [21, 4]
+
+
+def test_registry_counts_an_aot_executable_given_up_for_jit_dispatch(tmp_path, caplog):
+    """An AOT executable that rejects its inputs before dispatch is given
+    up for jit dispatch, which compiles the step AGAIN: one increment of
+    `compile.aot_fallbacks`, once an entry (the entry keeps the jit path
+    after), and the second compile shows in `jax.compiles`."""
+    import logging
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.utils.logging import logger as ptu_logger
+
+    obs.configure(str(tmp_path), host=0)
+    reg = compile_log.CompileRegistry(device_kind="cpu")
+    fn = jax.jit(lambda x: x * 2.0 + 1.0)
+    small, wide = jnp.ones((3,)), jnp.ones((4,))
+    assert float(reg.call("train_step", ("sig",), fn, small)[0]) == 3.0
+    c = obs.registry().snapshot()
+    assert c.get("compile.aot_fallbacks", 0) == 0
+    # the registry's key says "same signature", the executable disagrees
+    ptu_logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.WARNING, logger="paddle_tpu"):
+            out = reg.call("train_step", ("sig",), fn, wide)
+    finally:
+        ptu_logger.removeHandler(caplog.handler)
+    after = obs.registry().snapshot()
+    assert out.shape == (4,) and float(out[0]) == 3.0
+    assert "falling back to jit dispatch" in caplog.text
+    assert after["compile.aot_fallbacks"] == 1
+    assert after["jax.compiles"] == c["jax.compiles"] + 1
+    assert after["compile.count"] == c["compile.count"] == 1
+    reg.call("train_step", ("sig",), fn, wide)
+    assert obs.registry().snapshot()["compile.aot_fallbacks"] == 1
 
 
 def test_registry_cost_analysis_raise_keeps_compile_record(tmp_path, monkeypatch):

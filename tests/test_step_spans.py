@@ -3,13 +3,20 @@ phase of a launch is a `stat_timer` span under one `trainer/step` root, on
 the profiler trace's clock and in the `pass_end` record's `spans`; every
 layer, the cost and the optimizer run under a `jax.named_scope`; and each
 compile's optimized HLO text, which maps a profile's device events to those
-scopes, is kept beside its record. No assertion here is on a duration."""
+scopes, is kept beside its record. Round the steps, set-up is spans too
+(`config/parse`, `trainer/init` and its children, `trainer/train`,
+`data/provider_start`, `compile/*`), in the `pass_end` record's
+`spans_total` and the `restart` record's, which the benchmark's set-up
+readers read. No assertion here is on a duration."""
 
 import glob
 import os
 import re
+import subprocess
 import sys
 import textwrap
+import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +44,13 @@ OTHERS = ("trainer/step", "trainer/pass", "trainer/test", "data/prefetch_wait",
           "eval/classification_error", "checkpoint/save")
 # an evaluator with no in-step form: its layer stays a program output
 HOST_EVALUATOR = "value_printer_evaluator(input=output)"
+# set-up by phase: the children of `Trainer.__init__`'s span, and the
+# compile registry's three
+INIT_PARTS = ("trainer/init_devices", "trainer/init_graph",
+              "trainer/init_params", "trainer/init_opt_state")
+COMPILE_PARTS = ("compile/trace_lower", "compile/backend", "compile/report")
+SETUP_METRICS = ("setup_trainer_init_s", "setup_trace_lower_s",
+                 "setup_compile_s", "setup_outside_program_pct")
 
 
 def _config(tmp, evaluator=""):
@@ -276,3 +290,149 @@ def test_stat_timer_alone_adds_only_the_statset_entry(monkeypatch):
     with stat_timer("test/scope"):
         pass
     assert [e[0] for e in events] == ["test/scope"]
+
+
+# ------------------------------------------------------ set-up by phase
+
+
+@pytest.fixture(scope="module")
+def cli_run(tmp_path_factory):
+    """`paddle train` as the CLI builds it (`cli._setup` -> `Trainer`), two
+    `train()` calls of one pass each, in a StatSet and a registry emptied
+    first as a new process has them: the first call stands for a
+    benchmark's set-up, the second for its window."""
+    from paddle_tpu import cli
+    from paddle_tpu.utils.flags import FLAGS
+
+    tmp = tmp_path_factory.mktemp("cli_run")
+    saved = dict(vars(FLAGS))
+    sys.path.insert(0, PROVIDER_DIR)
+    try:
+        global_stats.reset()
+        obs.registry().reset()
+        t_start = time.monotonic()
+        flags, cfg = cli._setup([
+            f"--config={_config(tmp)}", f"--save_dir={tmp / 'out'}",
+            f"--metrics_path={tmp / 'metrics'}", "--num_passes=1",
+            "--log_period=0", "--seed=7", "--use_tpu=0"])
+        trainer = Trainer(cfg, flags)
+        trainer.train(num_passes=1)
+        trainer.start_pass = 1
+        setup_s = time.monotonic() - t_start
+        trainer.train(num_passes=2)
+        records = list(obs.read_records(str(tmp / "metrics" / "metrics.jsonl")))
+        return types.SimpleNamespace(
+            records=records, setup_s=setup_s, stats=global_stats.snapshot(),
+            metrics_dir=str(tmp / "metrics"))
+    finally:
+        sys.path.remove(PROVIDER_DIR)
+        obs.configure("")
+        obs_spans.configure("")
+        vars(FLAGS).clear()
+        vars(FLAGS).update(saved)
+
+
+def test_cli_run_shows_every_setup_span_with_the_counts_it_implies(cli_run):
+    stats = cli_run.stats
+    compiles = [r for r in cli_run.records if r["kind"] == "compile"]
+    assert compiles and not any(r.get("mode") == "inline" for r in compiles)
+    want = {"config/parse": 1, "trainer/init": 1, "trainer/train": 2,
+            "data/provider_start": 2, "trainer/pass": 2,
+            **{n: 1 for n in INIT_PARTS},
+            **{n: len(compiles) for n in COMPILE_PARTS}}
+    assert {n: stats.get(n, (0, 0.0))[0] for n in want} == want
+    # the compile record's two durations are the spans' own
+    for part, key in (("compile/trace_lower", "trace_s"),
+                      ("compile/backend", "compile_s")):
+        assert stats[part][1] == pytest.approx(
+            sum(r[key] for r in compiles), abs=1e-5 * len(compiles))
+
+
+def test_children_of_a_setup_span_lie_inside_it(cli_run):
+    def total(*names):
+        return sum(cli_run.stats[n][1] for n in names)
+
+    assert total(*INIT_PARTS) <= total("trainer/init")
+    assert (total("data/provider_start", "trainer/pass", "trainer/test",
+                  "checkpoint/save") <= total("trainer/train"))
+    # the train step compiles under its launch, the test forward under
+    # the pass-end test
+    assert total(*COMPILE_PARTS) <= total("trainer/launch", "trainer/test")
+
+
+def test_pass_end_totals_are_the_totals_before_plus_the_pass(cli_run):
+    first, second = [r for r in cli_run.records if r["kind"] == "pass_end"]
+    for name in PHASES + ("trainer/step", "data/h2d"):
+        c0, s0 = first["spans_total"][name]
+        dc, ds = second["spans"][name]
+        c1, s1 = second["spans_total"][name]
+        assert c1 == c0 + dc, name
+        assert s1 == pytest.approx(s0 + ds, abs=1e-5), name
+    # a span still open at the pass's end is not in its record yet: the
+    # first call's `trainer/train` and pass are closed by the second's
+    assert "trainer/train" not in first["spans_total"]
+    assert second["spans_total"]["trainer/train"][0] == 1
+    assert second["spans_total"]["trainer/pass"][0] == 1
+    assert first["spans_total"]["trainer/init"][0] == 1
+    # cumulative like the counters beside them
+    for name in ("jax.trace_s", "jax.lower_s", "jax.backend_compile_s"):
+        assert second["counters"][name] >= first["counters"][name] > 0
+    assert first["counters"]["jax.compiles"] >= first["counters"]["compile.count"]
+
+
+def test_restart_record_carries_time_to_first_step_by_phase(cli_run):
+    (restart,) = [r for r in cli_run.records if r["kind"] == "restart"]
+    assert obs.validate_record(restart) == []
+    spans = restart["spans_total"]
+    for name in ("config/parse", "trainer/init", "data/provider_start",
+                 "trainer/data_wait", "trainer/flops_count",
+                 "trainer/launch", "trainer/loss_sync") + INIT_PARTS + COMPILE_PARTS:
+        assert spans[name][0] == 1, name
+    # the launch's step, its pass and its call are still open
+    assert not {"trainer/step", "trainer/pass", "trainer/train"} & set(spans)
+    # and `paddle metrics` prints the phases beside the two numbers
+    from paddle_tpu.observability.analyze import (RESTART_PHASES, _fmt_table,
+                                                  analyze, load_run)
+
+    table = _fmt_table(analyze(load_run(cli_run.metrics_dir)))
+    (head,) = [l for l in table.splitlines() if l.startswith("restart  ")]
+    assert all(h in head for h, _ in RESTART_PHASES)
+
+
+def test_setup_readers_over_the_cli_run(cli_run):
+    """The benchmark's four readers of set-up, over this run's records:
+    the first `train()` call is the set-up, the second the window."""
+    from perfbench import harness
+
+    pb = os.path.join(os.path.dirname(os.path.dirname(__file__)), "perfbench")
+    view = types.SimpleNamespace(run=types.SimpleNamespace(
+        trace_dir=os.path.join(cli_run.metrics_dir, "trace"),
+        values={"setup_s": cli_run.setup_s}))
+    got = {m: harness.load_module(
+        os.path.join(pb, "layer_metrics", m + ".py")).read(view)
+        for m in SETUP_METRICS}
+    assert all(isinstance(v, float) for v in got.values()), got
+    first, second = [r for r in cli_run.records if r["kind"] == "pass_end"]
+    assert got["setup_trainer_init_s"] == first["spans_total"]["trainer/init"][1]
+    assert got["setup_trace_lower_s"] == (
+        first["counters"]["jax.trace_s"] + first["counters"]["jax.lower_s"])
+    assert sum(got[m] for m in SETUP_METRICS[:3]) < cli_run.setup_s
+    assert 0 < got["setup_outside_program_pct"] < 100
+
+
+def test_dump_config_opens_no_span_and_imports_no_jax(tmp_path):
+    """`config/parse` is the device jobs': a span's first scope imports
+    jax, which `paddle dump_config` must stay usable without."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from paddle_tpu import cli\n"
+            f"rc = cli.main(['dump_config', '--config={_config(tmp_path)}'])\n"
+            "from paddle_tpu.utils.stats import global_stats\n"
+            "assert global_stats.snapshot() == {}, global_stats.snapshot()\n"
+            "sys.exit(rc)\n")
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                          "PYTHONPATH": f"{repo}:{repo}/compat:{PROVIDER_DIR}"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert '"model_config"' in out.stdout
