@@ -156,6 +156,7 @@ def _project_out(cfg: LayerConfig, arg: Argument, ctx: LayerContext, out: Array)
 
 
 def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext) -> Argument:
+    from paddle_tpu.ops.pallas_attention import gate_heads
     from paddle_tpu.ops.pallas_head_prologue import head_prologue, turn_tables
     from paddle_tpu.parallel.sequence_parallel import rule_attention
 
@@ -175,18 +176,18 @@ def _grouped_query_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext)
         turn = turn_tables(pos, cfg.rope_theta, Dh, rot, tuple(cfg.rope_yarn) or None,
                            cfg.rope_attention_factor) if cfg.rope_theta else None
         # the scores' 1/sqrt(Dh) is folded into q: the kernel multiplies no
-        # score. The prologue leaves [B, H, T, Dh], as the flash kernels read
-        # it; relabelled to rule_attention's [B, T, H, Dh] here, its Pallas
-        # path's own transpose undoes this one and neither moves anything
-        q = head_prologue(q, gains[0], turn, Dh, cfg.norm_epsilon, Dh ** -0.5, rot).transpose(0, 2, 1, 3)
-        k = head_prologue(k, gains[1], turn, Dh, cfg.norm_epsilon, 1.0, rot).transpose(0, 2, 1, 3)
+        # score. The prologue leaves the projection's layout with its heads
+        # named, [B, T, H, Dh], and the flash kernels read a head where it
+        # lies (`pallas_attention.by_column`): nothing is transposed
+        q = head_prologue(q, gains[0], turn, Dh, cfg.norm_epsilon, Dh ** -0.5, rot)
+        k = head_prologue(k, gains[1], turn, Dh, cfg.norm_epsilon, 1.0, rot)
     with jax.named_scope("core"):
         out = rule_attention(q, k, v, arg.seq_lengths, rule, scale=1.0)
     if cfg.output_gate:
         with jax.named_scope("gate"):
             g = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", x, ctx.param(f"_{cfg.name}.wg"),
                                           preferred_element_type=jnp.float32))
-            out = (out.astype(jnp.float32) * g[..., None]).astype(out.dtype)
+            out = gate_heads(out, g)
     return _project_out(cfg, arg, ctx, out)
 
 
@@ -207,12 +208,13 @@ def _latent_attention(cfg: LayerConfig, arg: Argument, ctx: LayerContext) -> Arg
     # lanes: q_rope and k_rope reordered alike give the same scores)
     order = interleaved_order(dr) if cfg.rope_interleave else slice(None)
     # a part with rotary lanes goes through the head prologue, which turns it
-    # and leaves [B, H, T, dr] as the flash kernels read it: relabelled to
-    # rule_attention's [B, T, H, dr] here, its own transpose undoes this one.
-    # The other parts need no prologue (no per-head norm, no turn): they stay
-    # as the products leave them, and nothing of them is kept for a backward
-    turned = lambda y, scale: head_prologue(
-        y, None, turn, dr, cfg.norm_epsilon, scale).transpose(0, 2, 1, 3)
+    # and leaves [B, T, heads, dr]. The other parts need no prologue (no
+    # per-head norm, no turn), and nothing of them is kept for a backward.
+    # Every part stays as its product leaves it, and the flash kernels read a
+    # head where it lies (`pallas_attention.by_column`): q_nope, k_nope and v
+    # as 128-lane column blocks, k_rope as its one head; only q_rope, many
+    # heads of 64 lanes, is transposed to head-major round the kernels
+    turned = lambda y, scale: head_prologue(y, None, turn, dr, cfg.norm_epsilon, scale)
     with jax.named_scope("qkv"):
         wq = ctx.param(f"_{cfg.name}.wq").reshape(D, H, dn + dr)
         # the scores' 1/sqrt(dn + dr) is folded into both parts of q

@@ -36,19 +36,35 @@ Grouped-query heads: query head h reads K/V head ``h // (H / Hkv)``;
 dK/dV come back a query head and are summed over each group outside; dQ
 is a query head's own.
 
-Layout: q [B, H, T, D], k/v [B, Hkv, T, D] inside the kernels (callers
-transpose from the [B, T, H, D] sequence_parallel layout). Forward: a K/V
-head stays whole in VMEM while its query heads' tiles pass. Backward: a
-query head, its cotangent and its dQ stay whole while its key tiles pass.
+Layout: operands and results are the caller's [B, T, heads, D] arrays
+(the sequence_parallel layout, and what a projection leaves: a free
+reshape of its [B, T, heads*D]). A kernel sees one head's [rows, D] tile in
+VMEM either way; where it lies in HBM is the BlockSpec's business
+(`by_column`): a head of whole lane tiles (D a multiple of 128), or a
+part's only head, is a COLUMN BLOCK of [B, T, heads*D], ``rows`` runs of D
+lanes at the pitch of a position, so nothing is transposed before or after
+the kernels; a part with several heads narrower than a lane tile (latent
+attention's 32 rotary query heads of 64) is transposed to [B, heads, T, D]
+round them, the one place a transpose is left, for Mosaic cannot cut a
+64-lane block out of a wider row. What else touches these arrays stays on
+[B, T, heads*D] and never splits its lanes into two axes (XLA would answer
+with a relayout copy of the whole array: a tiled array's lane tile is 128
+wide): the backward's per-row sum of do * out is a third small kernel,
+`attention_delta`, the fold of a group's dK and dV adds 128-lane slices,
+the `out` a recomputation block keeps is kept as the kernel wrote it, and
+a per-head output gate is `gate_heads`, one more small kernel each way.
+Forward: a K/V head stays whole in VMEM while its query heads' tiles pass.
+Backward: a query head, its cotangent and its dQ stay whole while its key
+tiles pass.
 
 The scores may come in PARTS: q and k are then tuples of as many arrays,
-part i ``[B, H, T, D_i]`` against ``[B, Hk_i, T, D_i]`` with a head count of
+part i ``[B, T, H, D_i]`` against ``[B, T, Hk_i, D_i]`` with a head count of
 its own (latent attention: 128 lanes that differ by head, and 64 rotary
 lanes that every query head reads from ONE key head, which is never
 broadcast in memory); a tile's score products are summed before the mask
 and the softmax's vector work, dq and dk come back a part, and a part's dk
 is summed over the query heads that share its key head. The values have a
-width of their own (``v [B, Hkv, T, Dv]``, and so the output). One part of
+width of their own (``v [B, T, Hkv, Dv]``, and so the output). One part of
 the values' width is the kernel as it always was. Correctness is
 tested in interpret mode on CPU against the XLA path
 (tests/test_pallas_attention.py, tests/test_block_diffusion_moe.py); what
@@ -105,8 +121,8 @@ def _scores(a_parts, b_parts):
 
 
 def _rows(refs, rows):
-    """A tile of rows of each part's [1, 1, T, D_i] block."""
-    return tuple(r[0, 0, rows, :] for r in refs)
+    """A tile of rows of each part's [T, D_i] block."""
+    return tuple(r[rows, :] for r in refs)
 
 
 def _row(col):
@@ -181,16 +197,16 @@ def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
     q_refs, k_refs, (v_ref, o_ref, lse_ref) = refs[:parts], refs[parts:2 * parts], refs[2 * parts:]
     b = pl.program_id(0)
     iq = pl.program_id(2)
-    bq, D = q_refs[0].shape[2], v_ref.shape[3]
+    bq, D = q_refs[0].shape[0], v_ref.shape[1]
     length = len_ref[b]
-    q = tuple(r[0, 0] for r in q_refs)                        # each [bq, D_i]
+    q = tuple(r[...] for r in q_refs)                         # each [bq, D_i]
     q_attrs = tuple(qa_ref[a] for a in range(n_attr))         # each [bq, 1]
     single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, iq, width, pair_width)
 
     def tile(kt):
         start = pl.multiple_of(kt * block_k, block_k)
         k_blk = _rows(k_refs, pl.ds(start, block_k))
-        v_blk = v_ref[0, 0, pl.ds(start, block_k), :]
+        v_blk = v_ref[pl.ds(start, block_k), :]
         return start, v_blk, _scaled(_scores(q, k_blk), scale)             # s [bq, bk]
 
     def side(kt):
@@ -234,7 +250,7 @@ def _fwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
     o, m, l = run(_whole_inside(single, n_whole, block_k, length), single_step, pair_step,
                   (o0, m0, l0))
     l_safe = jnp.maximum(l, 1e-20)
-    o_ref[0, 0] = (o / l_safe).astype(o_ref.dtype)
+    o_ref[...] = (o / l_safe).astype(o_ref.dtype)
     lse_ref[0, 0, 0] = _row(jnp.where(l > 0, m + jnp.log(l_safe), _NEG))
 
 
@@ -255,11 +271,11 @@ def _bwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
         refs[end - n:end] for n, end in zip(sizes, ends))
     b = pl.program_id(0)
     ik = pl.program_id(2)
-    bk = k_refs[0].shape[2]
-    n_q = q_refs[0].shape[2] // block_q
+    bk = k_refs[0].shape[0]
+    n_q = q_refs[0].shape[0] // block_q
     length = len_ref[b]
-    k = tuple(r[0, 0] for r in k_refs)
-    v = v_ref[0, 0]
+    k = tuple(r[...] for r in k_refs)
+    v = v_ref[...]
     k_attrs = tuple(ka_ref[a] for a in range(n_attr))         # each [bk, 1]
     inside = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0) < length
     single, pair, n_whole, run = _walk_row(tab_ref, ptab_ref, cnt_ref, ik, width, pair_width)
@@ -284,7 +300,7 @@ def _bwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
     def tile(qt):
         rows = rows_of(qt)
         q_blk = _rows(q_refs, rows)
-        do_blk = do_ref[0, 0, rows, :]
+        do_blk = do_ref[rows, :]
         lse = lse_ref[0, 0, pl.ds(qt, 1), :]                  # [1, bq]
         delta = delta_ref[0, 0, pl.ds(qt, 1), :]
         return (rows, q_blk, do_blk, _scaled(_scores(k, q_blk), scale) - lse,  # s - lse [bk, bq]
@@ -329,28 +345,58 @@ def _bwd_kernel(len_ref, tab_ref, ptab_ref, cnt_ref, qa_ref, ka_ref, *refs,
 
     # a key tile the sequence's end cuts masks every one of its query tiles
     n_plain = jnp.where((ik + 1) * bk > length, 0, n_whole)
-    zeros = lambda ref: jnp.zeros((bk, ref.shape[3]), jnp.float32)
+    zeros = lambda ref: jnp.zeros((bk, ref.shape[1]), jnp.float32)
     dk, dv = run(n_plain, single_step, pair_step, (tuple(map(zeros, k_refs)), zeros(v_ref)))
     # the score's scale, once a tile of rows and not once a score
     for d, ref in zip(dk, dk_refs):
-        ref[0, 0] = _scaled(d, scale).astype(ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+        ref[...] = _scaled(d, scale).astype(ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _():
         def finish(rows):
             for acc, ref in zip(acc_refs, dq_refs):
-                ref[0, 0, rows, :] = _scaled(acc[rows, :], scale).astype(ref.dtype)
+                ref[rows, :] = _scaled(acc[rows, :], scale).astype(ref.dtype)
         query_tiles(finish)
+
+
+def _head_of(ref, h, heads):
+    """Head h of the ``heads`` in a block: a [heads, rows, D] block's h-th
+    slab, or a [rows, heads*D] block's h-th block of lanes."""
+    if len(ref.shape) == 3:
+        return ref[h]
+    D = ref.shape[1] // heads
+    return ref[:, h * D:(h + 1) * D]
+
+
+def _delta_kernel(do_ref, out_ref, delta_ref):
+    """sum over a head's lanes of do * out, in float32, for a tile of rows
+    of a few heads: a row a head, [heads, rows]."""
+    heads = delta_ref.shape[0]
+    for h in range(heads):
+        prod = _head_of(do_ref, h, heads).astype(jnp.float32) * _head_of(out_ref, h, heads).astype(jnp.float32)
+        delta_ref[h:h + 1, :] = _row(jnp.sum(prod, axis=1, keepdims=True))
+
+
+def _gate_kernel(x_ref, g_ref, y_ref):
+    """A tile [rows, heads*D] times one float32 number a head a row, ``g``
+    [heads, rows] (a row a head, as `_delta_kernel` writes its sums):
+    rounded once."""
+    heads, rows = g_ref.shape
+    D = x_ref.shape[1] // heads
+    for h in range(heads):
+        lanes = slice(h * D, (h + 1) * D)
+        col = jnp.broadcast_to(g_ref[h:h + 1, :], (_LANES, rows)).T[:, 0:1]         # [rows, 1]
+        y_ref[:, lanes] = (x_ref[:, lanes].astype(jnp.float32) * col).astype(y_ref.dtype)
 
 
 # small int tables visible to every program: scalar memory
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _params():
+def _params(last="arbitrary"):
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", last),
         vmem_limit_bytes=_VMEM_LIMIT,
     )
 
@@ -371,28 +417,81 @@ def _walk(rule: MaskRule, T: int, bq: int, bk: int, transpose: bool):
     return tables, dict(width=w.width, pair_width=w.pair_width, union_whole=w.union_whole)
 
 
+def by_column(heads: int, D: int) -> bool:
+    """Whether the kernels address the heads of a [B, T, heads, D] array
+    where the projection left them, as column blocks of its free reshape
+    [B, T, heads*D]: a head of whole lane tiles, or the only head (its
+    block is the whole last dimension). Mosaic cannot cut a narrower block
+    out of a wider row: such a part (several heads of fewer than 128
+    lanes) is transposed to [B, heads, T, D] round the kernels, the one
+    place a transpose is left."""
+    return heads == 1 or D % _LANES == 0
+
+
+def _to_kernel(x):
+    """[B, T, heads, D] as the kernels address it (`by_column`)."""
+    B, T, heads, D = x.shape
+    if by_column(heads, D):
+        return x.reshape(B, T, heads * D)
+    return jnp.transpose(x, (0, 2, 1, 3))
+
+
+def _kernel_struct(x):
+    """The kernels' form of a [B, T, heads, D] result, to be written."""
+    return jax.eval_shape(_to_kernel, x)
+
+
+def _from_kernel(y, like):
+    """A kernel's result as ``like``, [B, T, heads, D]."""
+    return y.reshape(like.shape) if y.ndim == 3 else jnp.transpose(y, (0, 2, 1, 3))
+
+
+def _head_views(y, heads):
+    """The heads of an array in the kernels' form, [B, T, D] each, cut
+    where they lie: XLA keeps a lane tile whole (a reshape that splits the
+    lanes of a [B, T, heads*D] array into two axes is a relayout copy of
+    all of it; a 128-lane slice is a view a fusion reads in place)."""
+    if y.ndim == 4:
+        return [y[:, h] for h in range(heads)]
+    D = y.shape[2] // heads
+    return [y[:, :, h * D:(h + 1) * D] for h in range(heads)]
+
+
 def _head_blocks(arrays, rows, tiled, H=0):
-    """A BlockSpec a [B, heads, T, D] array for a grid (batch, query head,
-    tile): ``rows`` rows of a head, the grid's tile of them where
-    ``tiled``, else all (rows = T). The head is the grid's query head, or
-    with ``H`` (the query heads: a key or value operand, whose heads may be
-    fewer) the one that query head h reads, ``h // (H / heads)``."""
+    """A BlockSpec a [B, T, heads, D] array (in its `_to_kernel` form) for
+    a grid (batch, query head, tile): ``rows`` rows of a head, the grid's
+    tile of them where ``tiled``, else all (rows = T); the kernel sees
+    [rows, D]. The head is the grid's query head, or with ``H`` (the query
+    heads: a key or value operand, whose heads may be fewer) the one that
+    query head h reads, ``h // (H / heads)``. By column the head picks the
+    block of lanes, ``rows`` runs of D lanes at the pitch of a position."""
     def spec(x):
-        group = H // x.shape[1] if H else 0
+        heads, D = x.shape[2:]
+        group = H // heads if H else 0
         head = (lambda h: h // group) if H else (lambda h: h)
         tile = (lambda i: i) if tiled else (lambda i: 0)
-        return pl.BlockSpec((1, 1, rows, x.shape[3]), lambda b, h, i: (b, head(h), tile(i), 0))
+        if by_column(heads, D):
+            return pl.BlockSpec((None, rows, D), lambda b, h, i: (b, tile(i), head(h)))
+        return pl.BlockSpec((None, None, rows, D), lambda b, h, i: (b, head(h), tile(i), 0))
     return [spec(x) for x in arrays]
 
 
+def _out_like(q, v):
+    """The result's [B, T, H, Dv]."""
+    return jax.ShapeDtypeStruct(q[0].shape[:3] + v.shape[3:], q[0].dtype)
+
+
 def _run_fwd(q, k, v, lengths, rule, bq, bk, scale, interpret):
-    """q, k: tuples of the score parts (module docstring)."""
-    B, H, T, _ = q[0].shape
+    """q, k: tuples of the score parts (module docstring), every operand
+    [B, T, heads, D]. The result is left in the kernels' form
+    (`_from_kernel` names its heads): what is kept of it for the backward
+    is kept as the kernel wrote it."""
+    B, T, H, _ = q[0].shape
     tables, walk = _walk(rule, T, bq, bk, False)
     qa, _ = _attr_arrays(rule, T, bq)
     _, ka = _attr_arrays(rule, T, bk)
     n_attr = qa.shape[0]
-    out_shape = jax.ShapeDtypeStruct((B, H, T, v.shape[3]), q[0].dtype)
+    out_like = _out_like(q, v)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, rule=rule, n_attr=n_attr, parts=len(q), block_k=bk,
                           scale=scale, **walk),
@@ -405,34 +504,65 @@ def _run_fwd(q, k, v, lengths, rule, bq, bk, scale, interpret):
             *_head_blocks(q, bq, True), *_head_blocks(k + (v,), T, False, H),
         ],
         out_specs=[
-            *_head_blocks([out_shape], bq, True),
+            *_head_blocks([out_like], bq, True),
             pl.BlockSpec((1, 1, 1, 1, bq), lambda b, h, i: (b, h, i, 0, 0)),
         ],
         out_shape=[
-            out_shape,
+            _kernel_struct(out_like),
             jax.ShapeDtypeStruct((B, H, T // bq, 1, bq), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_params(),
-    )(lengths, *tables, qa, ka, *q, *k, v)
+    )(lengths, *tables, qa, ka, *map(_to_kernel, q + k + (v,)))
     return out, lse
 
 
+def _head_tiles(x, H, rows):
+    """(the grid, a BlockSpec of ``x`` in the kernels' form, one of a [B, H,
+    T] array of a number a head a position) for `attention_delta` and
+    `attention_gate`: tiles of ``rows`` positions by 8 heads (all, where 8
+    does not divide them)."""
+    n = 8 if H % 8 == 0 else H
+    B, T = x.shape[0], x.shape[-2]
+    if x.ndim == 3:
+        block = pl.BlockSpec((None, rows, n * (x.shape[2] // H)), lambda b, g, i: (b, i, g))
+    else:
+        block = pl.BlockSpec((None, n, rows, x.shape[3]), lambda b, g, i: (b, g, i, 0))
+    return (B, H // n, T // rows), block, pl.BlockSpec((None, n, rows), lambda b, g, i: (b, g, i))
+
+
+def _run_delta(do, out, H, rows, interpret):
+    """[B, H, T] float32: sum over a head's lanes of do * out, both in the
+    kernels' form and read where they lie. A kernel, `attention_delta`,
+    because XLA has no cheap form of it on [B, T, H*Dv]: summing each
+    head's lanes apart needs the lanes split into two axes, which is a
+    relayout copy of both arrays, and a slice a head makes XLA lay the
+    product that computes ``do`` out position-minor for the reductions'
+    sake and copy it back for `attention_bwd`."""
+    grid, block, per_head = _head_tiles(do, H, rows)
+    return pl.pallas_call(
+        _delta_kernel, name="attention_delta", grid=grid,
+        in_specs=[block, block], out_specs=per_head,
+        out_shape=jax.ShapeDtypeStruct((do.shape[0], H, do.shape[-2]), jnp.float32),
+        interpret=interpret, compiler_params=_params("parallel"),
+    )(do, out)
+
+
 def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
-    B, H, T, _ = q[0].shape
+    B, T, H, _ = q[0].shape
     parts = len(q)
     nq = T // bq
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = _run_delta(_to_kernel(do), out, H, bq, interpret)
     _, qa_row = _attr_arrays(rule, T, bq)
     ka_col, _ = _attr_arrays(rule, T, bk)
     n_attr = qa_row.shape[0]
     tables, walk = _walk(rule, T, bq, bk, True)
     stat_full = pl.BlockSpec((1, 1, nq, bq), lambda b, h, i: (b, h, 0, 0))
     # dQ a query head whole, its block the same for all the head's key tiles;
-    # dK and dV a QUERY head too: [B, H, T, D_i] for part i's keys, [B, H, T, Dv]
-    dq_shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q]
-    d_shapes = [jax.ShapeDtypeStruct((B, H, T, x.shape[3]), x.dtype) for x in k + (v,)]
-    *grads, dv = pl.pallas_call(
+    # dK and dV a QUERY head too: [B, T, H, D_i] for part i's keys, [B, T, H, Dv]
+    dq_like = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q]
+    d_like = [jax.ShapeDtypeStruct((B, T, H, x.shape[3]), x.dtype) for x in k + (v,)]
+    grads = pl.pallas_call(
         functools.partial(_bwd_kernel, rule=rule, n_attr=n_attr, parts=parts, block_q=bq,
                           scale=scale, **walk),
         name="attention_bwd",
@@ -444,44 +574,52 @@ def _run_bwd(q, k, v, do, out, lse, lengths, rule, bq, bk, scale, interpret):
             *_head_blocks(q, T, False), *_head_blocks(k + (v,), bk, True, H),
             *_head_blocks([do], T, False), stat_full, stat_full,
         ],
-        out_specs=[*_head_blocks(dq_shapes, T, False), *_head_blocks(d_shapes, bk, True)],
-        out_shape=[*dq_shapes, *d_shapes],
+        out_specs=[*_head_blocks(dq_like, T, False), *_head_blocks(d_like, bk, True)],
+        out_shape=list(map(_kernel_struct, dq_like + d_like)),
         scratch_shapes=[pltpu.VMEM((T, x.shape[3]), jnp.float32) for x in q],
         interpret=interpret,
         compiler_params=_params(),
-    )(lengths, *tables, qa_row, ka_col, *q, *k, v, do,
+    )(lengths, *tables, qa_row, ka_col, *map(_to_kernel, q + k + (v, do)),
       lse.reshape(B, H, nq, bq), delta.reshape(B, H, nq, bq))
-    dq, dk = grads[:parts], grads[parts:]
+    dq, dk, dv = grads[:parts], grads[parts:-1], grads[-1]
 
     def fold(d, x):
-        """The query heads that share a K/V head: summed in float32."""
-        heads = x.shape[1]
+        """A query head's dK or dV onto the K/V head it read, ``x``'s
+        layout: the heads that share one are summed in float32."""
+        heads = x.shape[2]
         if heads == H:
-            return d
-        return jnp.sum(d.reshape(B, heads, H // heads, T, d.shape[3]).astype(jnp.float32),
-                       axis=2).astype(d.dtype)
+            return _from_kernel(d, x)
+        per_head = _head_views(d, H)
+        group = H // heads
+        folded = [functools.reduce(operator.add, (g.astype(jnp.float32)
+                                                  for g in per_head[j * group:(j + 1) * group]))
+                  for j in range(heads)]
+        return jnp.concatenate(folded, axis=-1).astype(d.dtype).reshape(x.shape)
 
-    return tuple(dq), tuple(map(fold, dk, k)), fold(dv, v)
+    return tuple(map(_from_kernel, dq, q)), tuple(map(fold, dk, k)), fold(dv, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash(q, k, v, lengths, rule, blocks, interpret):  # q, k: tuples of parts; blocks: (bq, bk, scale)
-    out, _ = _run_fwd(q, k, v, lengths, rule, *blocks, interpret)
-    return out
+    return _flash_fwd(q, k, v, lengths, rule, blocks, interpret)[0]
+
+
 
 
 # What the backward needs and only the forward KERNEL can remake: named, so
 # that a recomputation block (`graph/network.py::_forward_block`) keeps them
 # and does not run `attention_fwd` a second time. `out` is as large as q,
 # `lse` a 32nd of it in float32 at D = 128. Outside a `jax.checkpoint` with a
-# policy a name is the identity.
+# policy a name is the identity. `out` is kept in the kernels' form: a kept
+# [B, T, H, Dv] array would be laid out with (H, Dv) as its tiled axes, a
+# relayout copy of the kernel's [B, T, H*Dv] on the way in and on the way out.
 KEPT_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
 
 
 def _flash_fwd(q, k, v, lengths, rule, blocks, interpret):
     out, lse = _run_fwd(q, k, v, lengths, rule, *blocks, interpret)
     out, lse = map(checkpoint_name, (out, lse), KEPT_RESIDUALS)
-    return out, (q, k, v, out, lse, lengths)
+    return _from_kernel(out, _out_like(q, v)), (q, k, v, out, lse, lengths)
 
 
 def _flash_bwd(rule, blocks, interpret, res, g):
@@ -509,7 +647,9 @@ def supported(T: int, D, itemsize: int = 2, value_dim: int = 0) -> bool:
     their two cotangents where a single's holds one of each. ``D``: the
     scores' width, or the widths of their parts; ``value_dim``: the values'
     (the scores' by default). A part narrower than a lane tile sits in VMEM
-    as a whole one."""
+    as a whole one. How a part's heads are addressed in HBM, in place or
+    from a head-major copy, is `by_column`'s answer and changes nothing
+    here: the tiles in VMEM are the same."""
     widths = as_parts(D)
     value_dim = value_dim or sum(widths)
     block = default_block(T)
@@ -531,36 +671,6 @@ def walk_census(rule: MaskRule, T: int) -> str:
                      for name, transpose in (("fwd", False), ("bwd", True)))
 
 
-def tpu_flash_attention(
-    q: Array, k: Array, v: Array,
-    lengths: Optional[Array] = None,
-    causal: bool = False,
-) -> Array:
-    """Flash attention on a real TPU via jax's production Mosaic kernel
-    (jax.experimental.pallas.ops.tpu.flash_attention), with padding masked
-    through segment ids (valid positions = segment 1, padding = 0 → no
-    cross-attention between them). Layout [B, T, H, D] like
-    sequence_parallel. The hand-rolled kernels above remain the
-    interpret-mode-tested specification of the same math; the library
-    kernel carries the battle-tested Mosaic scheduling on hardware.
-    """
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-    B, T, H, D = q.shape
-    qt, kt, vt = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
-    segment_ids = None
-    if lengths is not None:
-        valid = (jnp.arange(T)[None, :] < lengths[:, None]).astype(jnp.int32)
-        segment_ids = fa.SegmentIds(q=valid, kv=valid)
-    out = fa.flash_attention(
-        qt, kt, vt,
-        causal=causal,
-        segment_ids=segment_ids,
-        sm_scale=1.0 / math.sqrt(D),
-    )
-    return jnp.transpose(out, (0, 2, 1, 3))
-
-
 def flash_attention(
     q: Array, k: Array, v: Array,
     lengths: Optional[Array] = None,
@@ -571,8 +681,9 @@ def flash_attention(
     scale: Optional[float] = None,
 ) -> Array:
     """Flash attention over [B, T, H, D] queries and [B, T, Hkv, D] keys
-    and values (the sequence_parallel layout), masked by ``rule``
-    (``causal`` is the old flag for the causal rule). ``q`` and ``k`` may
+    and values (the sequence_parallel layout, read and written where it
+    lies: `by_column`), masked by ``rule`` (``causal`` is the old flag for
+    the causal rule). ``q`` and ``k`` may
     be tuples of score parts, part i [B, T, H, D_i] against [B, T, Hk_i,
     D_i] (module docstring); the values' width is their own. ``block``: the
     tile edge, `default_block(T)` unless a test wants small tiles.
@@ -591,9 +702,49 @@ def flash_attention(
         assert H % x.shape[2] == 0, f"{H} query heads over {x.shape[2]} K/V heads"
     if lengths is None:
         lengths = jnp.full((B,), T, jnp.int32)
-    by_head = lambda x: jnp.transpose(x, (0, 2, 1, 3))
-    out = _flash(tuple(map(by_head, q)), tuple(map(by_head, k)), by_head(v),
-                 jnp.asarray(lengths, jnp.int32), rule,
-                 (block, block, 1.0 / math.sqrt(D) if scale is None else float(scale)),
-                 interpret)
-    return jnp.transpose(out, (0, 2, 1, 3))
+    return _flash(q, k, v, jnp.asarray(lengths, jnp.int32), rule,
+                  (block, block, 1.0 / math.sqrt(D) if scale is None else float(scale)),
+                  interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gate(x, g, rows, interpret):
+    """x [B, T, H*D] times g [B, T, H], a head's lanes by its number."""
+    grid, block, per_head = _head_tiles(x, g.shape[2], rows)
+    return pl.pallas_call(
+        _gate_kernel, name="attention_gate", grid=grid,
+        in_specs=[block, per_head], out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=interpret, compiler_params=_params("parallel"),
+    )(x, g.transpose(0, 2, 1))
+
+
+def _gate_fwd(x, g, rows, interpret):
+    return _gate(x, g, rows, interpret), (x, g)
+
+
+def _gate_bwd(rows, interpret, kept, dy):
+    x, g = kept
+    d_g = _run_delta(dy, x, g.shape[2], rows, interpret).transpose(0, 2, 1)
+    return _gate(dy, g, rows, interpret), d_g.astype(g.dtype)
+
+
+_gate.defvjp(_gate_fwd, _gate_bwd)
+
+
+def gate_heads(x: Array, g: Array) -> Array:
+    """The heads' results ``x`` [B, T, H, D], each multiplied by its own
+    number a position, ``g`` [B, T, H] in float32, and rounded once to x's
+    dtype. On [B, T, H*D], as the flash kernels leave x, XLA writes the
+    numbers out over the lanes first (a float32 array of x's size) and, for
+    d g, splits the lanes by a relayout copy: where a kernel can run
+    (`device.pallas_mode`) and a head is whole lane tiles, two small
+    kernels do it in one pass each way over the arrays where they lie,
+    `attention_gate` and, for d g, `attention_delta`."""
+    from paddle_tpu.utils import device
+
+    B, T, H, D = x.shape
+    mode, rows = device.pallas_mode(), default_block(T)
+    if mode is None or not rows or D % _LANES:
+        return (x.astype(jnp.float32) * g[..., None]).astype(x.dtype)
+    return _gate(x.reshape(B, T, H * D), g, rows, mode == "interpret").reshape(x.shape)

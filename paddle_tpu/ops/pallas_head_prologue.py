@@ -33,10 +33,11 @@ over row tiles, else the same functions over the whole array under XLA.
 
 Layout: x and dx are the projection as the product leaves it, ``[B, T,
 H*Dh]`` (a head is a 128-lane column block, a position a sublane row, so
-the tables apply to a head's slab as they are); y and dy are ``[B, H, T,
-Dh]``, as the flash kernels of `ops/pallas_attention.py` read q and k and
-write their cotangents, so no transpose of either is left in the program
-(XLA made the relayout of a ``[B, T, H*Dh]`` result two copies a pass).
+the tables apply to a head's slab as they are); y and dy are the same
+array with its heads named, ``[B, T, H, Dh]``, a free reshape: the flash
+kernels of `ops/pallas_attention.py` read a head of q and k, and write its
+cotangent, as that column block where it lies, so nothing is transposed
+between the product and the scores.
 Correctness is tested on the CPU, both ways
 (tests/test_block_diffusion_moe.py); what Mosaic accepts, by compiling for
 a described v5e (tests/test_chip_compile.py).
@@ -211,8 +212,9 @@ def _unpack(refs, has_gain, shifts):
 def _fwd_kernel(*refs, head_dim, eps, scale, has_gain, shifts):
     gain, c, s, roll, (x_ref, y_ref) = _unpack(refs, has_gain, shifts)
     for h in range(x_ref.shape[2] // head_dim):
-        xf = x_ref[0, :, h * head_dim:(h + 1) * head_dim].astype(F32)
-        y_ref[0, h] = _forward_rows(xf, gain, c, s, eps, scale, roll).astype(y_ref.dtype)
+        lanes = slice(h * head_dim, (h + 1) * head_dim)
+        xf = x_ref[0, :, lanes].astype(F32)
+        y_ref[0, :, lanes] = _forward_rows(xf, gain, c, s, eps, scale, roll).astype(y_ref.dtype)
 
 
 def _bwd_kernel(*refs, head_dim, eps, scale, has_gain, shifts):
@@ -220,7 +222,7 @@ def _bwd_kernel(*refs, head_dim, eps, scale, has_gain, shifts):
     acc = 0.0
     for h in range(x_ref.shape[2] // head_dim):
         lanes = slice(h * head_dim, (h + 1) * head_dim)
-        dx, du = _backward_rows(x_ref[0, :, lanes].astype(F32), dy_ref[0, h].astype(F32),
+        dx, du = _backward_rows(x_ref[0, :, lanes].astype(F32), dy_ref[0, :, lanes].astype(F32),
                                 gain, c, s, eps, scale, roll)
         dx_ref[0, :, lanes] = dx.astype(dx_ref.dtype)
         if has_gain:
@@ -230,19 +232,16 @@ def _bwd_kernel(*refs, head_dim, eps, scale, has_gain, shifts):
 
 
 def _call(kernel, name, x, dy, gain, tables, head_dim, eps, scale, rot, interpret):
-    """One pass in tiles of some rows by a few heads: the forward kernel
-    (``dy`` None) reads the projection ``x`` [B, T, width] and writes y
-    [B, H, T, Dh]; the backward kernel reads x and ``dy`` and writes dx
-    and, under a norm, each tile's ``d gain`` (one ``[1, Dh]`` a tile). The
-    grid walks the row tiles outermost, so a tile's tables stay in VMEM for
-    all its head groups and sequences."""
+    """One pass in tiles of some rows by a few heads, every array ``[B, T,
+    width]``: the forward kernel (``dy`` None) reads the projection ``x``
+    and writes y; the backward kernel reads x and ``dy`` and writes dx and,
+    under a norm, each tile's ``d gain`` (one ``[1, Dh]`` a tile). The grid
+    walks the row tiles outermost, so a tile's tables stay in VMEM for all
+    its head groups and sequences."""
     B, T, width = x.shape
     rows, lanes = _tiling(T, width, head_dim, x.dtype.itemsize)
     grid = (T // rows, width // lanes, B)
-    flat = (jax.ShapeDtypeStruct(x.shape, x.dtype),
-            pl.BlockSpec((1, rows, lanes), lambda j, g, b: (b, j, g)))
-    by_head = (jax.ShapeDtypeStruct((B, width // head_dim, T, head_dim), x.dtype),
-               pl.BlockSpec((1, lanes // head_dim, rows, head_dim), lambda j, g, b: (b, g, j, 0)))
+    tile = pl.BlockSpec((1, rows, lanes), lambda j, g, b: (b, j, g))
     operands, specs = [], []
     if gain is not None:
         operands.append(gain.astype(F32).reshape(1, head_dim))
@@ -250,24 +249,22 @@ def _call(kernel, name, x, dy, gain, tables, head_dim, eps, scale, rot, interpre
     if tables is not None:
         operands += list(tables)
         specs += [pl.BlockSpec((rows, head_dim), lambda j, g, b: (j, 0))] * len(tables)
-    if dy is None:
-        tiled, outs = [(x, flat[1])], [by_head]
-    else:
-        tiled, outs = [(x, flat[1]), (dy, by_head[1])], [flat]
-        if gain is not None:
-            outs.append((jax.ShapeDtypeStruct(grid + (1, head_dim), F32),
-                         pl.BlockSpec((1, 1, 1, 1, head_dim), lambda j, g, b: (j, g, b, 0, 0))))
+    tiled = [x] if dy is None else [x, dy]
+    outs = [(jax.ShapeDtypeStruct(x.shape, x.dtype), tile)]
+    if dy is not None and gain is not None:
+        outs.append((jax.ShapeDtypeStruct(grid + (1, head_dim), F32),
+                     pl.BlockSpec((1, 1, 1, 1, head_dim), lambda j, g, b: (j, g, b, 0, 0))))
     return pl.pallas_call(
         functools.partial(kernel, head_dim=head_dim, eps=eps, scale=scale,
                           has_gain=gain is not None, shifts=_shifts(head_dim, rot, tables)),
         name=name, grid=grid,
-        in_specs=specs + [spec for _, spec in tiled],
+        in_specs=specs + [tile] * len(tiled),
         out_specs=[spec for _, spec in outs], out_shape=[shape for shape, _ in outs],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT),
-    )(*operands, *(a for a, _ in tiled))
+    )(*operands, *tiled)
 
 
 # ------------------------------------------------------------ the function
@@ -284,24 +281,50 @@ def _whole(x, gain, tables, head_dim, rot):
             None if gain is None else gain.astype(F32), c, s, rolls)
 
 
+def _packed_tables(tables, n: int):
+    """A whole-head turn's ``(C, S)`` of heads that lie ``n`` to a lane tile,
+    as the three tables of a partial turn of the tile: the two rolls of the
+    module docstring bring every lane its partner inside its own head, and
+    the sines are zero where a roll crosses into a neighbour."""
+    if tables is None:
+        return None
+    c, s = tables
+    half = s.shape[1] // 2
+    zero = jnp.zeros_like(s[:, :half])
+    up, down = jnp.concatenate([s[:, :half], zero], -1), jnp.concatenate([zero, s[:, half:]], -1)
+    return tuple(jnp.tile(t, (1, n)) for t in (c, up, down))
+
+
 def head_prologue(x: Array, gain: Optional[Array], tables, head_dim: int,
                   eps: float, scale: float, rot: int = 0) -> Array:
     """A projection ``x`` [B, T, H*Dh], as the product leaves it, with its
-    heads made ready for the scores (module docstring): ``[B, H, T, Dh]``,
-    as the flash kernels read them, in x's dtype. ``gain`` [Dh] or None (no
+    heads made ready for the scores (module docstring): ``[B, T, H, Dh]``,
+    the same layout with the heads named, in x's dtype. ``gain`` [Dh] or None (no
     norm); ``tables`` from :func:`turn_tables` or None (no turn), ``rot``
     the lanes they turn where not the whole head. The kernels run where
     `device.pallas_mode` has a way to run them and
-    :func:`supported` admits the shape."""
+    :func:`supported` admits the shape. Heads narrower than a lane tile that
+    are turned whole and have no norm (latent attention's 32 rotary heads of
+    64) go through the kernels several to a tile (`_packed_tables`); XLA
+    pads each of their float32 passes to whole lane tiles, twice the bytes
+    at 64 lanes and four times for a half head."""
     from paddle_tpu.utils import device
 
     mode = device.pallas_mode()
-    if not supported(x.shape[1], x.shape[2], head_dim, x.dtype.itemsize):
-        mode = None
+    B, T, width = x.shape
+    packed = (gain is None and head_dim < _LANES and _LANES % head_dim == 0
+              and rot in (0, head_dim))
+    if mode and packed and supported(T, width, _LANES, x.dtype.itemsize):
+        tables, rot, kernel_dim = _packed_tables(tables, _LANES // head_dim), head_dim, _LANES
+    else:
+        kernel_dim = head_dim
+        if not supported(T, width, head_dim, x.dtype.itemsize):
+            mode = None
     device.log_selection(
-        "head_prologue", f"T={x.shape[1]} heads={x.shape[2] // head_dim} Dh={head_dim}",
+        "head_prologue", f"T={T} heads={width // head_dim} Dh={head_dim}",
         f"Pallas kernel, {mode}" if mode else "XLA path")
-    return _prologue(x, gain, tables, head_dim, eps, scale, mode, rot)
+    y = _prologue(x, gain, tables, kernel_dim, eps, scale, mode, rot)
+    return y.reshape(B, T, width // head_dim, head_dim)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -309,11 +332,12 @@ def _prologue(x, gain, tables, head_dim, eps, scale, mode, rot=0):
     """``mode`` "compiled" / "interpret": the kernels; None: the same
     mathematics over the whole array under XLA."""
     if mode is not None:
-        return _call(_fwd_kernel, "head_prologue_fwd", x, None, gain, tables,
-                     head_dim, eps, scale, rot, mode == "interpret")[0]
+        y = _call(_fwd_kernel, "head_prologue_fwd", x, None, gain, tables,
+                  head_dim, eps, scale, rot, mode == "interpret")[0]
+        return y.reshape(*x.shape[:2], -1, head_dim)
     shape, g, c, s, roll = _whole(x, gain, tables, head_dim, rot)
     y = _forward_rows(x.astype(F32).reshape(shape), g, c, s, eps, scale, roll)
-    return y.astype(x.dtype).transpose(0, 2, 1, 3)
+    return y.astype(x.dtype)
 
 
 def _vjp_fwd(x, gain, tables, head_dim, eps, scale, mode, rot):
@@ -323,13 +347,12 @@ def _vjp_fwd(x, gain, tables, head_dim, eps, scale, mode, rot):
 def _vjp_bwd(head_dim, eps, scale, mode, rot, kept, dy):
     x, gain, tables = kept
     if mode is not None:
-        dx, *du = _call(_bwd_kernel, "head_prologue_bwd", x, dy, gain, tables,
+        dx, *du = _call(_bwd_kernel, "head_prologue_bwd", x, dy.reshape(x.shape), gain, tables,
                         head_dim, eps, scale, rot, mode == "interpret")
         du = du[0] if du else None
     else:
         shape, g, c, s, roll = _whole(x, gain, tables, head_dim, rot)
-        dx, du = _backward_rows(x.astype(F32).reshape(shape),
-                                dy.astype(F32).transpose(0, 2, 1, 3),
+        dx, du = _backward_rows(x.astype(F32).reshape(shape), dy.astype(F32),
                                 g, c, s, eps, scale, roll)
         dx = dx.reshape(x.shape).astype(x.dtype)
     d_gain = None if gain is None else (

@@ -74,10 +74,10 @@ def full_attention(
     else:
         from paddle_tpu.ops import pallas_attention
 
-        if pallas_attention.supported(q.shape[1], q.shape[3]):
+        if pallas_attention.supported(q.shape[1], q.shape[3], q.dtype.itemsize):
             device.log_selection("flash_attention", site,
                                  "Pallas kernel, compiled")
-            return pallas_attention.tpu_flash_attention(
+            return pallas_attention.flash_attention(
                 q, k, v, lengths=lengths, causal=causal
             )
         why = "kernel gate refuses the shape"

@@ -82,13 +82,19 @@ def _dense(q, k, v, rule):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
+@pytest.mark.parametrize("heads", [(4, 2, 16), (32, 4, 128)],
+                         ids=["head-major-4-over-2x16", "column-block-32-over-4x128"])
 @pytest.mark.parametrize("kind", ["full", "causal", "block_diffusion"])
-def test_kernel_matches_the_dense_rule(kind):
+def test_kernel_matches_the_dense_rule(kind, heads):
     """Interpret mode, small tiles (so that tiles are skipped), grouped
-    heads: forward and the three gradients."""
+    heads: forward and the three gradients; with heads of 16 lanes, which
+    the kernels read from a head-major copy, and with the cell's 32 heads of
+    128 over 4, which they read and write as column blocks of [B, T, H*D]
+    (dK and dV folded over each group of 8 lane blocks)."""
     rule = MaskRule(kind, 4 if kind == "block_diffusion" else 0)
-    q, k, v = _qkv(64, 4, 2, 16, 3)
-    w = jnp.asarray(np.random.RandomState(4).randn(2, 64, 4, 16).astype(np.float32))
+    H, Hkv, D = heads
+    q, k, v = _qkv(64, H, Hkv, D, 3)
+    w = jnp.asarray(np.random.RandomState(4).randn(2, 64, H, D).astype(np.float32))
     flash = lambda q, k, v: flash_attention(q, k, v, rule=rule, interpret=True, block=16)
     np.testing.assert_allclose(flash(q, k, v), _dense(q, k, v, rule), atol=2e-5)
     g_k = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
@@ -206,8 +212,8 @@ def test_head_prologue_matches_the_written_out_rule(norm, turn, dtype, path, mon
     def new(x, gain):
         tables = turn_tables(positions, theta, Dh) if theta else None
         y = head_prologue(x.reshape(B, T, H * Dh), gain, tables, Dh, 1e-6, scale)
-        assert y.shape == (B, H, T, Dh) and y.dtype == x.dtype
-        return y.transpose(0, 2, 1, 3)
+        assert y.shape == (B, T, H, Dh) and y.dtype == x.dtype
+        return y
 
     old = lambda x, gain: _written_out_prologue(x, gain, positions, theta, scale)
     loss = lambda f: (lambda x, gain: jnp.sum(f(x, gain).astype(jnp.float32) * w))
@@ -468,8 +474,9 @@ def test_block_recomputation_changes_no_number(rule, seq_len, monkeypatch):
     # a block's extras cross its edge
     assert {"l1_moe@chosen", "l1_moe@counter.sum:moe.pairs_held"} <= set(blocks[2])
     # the kernels of the gradient's program: a layer's forward once, with
-    # or without blocks (2 layers)
-    a_layer = ["attention_bwd", "attention_fwd"] if seq_len == 64 else []
+    # or without blocks (2 layers); `attention_delta` reads the kept `out`
+    # and its cotangent for the backward
+    a_layer = ["attention_bwd", "attention_delta", "attention_fwd"] if seq_len == 64 else []
     for remat in ("none", "block"):
         jaxpr = jax.make_jaxpr(gm.grad_fn(remat))(params, batch, None).jaxpr
         assert sorted(_pallas_calls(jaxpr)) == sorted(2 * a_layer), remat

@@ -97,17 +97,20 @@ def _dense(q, k, v, window):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
+@pytest.mark.parametrize("D,kv_heads", [(16, 2), (128, 8)],
+                         ids=["head-major-16", "column-block-128-over-8"])
 @pytest.mark.parametrize("group", [6, 8])
-def test_window_rule_and_its_kernel_match_the_dense_rule(group):
+def test_window_rule_and_its_kernel_match_the_dense_rule(group, D, kv_heads):
     """The rule against the written-out mask, and the kernel (interpreted,
     small tiles so that tiles are skipped on both sides of the band)
     against the rule, for 6 and for 8 query heads a key/value head: forward
-    and the three gradients."""
+    and the three gradients; heads of 16 lanes (a head-major copy) and the
+    cell's 48 and 64 heads of 128 over 8 (column blocks of [B, T, H*D])."""
     rule = MaskRule("sliding_window", window=24)
     idx = np.arange(64)
     want = (idx[None, :] <= idx[:, None]) & (idx[:, None] - idx[None, :] < 24)
     np.testing.assert_array_equal(rule.allowed(idx, idx, 64), want)
-    q, k, v = _qkv(64, 2 * group, 2, 16, group)
+    q, k, v = _qkv(64, kv_heads * group, kv_heads, D, group)
     w = jnp.asarray(np.random.RandomState(4).randn(*q.shape).astype(np.float32))
     flash = lambda q, k, v: flash_attention(q, k, v, rule=rule, interpret=True, block=16)
     np.testing.assert_allclose(flash(q, k, v), _dense(q, k, v, 24), atol=2e-5)
@@ -321,8 +324,8 @@ def test_head_prologue_with_a_partial_turn(norm, dtype, path, monkeypatch):
         tables = turn_tables(positions, 5e5, Dh, rot, yarn, factor)
         assert len(tables) == 3
         y = head_prologue(x.reshape(B, Tn, H * Dh), gain, tables, Dh, 1e-6, scale, rot)
-        assert y.shape == (B, H, Tn, Dh) and y.dtype == x.dtype
-        return y.transpose(0, 2, 1, 3)
+        assert y.shape == (B, Tn, H, Dh) and y.dtype == x.dtype
+        return y
 
     freqs = rotary_frequencies(5e5, rot, yarn)
     old = lambda x, gain: _written_out_turn(x, gain, positions, freqs, factor, rot, scale)
@@ -675,3 +678,34 @@ def test_three_steps_match_the_reference_and_the_fp8_control_does_not(dtype, tmp
     control = cmp.checks(cmp.reference_steps(ref, cell.config, 2147483659, batches, mode="fp8"),
                          base, cell.workload["limits"])
     assert not all(c.ok for c in control), control
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [6, 16], ids=["6-heads-one-tile", "16-heads-8-a-tile"])
+def test_the_gates_kernels_match_its_written_out_form(heads, dtype, monkeypatch):
+    """`gate_heads` through its kernels (interpreted: `attention_gate` each
+    way and `attention_delta` for d g, on [B, T, H*128] where it lies)
+    against the product written out under `jax.grad`: the value, dx and
+    d g, bit for bit (one float32 product a number, rounded once; d g a
+    float32 sum over a head's 128 lanes). Heads narrower than a lane tile
+    take the written-out form."""
+    from paddle_tpu.ops.pallas_attention import gate_heads
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    rng = np.random.RandomState(heads)
+    x = jnp.asarray(rng.randn(2, 256, heads, 128), dtype)
+    g = jnp.asarray(rng.rand(2, 256, heads), jnp.float32)
+    w = jnp.asarray(rng.randn(2, 256, heads, 128), jnp.float32)
+    written_out = lambda x, g: (x.astype(jnp.float32) * g[..., None]).astype(x.dtype)
+    kernels = gate_heads
+    both = lambda f: (f(x, g), *jax.grad(lambda x, g: jnp.sum(f(x, g).astype(jnp.float32) * w),
+                                         argnums=(0, 1))(x, g))
+    assert "attention_gate" in str(jax.make_jaxpr(kernels)(x, g))
+    for name, a, b in zip(("y", "dx", "d g"), both(kernels), both(written_out)):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        if name == "d g":
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * float(jnp.abs(b).max()), err_msg=name)
+        else:
+            np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name)
+    narrow = x[..., :16]
+    assert "pallas_call" not in str(jax.make_jaxpr(kernels)(narrow, g))

@@ -91,6 +91,13 @@ KERNEL_CASES = {
                                 dict(widths=(128,), key_heads=(2,), Hv=2)),
     "block_diffusion-one-part": (MaskRule("block_diffusion", 4),
                                  dict(widths=(128,), key_heads=(2,), Hv=2)),
+    # the cells' grouped heads at their counts, every operand a column block of
+    # [B, T, heads*128]: dK and dV fold 8 (or 6) lane blocks onto one
+    "block_diffusion-32-over-4": (MaskRule("block_diffusion", 4),
+                                  dict(B=1, H=32, widths=(128,), key_heads=(4,), Hv=4)),
+    "sliding_window-64-over-8": (MaskRule("sliding_window", 0, 96),
+                                 dict(B=1, H=64, widths=(128,), key_heads=(8,), Hv=8)),
+    "causal-48-over-8": (MaskRule("causal"), dict(B=1, H=48, widths=(128,), key_heads=(8,), Hv=8)),
 }
 
 
@@ -102,7 +109,7 @@ def test_kernels_with_score_parts_match_the_xla_path(case):
     over its query heads."""
     rule, kw = KERNEL_CASES[case]
     q, k, v, w = _parts(7, **kw)
-    lengths = jnp.array([256, 256 - 37], jnp.int32)
+    lengths = jnp.array([256, 256 - 37][-v.shape[0]:], jnp.int32)
     inside = (jnp.arange(256)[None, :] < lengths[:, None])[:, :, None, None]
     loss = lambda fn: (lambda q, k, v: jnp.sum(jnp.where(inside, fn(q, k, v), 0.0) * w))
     kernel = lambda q, k, v: flash_attention(q, k, v, lengths=lengths, rule=rule,
@@ -114,6 +121,40 @@ def test_kernels_with_score_parts_match_the_xla_path(case):
     for a, b in zip(jax.tree_util.tree_leaves(got[1]), jax.tree_util.tree_leaves(want[1])):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def _transposes(jaxpr):
+    """The `transpose` equations of whole operands ([B, ., ., D]: a kernel's
+    own turn of a tile's statistics is rank 2) in a jaxpr and in every
+    jaxpr inside it, by the shape each writes."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "transpose" and len(eqn.outvars[0].aval.shape) == 4:
+            found.append(eqn.outvars[0].aval.shape)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _transposes(sub)
+    return found
+
+
+def test_only_the_narrow_many_head_part_is_transposed():
+    """Which layout a part takes is its shape's alone (`by_column`): in the
+    traced forward and backward of the latent cell's form the only
+    transposes are q_rope's (4 heads of 64: Mosaic cannot cut a 64-lane
+    block out of a wider row), in and again for the backward and its dq
+    out; q_nope, k_nope, v, the ONE k_rope head, the result, its cotangent
+    and their gradients are read and written where they lie. A form with no
+    such part traces no transpose at all."""
+    from paddle_tpu.ops.pallas_attention import by_column
+
+    assert by_column(32, 128) and by_column(1, 64) and by_column(4, 256)
+    assert not by_column(32, 64) and not by_column(4, 16)
+    rule = MaskRule("causal")
+    q, k, v, w = _parts(5, B=1)
+    loss = lambda q, k, v: jnp.sum(flash_attention(q, k, v, rule=rule, interpret=True, block=128) * w)
+    both = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    assert sorted(_transposes(both(q, k, v).jaxpr)) == [(1, 4, 256, 64)] * 2 + [(1, 256, 4, 64)]
+    one = lambda q, k, v: loss((q,), (k,), v)
+    assert _transposes(jax.make_jaxpr(jax.value_and_grad(one, argnums=(0, 1, 2)))(q[0], k[0], v).jaxpr) == []
 
 
 def test_one_part_is_the_kernel_as_it_was():
@@ -159,7 +200,8 @@ def test_the_interleaved_turn_against_the_formula():
 
     def turned(x, n):
         cols = np.concatenate([h * d + order for h in range(n)])
-        return head_prologue(jnp.asarray(x[..., cols]), None, tables, d, 1e-6, 1.0)   # [B, n, T, d]
+        y = head_prologue(jnp.asarray(x[..., cols]), None, tables, d, 1e-6, 1.0)
+        return np.asarray(y).transpose(0, 2, 1, 3)                                    # [B, n, T, d]
 
     ang = np.arange(T)[:, None] * theta ** (-2.0 * np.arange(d // 2) / d)[None, :]
 
@@ -178,6 +220,44 @@ def test_the_interleaved_turn_against_the_formula():
     ref = _reference()
     theirs = ref.rotary(jnp.asarray(q[0].reshape(T, heads, d)), jnp.arange(T), theta, True)
     np.testing.assert_allclose(theirs.transpose(1, 0, 2), written_out(q, heads)[0], atol=2e-6)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("turn", [True, False], ids=["turn", "scale"])
+def test_narrow_heads_go_through_the_prologue_kernels_two_to_a_lane_tile(turn, dtype, monkeypatch):
+    """The latent cell's rotary heads (64 lanes, no norm, turned whole):
+    the kernels (interpreted) take them two to a 128-lane tile with the
+    turn's tables tiled (`_packed_tables`), and give what the XLA path
+    gives, value and dx: float32 to a rounding of the arithmetic, bfloat16
+    to one rounding of the result. A norm, or ONE head of 64 (k_rope), keeps
+    the XLA path."""
+    from paddle_tpu.ops import pallas_head_prologue as hp
+
+    B, Tn, H, d = 2, 64, 4, 64
+    rng = np.random.RandomState(8)
+    x = jnp.asarray(rng.randn(B, Tn, H * d), dtype)
+    w = jnp.asarray(rng.randn(B, Tn, H, d), jnp.float32)
+    tables = hp.turn_tables(jnp.arange(Tn), 1e4, d, d) if turn else None
+    run = lambda x: hp.head_prologue(x, None, tables, d, 1e-6, 0.3)
+    both = lambda: (run(x), jax.grad(lambda x: jnp.sum(run(x).astype(jnp.float32) * w))(x))
+    calls = []
+    real = hp._call
+    monkeypatch.setattr(hp, "_call", lambda *a, **kw: calls.append(a[1]) or real(*a, **kw))
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    want = both()
+    assert not calls
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    got = both()
+    assert sorted(set(calls)) == ["head_prologue_bwd", "head_prologue_fwd"]
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        tol = 2.0 ** -7 * np.abs(b) + 1e-30 if dtype == "bfloat16" else 1e-6 * np.abs(b).max()
+        np.testing.assert_array_less(np.abs(a - b), tol + 1e-38)
+    del calls[:]
+    hp.head_prologue(x[..., :d], None, tables, d, 1e-6, 1.0)                    # one head of 64
+    hp.head_prologue(x, jnp.ones((d,)), tables, d, 1e-6, 1.0)                   # a norm a head
+    assert not calls
 
 
 # -------------------------------------------------------------- the layers

@@ -16,10 +16,16 @@ from paddle_tpu.parallel.sequence_parallel import full_attention
 B, T, H, D = 2, 256, 2, 32
 
 
-def _qkv(seed=0):
+def _qkv(seed=0, D=D):
     rng = np.random.RandomState(seed)
     mk = lambda: jnp.asarray(rng.randn(B, T, H, D).astype(np.float32))
     return mk(), mk(), mk()
+
+
+# how the kernels address a head (`pallas_attention.by_column`): a head of 32
+# lanes out of a head-major [B, H, T, D] copy, a head of 128 as a column block
+# of the [B, T, H*D] array the caller holds
+LAYOUTS = pytest.mark.parametrize("D", [32, 128], ids=["head-major-32", "column-block-128"])
 
 
 def test_supported_predicate():
@@ -61,23 +67,25 @@ def test_the_gate_refuses_where_the_heads_dq_no_longer_fits(T, widths, itemsize)
     assert not supported(T, widths, itemsize, 128)
 
 
+@LAYOUTS
 @pytest.mark.parametrize("causal", [False, True])
-def test_forward_matches_xla(causal):
-    q, k, v = _qkv()
+def test_forward_matches_xla(causal, D):
+    q, k, v = _qkv(D=D)
     lengths = jnp.asarray([T, T - 77], jnp.int32)
     ref = full_attention(q, k, v, lengths=lengths, causal=causal)
     out = flash_attention(q, k, v, lengths=lengths, causal=causal, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@LAYOUTS
 @pytest.mark.parametrize("block", [None, 64], ids=["one-tile", "4x4-tiles"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_xla(causal, block):
+def test_gradients_match_xla(causal, block, D):
     """dq, dk and dv of the one backward kernel against the XLA path: a
     head of one tile, and of 4 x 4 tiles of 64, where a head's dq is summed
     over its key tiles in VMEM and the second sequence's end (126) cuts the
     second tile, so that whole key tiles past it add nothing."""
-    q, k, v = _qkv(1)
+    q, k, v = _qkv(1, D)
     lengths = jnp.asarray([T, T - 130], jnp.int32)
 
     def loss_ref(q, k, v):
