@@ -32,6 +32,7 @@ TPU-native formulation:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -124,6 +125,17 @@ def _memory_boot(network, mem, ctx: LayerContext, batch: int, dtype, sub: SubMod
     return boot
 
 
+class _DeferredRead:
+    """One step's part in a static read whose gradient is taken after the
+    scan (see _plan_static_grad): the scaling multiplies the static with its
+    gradient stopped, its weights are kept for the product after the loop,
+    and the pooling's result gets this step's zero tap."""
+
+    def __init__(self, scaling: str, pooling: str, x_sg: Array, tap: Array):
+        self.scaling, self.pooling, self.x_sg, self.tap = scaling, pooling, x_sg, tap
+        self.weights = None
+
+
 def _run_submodel_step(
     network,
     sub: SubModelConfig,
@@ -132,12 +144,14 @@ def _run_submodel_step(
     rng: Optional[Array],
     skip: frozenset = frozenset(),
     mixed_prologue: Optional[Dict[str, Any]] = None,
+    deferred: Tuple[_DeferredRead, ...] = (),
 ) -> Dict[str, Argument]:
     """Run the sub-model's layers once with pre-fed agent outputs.
     ``skip`` names epilogue layers hoisted out of the scan;
     ``mixed_prologue`` maps a mixed layer to (skip_input_indices,
     precomputed [B, out] slice) for projections hoisted BEFORE the scan
-    (see _plan_prologue)."""
+    (see _plan_prologue); ``deferred`` are the static reads whose gradient
+    the caller takes after the scan."""
     step_ctx = LayerContext(
         params=ctx.params,
         model=ctx.model,
@@ -170,7 +184,17 @@ def _run_submodel_step(
             network._lookup_input(step_ctx, ic.input_layer_name, ic.input_layer_argument)
             for ic in lcfg.inputs
         ]
-        forward_layer(lcfg, ins, step_ctx)
+        for d in deferred:
+            if d.scaling == name:
+                w, x = ins
+                d.weights = jnp.broadcast_to(w.value, x.value.shape[:-1] + (1,))[..., 0]
+                ins = [w, x.replace(value=d.x_sg)]
+        out = forward_layer(lcfg, ins, step_ctx)
+        for d in deferred:
+            if d.pooling == name:
+                step_ctx.outputs[name] = out.replace(
+                    value=out.value + d.tap.astype(out.value.dtype)
+                )
     # NOTE: state updates produced inside the scan body (batch_norm moving
     # stats) would be scan tracers — propagating them out would leak.
     # Running statistics are not updated inside recurrent groups
@@ -355,6 +379,153 @@ def _plan_prologue(network, sub: SubModelConfig, epilogue: frozenset):
     return plan
 
 
+def _plan_static_grad(network, sub: SubModelConfig, ctx: LayerContext,
+                      statics: Dict[str, Argument], skip: frozenset,
+                      frontier) -> Tuple[Tuple[str, str, str], ...]:
+    """The static reads whose gradient is taken ONCE, after the scan.
+
+    An attention read (simple_attention's `_scaling` -> `_pooling`) is a
+    scaling of a static sequence x by per-position weights w_t, summed by
+    a pooling: ctx_t[b] = sum_s w_t[b, s] x[b, s]. Left to autodiff, the
+    scan's transpose adds each step's cotangent of the closed-over x into
+    a carry of x's own shape, a read and a write of all of x every
+    reverse step. That cotangent is w_t[b, s] * d ctx_t[b] (rank one a
+    row), so its sum over the steps is one batched product of the stacked
+    weights and the stacked d ctx after the loop (_scan_static_grad).
+
+    Returns ((scaling, pooling, static link), ...): every scaling in a
+    training group's step that reads a dense static sequence and whose
+    result goes to a linear sum pooling alone; empty where there is none.
+    Any other read of the static keeps its own gradient path."""
+    from paddle_tpu.utils import device
+
+    def no(why):
+        device.log_selection("scan_static_grad", sub.name,
+                             f"per-step accumulation ({why})")
+        return ()
+
+    if not ctx.is_training or sub.generator is not None:
+        return no("not a training group")
+    if any(l.has_subseq for l in sub.in_links):
+        return no("nested group")
+    lm = network.layer_map
+    names = [n for n in sub.layer_names if n in lm and n not in skip]
+    readers: Dict[str, List[str]] = {}
+    for n in names:
+        for ic in lm[n].inputs:
+            readers.setdefault(ic.input_layer_name, []).append(n)
+    # a scaling's result read outside the step graph's own layers keeps
+    # the per-step path: a memory, an out-link, the hoisted epilogue
+    outside = ({m.layer_name for m in sub.memories}
+               | {l.layer_name for l in sub.out_links} | set(frontier))
+    reads, why = [], "no scaling of a static sequence"
+    for n in names:
+        sc = lm[n]
+        if sc.type != "scaling" or len(sc.inputs) != 2:
+            continue
+        link = sc.inputs[1].input_layer_name
+        x = statics.get(link)
+        if x is None or x.value is None or not x.is_seq or x.is_nested_seq \
+                or x.value.ndim != 3 or lm[sc.inputs[0].input_layer_name].size != 1:
+            continue
+        pool = lm.get(readers[n][0]) if len(readers.get(n, ())) == 1 else None
+        if (
+            n in outside
+            or sc.drop_rate or sc.error_clipping_threshold
+            or pool is None or pool.type != "average"
+            or (pool.average_strategy or "average") != "sum"
+            or pool.trans_type == "seq" or len(pool.inputs) != 1
+            or pool.active_type not in ("", "linear") or pool.bias_parameter_name
+            or pool.drop_rate or pool.error_clipping_threshold
+        ):
+            why = f"{n} feeds more than a linear sum pooling"
+            continue
+        reads.append((n, pool.name, link))
+    if not reads:
+        return no(why)
+    device.log_selection(
+        "scan_static_grad", sub.name,
+        "after the scan: " + ", ".join(f"{s} -> {p} of {l}" for s, p, l in reads))
+    return tuple(reads)
+
+
+def _scan_static_grad(step, init_carries, xs, statics, reads, reverse, unroll):
+    """The group's scan with the static reads' gradient taken after it
+    (see _plan_static_grad): (ys, frontier values) as jax.lax.scan's.
+
+    The step reads the static under stop_gradient, and each pooling's
+    result gets a zero tap, an input of the scan, so d ctx comes back
+    stacked [T, B, D] as a scan input's cotangent does, never in a carry.
+    A custom VJP round the scan adds sum_t W[t] * d tap[t] to the static's
+    cotangent in one product, in float32, cast once; every other input's
+    cotangent is the scan's own."""
+    T = xs[3].shape[0]  # the time-major step mask
+    links = tuple(dict.fromkeys(l for _, _, l in reads))
+    vals = {l: statics[l].value for l in links}
+
+    def zero_taps():
+        return tuple(
+            jnp.zeros((T, vals[l].shape[0], vals[l].shape[2]), vals[l].dtype)
+            for _, _, l in reads
+        )
+
+    def scan_fn(vals, taps):
+        fed = {**statics, **{l: statics[l].replace(value=vals[l]) for l in links}}
+        # the stop sits outside the loop, so x enters the scan with no tangent
+        sg = {l: jax.lax.stop_gradient(vals[l]) for l in links}
+        body = functools.partial(
+            step, statics=fed,
+            reads=tuple((s, p, sg[l]) for s, p, l in reads),
+        )
+        _, (ys, frs, weights) = jax.lax.scan(
+            body, init_carries, xs[:-1] + (taps,), reverse=reverse, unroll=unroll
+        )
+        return (ys, frs), weights
+
+    # every array the scan closes over (parameters, the other links, the
+    # lengths, the rng key) becomes an explicit input of the custom VJP:
+    # one closed over would be a tracer of this trace, stale wherever the
+    # call is replayed (a rematerialised loss replays it)
+    closed, out_shapes = jax.make_jaxpr(scan_fn, return_shape=True)(vals, zero_taps())
+    consts, out_tree = list(closed.consts), jax.tree.structure(out_shapes)
+
+    def conv(vals, taps, *consts):
+        out = jax.core.eval_jaxpr(closed.jaxpr, consts, *jax.tree.leaves((vals, taps)))
+        return jax.tree.unflatten(out_tree, out)
+
+    # the pooling's mask of each static, [B, S]: an input too, for the same reason
+    masks = {l: statics[l].seq_mask() for l in links}
+
+    @jax.custom_vjp
+    def run(vals, consts, masks):
+        return conv(vals, zero_taps(), *consts)[0]
+
+    def fwd(vals, consts, masks):
+        out, vjp_fn, weights = jax.vjp(
+            lambda v, c, t: conv(v, t, *c), vals, consts, zero_taps(), has_aux=True
+        )
+        return out, (vjp_fn, weights, masks)
+
+    def bwd(res, d_out):
+        vjp_fn, weights, masks = res
+        d_vals, d_consts, d_taps = vjp_fn(d_out)
+        d_vals = dict(d_vals)
+        for l in links:
+            # a scope with a name of its own, so a profile's table by scope
+            # shows the product beside the scan it came out of
+            with jax.named_scope(f"static_grad:{l}"):
+                g = sum(
+                    jnp.einsum("tbs,tbd->bsd", w, d, preferred_element_type=jnp.float32)
+                    for (_, _, rl), w, d in zip(reads, weights, d_taps) if rl == l
+                )
+                g = g * masks[l][..., None]
+                d_vals[l] = (d_vals[l].astype(jnp.float32) + g).astype(d_vals[l].dtype)
+        return d_vals, d_consts, jax.tree.map(jnp.zeros_like, masks)
+
+    run.defvjp(fwd, bwd)
+    return run(vals, consts, masks)
+
+
 def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerContext) -> None:
     assert sub.in_links, f"recurrent group {cfg.name} has no sequence inputs"
     nested = any(link.has_subseq for link in sub.in_links)
@@ -476,8 +647,12 @@ def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerCont
                     init_carries[0], mask_bt,
                 )
 
-    def step(carries, inp):
-        x_v, x_i, x_sl, m_t, t_idx, x_pro = inp
+    # attention reads of a static whose gradient is taken after the scan
+    reads = () if fused_ys is not None else _plan_static_grad(
+        network, sub, ctx, statics, skip, dyn_frontier)
+
+    def step(carries, inp, statics=statics, reads=()):
+        x_v, x_i, x_sl, m_t, t_idx, x_pro, x_taps = inp
         fed: Dict[str, Argument] = {}
         for link in sub.in_links:
             name = link.link_name
@@ -494,8 +669,12 @@ def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerCont
         mixed_pro = {
             lname: (pro_plan[lname], x_pro[lname]) for lname in x_pro
         }
+        deferred = tuple(
+            _DeferredRead(s, p, x_sg, tap) for (s, p, x_sg), tap in zip(reads, x_taps)
+        )
         outs = _run_submodel_step(
-            network, sub, ctx, fed, rng, skip=skip, mixed_prologue=mixed_pro
+            network, sub, ctx, fed, rng, skip=skip, mixed_prologue=mixed_pro,
+            deferred=deferred,
         )
         new_carries = []
         m = m_t[:, None]
@@ -531,7 +710,7 @@ def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerCont
         # out-link mask is applied after the epilogue, matching the
         # masked-inside semantics exactly)
         fr = tuple((outs[f].value, outs[f].ids) for f in dyn_frontier)
-        return tuple(new_carries), (tuple(ys), fr)
+        return tuple(new_carries), (tuple(ys), fr, tuple(d.weights for d in deferred))
 
     xs = (
         xs_vals,
@@ -540,6 +719,7 @@ def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerCont
         jnp.swapaxes(mask_bt, 0, 1),
         jnp.arange(T, dtype=jnp.int32),
         pro_feeds,
+        (),
     )
     if fused_ys is not None:
         # same (ys, frs) pytree the scan would produce: masked out-link
@@ -547,8 +727,12 @@ def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerCont
         m3 = jnp.swapaxes(mask_bt, 0, 1)[:, :, None].astype(fused_ys.dtype)
         ys = [(fused_ys * m3, None) for _ in inside_out_links]
         frs = tuple((fused_ys, None) for _ in dyn_frontier)
+    elif reads:
+        ys, frs = _scan_static_grad(
+            step, init_carries, xs, statics, reads, bool(sub.reversed), ctx.scan_unroll
+        )
     else:
-        _, (ys, frs) = jax.lax.scan(
+        _, (ys, frs, _) = jax.lax.scan(
             step, init_carries, xs, reverse=bool(sub.reversed), unroll=ctx.scan_unroll
         )
     for link, (y, y_lens) in zip(inside_out_links, ys):
