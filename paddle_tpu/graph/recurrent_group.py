@@ -32,6 +32,7 @@ TPU-native formulation:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -40,7 +41,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.graph.argument import Argument
 from paddle_tpu.layers.base import (LayerContext, TimeMajorLogits, forward_layer,
-                                   register_layer)
+                                   input_mask, publish_counter, register_layer)
 from paddle_tpu.ops.activations import apply_activation
 from paddle_tpu.proto import LayerConfig, SubModelConfig
 
@@ -145,13 +146,16 @@ def _run_submodel_step(
     skip: frozenset = frozenset(),
     mixed_prologue: Optional[Dict[str, Any]] = None,
     deferred: Tuple[_DeferredRead, ...] = (),
+    recompute: Optional[Dict[Tuple[str, ...], int]] = None,
 ) -> Dict[str, Argument]:
     """Run the sub-model's layers once with pre-fed agent outputs.
     ``skip`` names epilogue layers hoisted out of the scan;
     ``mixed_prologue`` maps a mixed layer to (skip_input_indices,
     precomputed [B, out] slice) for projections hoisted BEFORE the scan
     (see _plan_prologue); ``deferred`` are the static reads whose gradient
-    the caller takes after the scan."""
+    the caller takes after the scan; ``recompute`` maps each span the
+    backward remakes (see _plan_static_recompute) to the bytes of wide
+    residuals it spares a step, which this run writes in."""
     step_ctx = LayerContext(
         params=ctx.params,
         model=ctx.model,
@@ -171,9 +175,15 @@ def _run_submodel_step(
     # an outer sequence without StaticInput still fails loudly
     step_ctx.parent = ctx
     step_ctx.outputs.update(fed)
+    starts = {s[0]: s for s in recompute or ()}
+    spanned = {n for s in recompute or () for n in s}
     for name in sub.layer_names:
         lcfg = network.layer_map[name]
         if lcfg.name in step_ctx.outputs or lcfg.name in skip:
+            continue
+        if name in starts:
+            recompute[starts[name]] = _run_recomputed(network, starts[name], step_ctx)
+        if name in spanned:
             continue
         if lcfg.type == "recurrent_layer_group":
             # nested group: the inner executor scans the tokens of this
@@ -449,6 +459,142 @@ def _plan_static_grad(network, sub: SubModelConfig, ctx: LayerContext,
     return tuple(reads)
 
 
+def _plan_static_recompute(network, sub: SubModelConfig, ctx: LayerContext,
+                           statics: Dict[str, Argument], skip: frozenset,
+                           frontier) -> Tuple[Tuple[str, ...], ...]:
+    """The spans of step layers that the backward loop recomputes, and the
+    forward loop does not stack.
+
+    An additive attention's scores (simple_attention's `_expand` ->
+    `_combine` -> `_softmax`) are worked out over every position of a
+    static sequence: the step's [B, D] transform is expanded to [B, S, D],
+    added to the projected states and put through the tanh, and a size-1
+    fc reduces the width away. Left to autodiff, the scan stacks that
+    tanh's residuals (its value and 1 - value), T copies of [B, S, D]
+    each, written by the forward loop and read back by the backward.
+    Under jax.checkpoint the span keeps only its inputs, the [B, D]
+    transform and the loop-invariant statics and parameters, and the
+    backward loop remakes the tanh from them (_run_recomputed).
+
+    A span starts at an `expand` of a step value as a static sequence,
+    takes every `mixed` / `addto` that reads such wide values and static
+    sequences only, and ends at each size-1 fc that reads only wide values:
+    what leaves it is [B, S, 1]. A [B, D] value is left alone: it is all
+    the span would keep, so recomputing it buys nothing. Returns the spans'
+    layer names, each in step order; empty where a wide value is read
+    outside its span, or there is none."""
+    from paddle_tpu.utils import device
+
+    def no(why):
+        device.log_selection("scan_recompute_static", sub.name,
+                             f"stacked residuals ({why})")
+        return ()
+
+    if not ctx.is_training or sub.generator is not None:
+        return no("not a training group")
+    if any(l.has_subseq for l in sub.in_links):
+        return no("nested group")
+    lm = network.layer_map
+    names = [n for n in sub.layer_names if n in lm and n not in skip]
+    if any(lm[n].type == "recurrent_layer_group" for n in names):
+        return no("a group inside the step")
+    seqs = {l for l, a in statics.items() if a.value is not None and a.is_seq
+            and not a.is_nested_seq and a.value.ndim == 3}
+    seq_mems = {m.link_name for m in sub.memories if m.is_sequence}
+    wide, groups = set(), []
+    for n in names:
+        lc = lm[n]
+        srcs = {ic.input_layer_name for ic in lc.inputs}
+        if lc.type == "expand":
+            x, layout = (ic.input_layer_name for ic in lc.inputs)
+            joins = layout in seqs and not {x} & (wide | set(statics) | seq_mems)
+        elif lc.type in ("mixed", "addto"):
+            joins = bool(srcs & wide) and srcs <= wide | seqs
+        else:
+            # the reduce: its activation runs after the checkpoint, so no
+            # dropout or error clip may follow the activation in its layer
+            joins = (lc.type == "fc" and lc.size == 1 and srcs and srcs <= wide
+                     and not lc.drop_rate and not lc.error_clipping_threshold)
+        if not joins:
+            continue
+        if lc.type != "fc":
+            wide.add(n)
+        # a span is a connected part of the joined layers
+        linked = [g for g in groups if srcs & set(g)]
+        groups = [g for g in groups if g not in linked] + [sum(linked, []) + [n]]
+    spans = [g for g in groups if any(lm[n].type == "fc" for n in g)]
+    if not spans:
+        return no("no width reduced over a static sequence's positions")
+    outside = ({m.layer_name for m in sub.memories}
+               | {l.layer_name for l in sub.out_links} | set(frontier))
+    spanned = {n for g in groups for n in g}
+    for n in names:
+        for ic in lm[n].inputs:
+            if ic.input_layer_name in wide and n not in spanned:
+                return no(f"{ic.input_layer_name} is read by {n}")
+    if wide & outside:
+        return no(f"{sorted(wide & outside)[0]} leaves the step")
+    # a span runs where its first layer stands: what it reads from outside
+    # has to be made before that
+    order = {n: i for i, n in enumerate(names)}
+    spans = tuple(tuple(sorted(g, key=order.get)) for g in spans)
+    for g in spans:
+        for n in g:
+            for ic in lm[n].inputs:
+                if order.get(ic.input_layer_name, -1) > order[g[0]] \
+                        and ic.input_layer_name not in g:
+                    return no(f"{n} reads {ic.input_layer_name}, made after {g[0]}")
+    device.log_selection(
+        "scan_recompute_static", sub.name,
+        "recomputed in the backward: " + "; ".join(" -> ".join(s) for s in spans))
+    return spans
+
+
+def _run_recomputed(network, span: Tuple[str, ...], ctx: LayerContext) -> int:
+    """Run a span of _plan_static_recompute under one jax.checkpoint that
+    saves nothing: its inputs (a [B, D] step value, statics, parameters)
+    are its only residuals, and the backward remakes the wide values from
+    them. The reducing fc's activation runs after the checkpoint, so its
+    [B, S] residuals are kept as usual and the backward does not remake
+    the scores before it needs the wide values. Puts the span's outputs
+    into ``ctx.outputs``; returns the bytes of the wide residuals autodiff
+    would have kept of the span, a step's worth."""
+    lm = network.layer_map
+    inside = set(span)
+    reads = {(ic.input_layer_name, ic.input_layer_argument)
+             for n in span for ic in lm[n].inputs if ic.input_layer_name not in inside}
+    read = {network._key(n, a): network._lookup_input(ctx, n, a) for n, a in sorted(reads)}
+    leaves = tuple(n for n in span if lm[n].type == "fc")
+
+    def run(params, read, layers):
+        sub = dataclasses.replace(ctx, params=params, outputs=dict(read),
+                                  state_updates={}, nhwc={}, logits={}, conv_stats={})
+        for c in layers:
+            ins = [network._lookup_input(sub, ic.input_layer_name, ic.input_layer_argument)
+                   for ic in c.inputs]
+            forward_layer(c, ins, sub)
+        return {n: sub.outputs[n] for n in leaves}
+
+    def kept(params, read):
+        # what jax.vjp of the plain span keeps beyond its own inputs
+        given = {id(x) for x in jax.tree.leaves((params, read))}
+        plain = functools.partial(run, layers=[lm[n] for n in span])
+        res = jax.tree.leaves(jax.vjp(plain, params, read)[1])
+        return [r for r in res if id(r) not in given]
+
+    wide = [r for r in jax.eval_shape(kept, ctx.params, read)
+            if r.ndim == 3 and r.shape[-1] > 1]
+    linear = [dataclasses.replace(lm[n], active_type="") if n in leaves else lm[n]
+              for n in span]
+    made = jax.checkpoint(functools.partial(run, layers=linear), prevent_cse=False)(
+        ctx.params, read)
+    for n, out in made.items():
+        with jax.named_scope(f"{lm[n].type}:{n}"):
+            ctx.outputs[n] = out.replace(value=apply_activation(
+                lm[n].active_type, out.value, input_mask(out)))
+    return sum(r.size * r.dtype.itemsize for r in wide)
+
+
 def _scan_static_grad(step, init_carries, xs, statics, reads, reverse, unroll):
     """The group's scan with the static reads' gradient taken after it
     (see _plan_static_grad): (ys, frontier values) as jax.lax.scan's.
@@ -650,6 +796,11 @@ def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerCont
     # attention reads of a static whose gradient is taken after the scan
     reads = () if fused_ys is not None else _plan_static_grad(
         network, sub, ctx, statics, skip, dyn_frontier)
+    # attention scores over a static's positions, remade by the backward
+    # loop: each span, and the residual bytes it spares a step
+    spans = () if fused_ys is not None else _plan_static_recompute(
+        network, sub, ctx, statics, skip, dyn_frontier)
+    recomputed = dict.fromkeys(spans, 0)
 
     def step(carries, inp, statics=statics, reads=()):
         x_v, x_i, x_sl, m_t, t_idx, x_pro, x_taps = inp
@@ -674,7 +825,7 @@ def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerCont
         )
         outs = _run_submodel_step(
             network, sub, ctx, fed, rng, skip=skip, mixed_prologue=mixed_pro,
-            deferred=deferred,
+            deferred=deferred, recompute=recomputed,
         )
         new_carries = []
         m = m_t[:, None]
@@ -735,6 +886,10 @@ def _forward_scan(network, cfg: LayerConfig, sub: SubModelConfig, ctx: LayerCont
         _, (ys, frs, _) = jax.lax.scan(
             step, init_carries, xs, reverse=bool(sub.reversed), unroll=ctx.scan_unroll
         )
+    if recomputed:
+        # a gauge: the bytes the forward loop no longer stacks a training step
+        publish_counter(cfg, ctx, "scan.recomputed_bytes",
+                        T * sum(recomputed.values()), how="max")
     for link, (y, y_lens) in zip(inside_out_links, ys):
         if y_lens is not None:
             # [S, B, T, D] → nested [B, S, T, D] with per-subseq lengths

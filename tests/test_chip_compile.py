@@ -357,13 +357,15 @@ def test_a_recomputation_block_runs_the_flash_forward_once(chip):
     assert calls(jax.checkpoint(fn)) == 4
 
 
-def _step_compiled(stem, chip, monkeypatch):
+def _step_compiled(stem, chip, monkeypatch, inputs=("tokens", "labels"),
+                   batch=4, length=8192):
     """A benchmark configuration's REAL train step (its DSL file at its own
     sizes -> GradientMachine's gradient under its recomputation blocks ->
     the updater, parameters and optimizer state donated as the trainer
     donates them) compiled for the described chip from shapes alone:
     nothing is allocated here. The kernels are steered on in the test (the
-    program asks `jax.default_backend()`, which is the CPU here)."""
+    program asks `jax.default_backend()`, which is the CPU here). Every
+    input is `batch` id sequences of `length`."""
     from paddle_tpu.config import parse_config
     from paddle_tpu.graph.argument import Argument
     from paddle_tpu.graph.machine import GradientMachine, compute_dtype_of
@@ -373,26 +375,25 @@ def _step_compiled(stem, chip, monkeypatch):
     monkeypatch.setattr(device, "pallas_mode", lambda: "compiled")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     conf = parse_config(os.path.join(root, "perfbench", "configs", stem + ".py"),
-                        "feed=x,feed_list=y,batch=4")
+                        f"feed=x,feed_list=y,batch={batch}")
     gm = GradientMachine(conf.model_config, compute_dtype=compute_dtype_of(conf.opt_config))
     updater = Updater(conf.opt_config, conf.model_config)
     params = jax.eval_shape(lambda: gm.init_params(seed=1))
     state = jax.eval_shape(updater.init_state, params)
     on_chip = lambda tree: jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree)
-    ids = jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=chip)
-    lens = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=chip)
-    batch = {"tokens": Argument(ids=ids, seq_lengths=lens),
-             "labels": Argument(ids=ids, seq_lengths=lens)}
+    ids = jax.ShapeDtypeStruct((batch, length), jnp.int32, sharding=chip)
+    lens = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=chip)
+    feed = {name: Argument(ids=ids, seq_lengths=lens) for name in inputs}
     grad_fn = gm.grad_fn(remat=conf.opt_config.remat, sparse=True)
 
     def step(params, opt_state, in_args):
         loss, grads, outputs, _ = grad_fn(params, in_args, None)
-        new_params, new_state = updater(params, grads, opt_state, jnp.float32(4))
+        new_params, new_state = updater(params, grads, opt_state, jnp.float32(batch))
         return new_params, new_state, loss
 
     return jax.jit(step, donate_argnums=(0, 1)).lower(
-        on_chip(params), on_chip(state), batch).compile()
+        on_chip(params), on_chip(state), feed).compile()
 
 
 def test_the_latent_cells_real_step_fits_the_chip(chip, monkeypatch):
@@ -429,3 +430,21 @@ def test_the_latent_cells_real_step_fits_the_chip(chip, monkeypatch):
     names = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in ("latent_down", "latent_up"):
         assert any(f"/{scope}/" in n for n in names), scope
+
+
+def test_the_decoders_real_step_stacks_no_tanh_of_its_attention(chip, monkeypatch):
+    """`nmt.train`'s step (256 pairs, both sides padded to 128, widths 512)
+    compiles for a v5e with its attention's scores over the 128 source
+    positions remade by the decoder's backward loop: no `[128, 256, 128,
+    512]` array (the tanh, T x B x S x D) is left in the program, and
+    `mem_total_bytes` is under 7 GB (5.23 GB; 15.28 while the forward loop
+    stacked the tanh and 1 - tanh)."""
+    from paddle_tpu.observability.memory import memory_analysis_of
+
+    compiled = _step_compiled(
+        "seqtoseq-wmt14", chip, monkeypatch, batch=256, length=128,
+        inputs=("source_language_word", "target_language_word",
+                "target_language_next_word"))
+    assert "[128,256,128,512]" not in compiled.as_text()
+    memory = memory_analysis_of(compiled)
+    assert memory["mem_total_bytes"] < 7e9, memory

@@ -21,6 +21,7 @@ import pytest
 
 from paddle_tpu.config import parse_config
 from paddle_tpu.graph.argument import Argument
+from paddle_tpu.layers.base import LAYER_COUNTERS
 from paddle_tpu.observability import metrics as obs
 from paddle_tpu.observability import spans as obs_spans
 from paddle_tpu.proto import EvaluatorConfig, ModelConfig
@@ -370,8 +371,11 @@ def test_the_step_of_a_classification_cost_net_returns_no_softmax_output():
     assert not set(ev.input_layers) & set(keep)
     assert set(keep) == set(trainer.gm.network.output_layer_names)
     assert not [x for x in jax.tree_util.tree_leaves(keep) if vocab in x.shape]
-    assert {k: (v.shape, v.dtype) for k, v in states.items()} == {
-        ev.name: ((2,), jnp.float32)}
+    # beside the evaluator's statistic, the decoder group's one layer
+    # counter (graph/recurrent_group.py: scan.recomputed_bytes)
+    assert jax.tree_util.tree_map(lambda v: (v.shape, v.dtype), states) == {
+        ev.name: ((2,), jnp.float32),
+        LAYER_COUNTERS: {"max": {"scan.recomputed_bytes": ((), jnp.float32)}}}
     assert loss.shape == ()
 
 
